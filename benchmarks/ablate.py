@@ -9,12 +9,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "..", ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 from quiver_tpu.ops.sample import (sample_layer, compact_layer)
+from quiver_tpu.utils.compile_cache import place_compile_cache
+
+place_compile_cache()
 
 N = 2_450_000
 AVG = 25

@@ -64,12 +64,10 @@ CAP_FANOUT = [32, 16]
 CAP_SHED_LADDER = [[32, 16], [12, 6], [4, 2]]
 
 
-def _record(value=None, err=None, skipped=False, **extra):
+def _record(value=None, err=None, **extra):
     rec = {"metric": METRIC, "value": value, "unit": "requests/s"}
     if err is not None:
         rec["error"] = err
-    if skipped:
-        rec["skipped"] = True
     rec.update(extra)
     return rec
 
@@ -306,22 +304,14 @@ def main():
                          "must be <= tol")
     ap.add_argument("--smoke", action="store_true",
                     default=bool(os.environ.get("QT_SERVE_SMOKE")))
-    ap.add_argument("--platform", default=os.environ.get(
-        "QT_BENCH_PLATFORM", ""))
+    ap.add_argument("--platform", default="")
     args_cli = ap.parse_args()
 
     if args_cli.platform:
         os.environ["JAX_PLATFORMS"] = args_cli.platform
-    platform = os.environ.get("JAX_PLATFORMS", "") or "default"
-    if platform not in ("", "cpu", "default"):
-        from bench import probe_backend
-        ok, detail = probe_backend(args_cli.platform)
-        if not ok:
-            _emit(_record(err=f"backend unavailable: {detail}",
-                          skipped=True, platform=platform))
-            return 0
 
     jax = configure_jax()
+    platform = jax.devices()[0].platform
     import quiver_tpu as qv
     from quiver_tpu import capacity as qcap
     from quiver_tpu import traffic
@@ -423,8 +413,8 @@ def main():
 
     rec = _record(
         value=measured_rps,
-        platform=("cpu-smoke" if args_cli.smoke and platform
-                  in ("cpu", "default") else platform),
+        platform=("cpu-smoke" if args_cli.smoke and platform == "cpu"
+                  else platform),
         smoke=args_cli.smoke,
         budget_ms=budget_ms,
         prediction=pred,

@@ -1,8 +1,8 @@
 """Fused single-kernel sample+gather hop A/B (qt-fuse).
 
 Three checks in one pass, each printed as a one-line JSON record with a
-``metric`` key (the chip-suite log grammar ``bench_regress.py`` and
-``transcribe_log.py`` parse):
+``metric`` key (the chip-suite log grammar ``bench_regress.py``
+parses):
 
 1. ``fused_bit_equal`` — the fused kernel's picks AND dequantized rows
    against the split two-program oracle (``sample_layer_pallas`` +

@@ -57,8 +57,12 @@ dispatch; longer deadlines add wait the SLO must absorb).
 
 Emits ONE ``BENCH_*``-compatible JSON line on stdout (mirrored to
 ``QT_METRICS_JSONL`` with the shared ``{ts, kind, ...}`` schema, kind
-``bench``); an unavailable backend emits ``"skipped": true`` and exits
-0 (the r4/r5 outage convention, same as bench.py).
+``bench``); an unavailable backend raises and the run exits non-zero.
+
+One process per chip: this process measures the engine, so it holds the
+device; the fleet arms' real replica children are told theirs, the CPU
+backend (``replica_platform`` in the record) — those arms measure
+supervision, routing and RPC, not the device.
 
 Usage: JAX_PLATFORMS=cpu python benchmarks/bench_serving.py
        [--budget-ms F] [--trial-s F] [--smoke]
@@ -96,13 +100,14 @@ TAIL_METRIC = ("assembled tail-sampled traces under seeded slow+error "
 TAIL_SLOW_AFTER = 15
 TAIL_ERROR_AFTER = 15
 
+#: the backend every replica child is started on (see _spawn_replica)
+REPLICA_PLATFORM = "cpu"
 
-def _record(value=None, err=None, skipped=False, **extra):
+
+def _record(value=None, err=None, **extra):
     rec = {"metric": METRIC, "value": value, "unit": "requests/s"}
     if err is not None:
         rec["error"] = err
-    if skipped:
-        rec["skipped"] = True
     rec.update(extra)
     return rec
 
@@ -562,10 +567,13 @@ def _spawn_replica(name, port, sink_path, budget_ms, env_extra=None,
     """One serve-replica child (the ``--replica`` entry of this
     file): the parent's QT_FAULTS* scrubbed — each child's fault plan
     (and QT_TAIL) arrives via ``env_extra`` only — stdout/stderr
-    silenced."""
+    silenced. The child is told its device: the parent may hold the
+    chip, which belongs to one process, so replicas serve from the CPU
+    backend (``REPLICA_PLATFORM``, reported in the record)."""
     import subprocess
     env = {k: v for k, v in os.environ.items()
            if k not in ("QT_FAULTS", "QT_FAULTS_SEED", "QT_TAIL")}
+    env["JAX_PLATFORMS"] = REPLICA_PLATFORM
     if env_extra:
         env.update(env_extra)
     cmd = [sys.executable, os.path.abspath(__file__),
@@ -938,8 +946,7 @@ def main():
                     default=float(os.environ.get("QT_SERVE_TRIAL_S", 2.0)))
     ap.add_argument("--smoke", action="store_true",
                     default=bool(os.environ.get("QT_SERVE_SMOKE")))
-    ap.add_argument("--platform", default=os.environ.get(
-        "QT_BENCH_PLATFORM", ""))
+    ap.add_argument("--platform", default="")
     ap.add_argument("--chaos-only", action="store_true",
                     help="run ONLY the chaos kill A/B (real serve "
                          "replicas unless --smoke) — the chip_suite "
@@ -965,17 +972,6 @@ def main():
 
     if args_cli.platform:
         os.environ["JAX_PLATFORMS"] = args_cli.platform
-    platform = os.environ.get("JAX_PLATFORMS", "") or "default"
-    if platform not in ("", "cpu", "default"):
-        # non-CPU backends can hang at init (the r4/r5 rounds): reuse
-        # bench.py's out-of-process probe + skip convention
-        from bench import probe_backend
-        ok, detail = probe_backend(args_cli.platform)
-        if not ok:
-            _emit(_record(err=f"backend unavailable: {detail}",
-                          skipped=True, platform=platform))
-            return 0
-
     jax = configure_jax()
     import quiver_tpu as qv
 
@@ -986,8 +982,7 @@ def main():
             "metric": TAIL_METRIC,
             "value": res["assembled_traces"],
             "unit": "traces",
-            "platform": ("cpu-smoke"
-                         if platform in ("cpu", "default") else platform),
+            "replica_platform": REPLICA_PLATFORM,
             "tail_fleet": res,
             "elapsed_s": round(time.time() - t_start, 1),
         }
@@ -1003,8 +998,7 @@ def main():
             "metric": CHAOS_METRIC,
             "value": res["chaos"]["accepted_rps"],
             "unit": "requests/s",
-            "platform": ("cpu-smoke"
-                         if platform in ("cpu", "default") else platform),
+            "replica_platform": REPLICA_PLATFORM,
             "chaos_ab": res,
             "elapsed_s": round(time.time() - t_start, 1),
         }
@@ -1191,7 +1185,8 @@ def main():
 
     rec = _record(
         value=round(co_rps, 1),
-        platform="cpu-smoke" if platform in ("cpu", "default") else platform,
+        platform=jax.devices()[0].platform,
+        replica_platform=REPLICA_PLATFORM,
         p99_budget_ms=round(budget_ms, 2),
         batch_cap=batch_cap,
         serial_rps=round(serial_rps, 1),

@@ -71,10 +71,11 @@ except AttributeError:      # pragma: no cover - jax moved core
 
 # host round-trip primitives: the structural definition of "this traced
 # program syncs with the host" — callback-based syncs included
-# (jax.debug.print lowers to debug_callback; jax.pure_callback /
-# io_callback are the blocking data paths)
+# (jax.debug.print traces to debug_print, jax.debug.callback to
+# debug_callback; jax.pure_callback / io_callback are the blocking data
+# paths)
 HOST_SYNC_PRIMS = ("io_callback", "pure_callback", "debug_callback",
-                   "python_callback", "infeed", "outfeed")
+                   "debug_print", "python_callback", "infeed", "outfeed")
 
 # collectives that rendezvous across the mesh axis — any of these inside
 # a divergent cond branch deadlocks the mesh

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 from .ops import quant
 from .ops.dedup import I32_MAX, unique_within_budget
 from .profiling import hot_path

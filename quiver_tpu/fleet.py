@@ -732,6 +732,14 @@ class ReplicaSupervisor:
     count, so one crash a day pays the MINIMUM backoff, not an
     ever-growing one.
 
+    An accelerator belongs to ONE process at a time: a supervising
+    process that has initialised a JAX backend holds the chips, and a
+    child that needs one then fails or hangs. This module imports no
+    jax; a ``spawn`` whose children serve from a chip must name each
+    child's device in its environment (``index`` is there for that, one
+    chip per child) from a parent that stays off JAX — or the replicas
+    run in-process, one engine per device.
+
     Lifecycle events (spawn / exit / breaker transitions) append to
     ``sink`` as ``chaos`` JSONL records and to the in-memory
     ``events`` deque. ``kill(name)`` is the chaos harness's trigger

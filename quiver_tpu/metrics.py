@@ -35,8 +35,7 @@ JSONL record schema (one object per line)::
 
 Record kinds emitted in-tree: ``step_stats`` (StepStats.snapshot()),
 ``bench`` (bench.py's and benchmarks/bench_serving.py's measurement
-records), ``canary`` (benchmarks/canary.py's usability probe),
-``serving`` (``serving.MicroBatchServer.snapshot()`` — a ``step_stats``
+records), ``serving`` (``serving.MicroBatchServer.snapshot()`` — a ``step_stats``
 payload whose ``wall`` block times BATCH dispatches, plus a ``request``
 block with per-REQUEST admission->result latency percentiles and a
 ``serving`` block with admission/shed/variant-mix counts), ``slo``
@@ -696,7 +695,7 @@ def _json_default(o):
 class MetricsSink:
     """Append-only JSONL emitter — the one record schema shared by the
     interactive ``report()``, ``bench.py``'s measurement line, and the
-    long-running watch logs (``benchmarks/chip_watch.sh``'s canary).
+    long-running replicas' heartbeat logs.
 
     ``path`` is a filesystem path (opened append) or any file-like with
     ``write``. Every record gains ``ts`` (unix seconds) and ``kind``.
@@ -704,7 +703,7 @@ class MetricsSink:
     ``max_bytes`` (path-owned sinks only) bounds the file: when an emit
     pushes it past the limit, the file rolls over to ``<path>.1``
     (replacing any previous rollover) and a fresh file starts — a
-    week-long chip_watch keeps at most ``2 * max_bytes`` on disk
+    week-long replica keeps at most ``2 * max_bytes`` on disk
     instead of growing without bound. Readers that want the full
     window read the seam: :func:`read_jsonl` (and ``scripts/qt_top.py``
     / ``scripts/bench_regress.py``) consume ``<path>.1`` before

@@ -25,12 +25,5 @@ def init_reductions():
     Pickler dispatch keys on the *concrete* class (ArrayImpl), not the
     abstract ``jax.Array``, so register the implementation type directly.
     """
-    try:
-        from jax._src.array import ArrayImpl
-        copyreg.pickle(ArrayImpl, _reduce_jax_array)
-    except ImportError:
-        # private path moved: materialize a tiny CPU array to get the
-        # concrete class (cpu backend only; cheap)
-        concrete = type(jax.device_put(
-            np.zeros(1), jax.local_devices(backend="cpu")[0]))
-        copyreg.pickle(concrete, _reduce_jax_array)
+    from jax._src.array import ArrayImpl
+    copyreg.pickle(ArrayImpl, _reduce_jax_array)

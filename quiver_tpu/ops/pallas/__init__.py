@@ -1,57 +1,45 @@
-"""Pallas TPU kernels — EXPERIMENTAL status (provisionally retired).
+"""Pallas TPU kernels.
 
-Status (round 5, see docs/introduction.md "Custom kernels:
-wire-or-retire"): the production L3 for both hot ops is the jnp/XLA
-path, not these kernels. The decision is provisional-by-necessity —
-the TPU backend outage that began in round 3 has prevented either
-kernel from ever executing on hardware — but the jnp evidence alone
-supports it:
+Status after PR 23: every kernel here compiles for the v5e at
+ogbn-products sizes (``tests/test_chip_compile.py`` asks the chip's
+compiler on every test run) and ``chip_smoke.py`` runs the fused train
+step on the chip. None has a timing yet; the production default for both
+hot ops stays the jnp/XLA path (``ops/sample.py``, ``jnp.take``).
 
-- feature gather: ``jnp.take`` sustains 230.5 GB/s on one v5e chip
-  (vs the reference's published 14.82 GB/s single-GPU UVA gather,
-  Introduction_en.md:92-95) — the XLA gather already saturates a
-  usable fraction of HBM for 100-1024-float rows, leaving little
-  headroom for ``gather.py`` to win;
-- sampling: the wide-row-fetch redesign (rotation/window/wide-exact in
-  ``ops/sample.py``) reached 73.33M SEPS = 2.14x the reference on
-  chip, by restructuring memory access around 128-lane rows rather
-  than accelerating the reference's warp-per-seed shape that
-  ``sample_kernel.py`` mirrors (cuda_random.cu.hpp:7-69).
+- ``sample_kernel.py`` (``sample_layer_pallas``) and ``gather.py``
+  (``gather_rows``) mirror the reference's warp-per-seed sampler and
+  warp-per-row gather. They are on no production call path;
+  ``sample_layer_pallas`` is the split oracle the fused kernels are
+  pinned against.
+- ``fused.py`` fuses the hop walk and the hot-tier feature gather into
+  ONE kernel, so the frontier id list never round-trips through HBM
+  between a sample program and a gather program — something no jnp
+  graph can express (XLA materializes the ids between the two gathers).
+  It IS reachable from production builders, strictly opt-in:
+  ``build_train_step(fused_hot_hop=True)`` /
+  ``build_serve_step(fused_hot_hop=True)`` / ``ServeEngine`` /
+  ``build_e2e_train_step`` and the hot-tier leg of
+  ``build_sharded_serve_step``, exact method only. ``fused_multihop``
+  walks ANY fanout ladder: interior hops run the sampling-only kernel
+  variant (degrees/starts resolve in-kernel, no XLA indptr gather), the
+  sort-based gather-free ``compact_layer`` dedups between hops, and
+  only the LEAF hop's feature rows are ever written to HBM, so the
+  modeled ``gather_index_bytes`` is zero across every hop. The whole
+  walk — kernels, compaction, the final two-scatter row reassembly —
+  compiles as one program. The split oracles are
+  ``fused_hot_hop_reference`` / ``fused_multihop_reference``,
+  bit-equality pinned in ``tests/test_fused.py``. Per-hop frontier
+  budgets truncate exactly as the split path's ``compact_layer``
+  budgets do — duplicates compact first, overflow drops from the tail —
+  so fused and split walks always agree bit-for-bit, truncation
+  included.
 
-The kernels stay importable and interpret-mode-tested (they mirror the
-jnp correctness oracles, and ``bench_sampler.py --pallas`` /
-``bench_feature.py --pallas`` stay wired in ``chip_suite.sh``), so
-the moment hardware returns the decision can be revisited with
-numbers. ``sample_kernel.py`` and ``gather.py`` are NOT on any
-production call path.
-
-Round 18 (qt-fuse) adds the exception: ``fused.py`` fuses the hop walk
-and the hot-tier feature gather into ONE kernel, so the frontier id
-list never round-trips through HBM between a sample program and a
-gather program — something no jnp graph can express (XLA materializes
-the ids between the two gathers). It IS reachable from production
-builders, strictly opt-in: ``build_train_step(fused_hot_hop=True)`` /
-``build_serve_step(fused_hot_hop=True)`` / ``ServeEngine``, exact
-method only, with the jnp split path as the default and the
-bit-equivalence oracle (``fused_hot_hop_reference``, pinned in
-``tests/test_fused.py``).
-
-Round 21 (qt-fuse-deep) lifts the single-hop restriction: the same
-knob now engages ``fused_multihop`` for ANY fanout ladder — interior
-hops run the sampling-only kernel variant (degrees/starts resolve
-in-kernel, no XLA indptr gather), the sort-based gather-free
-``compact_layer`` dedups between hops, and only the LEAF hop's feature
-rows are ever written to HBM, so the modeled ``gather_index_bytes`` is
-zero across every hop. The whole walk — kernels, compaction, the final
-two-scatter row reassembly — compiles as one program.
-``build_e2e_train_step`` and the hot-tier leg of
-``build_sharded_serve_step`` take the same knob; the split oracle is
-``fused_multihop_reference``, bit-equality pinned in
-``tests/test_fused.py``. Per-hop frontier budgets truncate exactly as
-the split path's ``compact_layer`` budgets do — duplicates compact
-first, overflow drops from the tail — so fused and split walks always
-agree bit-for-bit, truncation included. Shared DMA/window/PRNG helpers
-for all kernels live in ``_dma.py``.
+Nothing here falls back: ``interpret`` / ``rng`` default from the
+backend in ONE place (``_dma.default_interpret`` / ``default_rng``:
+compiled with the on-core generator on a TPU, interpreted with the
+portable hash generator elsewhere), and what the chip's compiler
+refuses raises (the fused gather over an int8 table). The layout rules
+the compiler imposes live in ``_dma.py``.
 """
 
 __all__ = []

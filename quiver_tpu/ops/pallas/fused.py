@@ -13,11 +13,13 @@ This kernel walks ONE hop for a block of 128 seeds and gathers the
 feature rows of every seed and every pick before returning:
 
   phase A (sample, per block)
-    - DMA each seed's ``indptr`` pair HBM->SMEM (degrees/starts are
-      computed in-kernel — the wrapper issues NO gather, which is what
-      makes ``gather_index_bytes=0`` a verifiable model output);
-    - DMA each seed's CSR neighbor row HBM->VMEM at the 128-aligned
-      start (``_dma`` rules), residual shifting the position compare;
+    - DMA the 128-entry rows of ``indptr`` holding each seed's pair
+      HBM->SMEM (degrees/starts are computed in-kernel — the wrapper
+      issues NO gather, which is what makes ``gather_index_bytes=0`` a
+      verifiable model output);
+    - DMA each seed's CSR neighbor window HBM->VMEM from the row its
+      first entry sits in (``_dma`` rules), residual shifting the
+      position compare;
     - the ``sample_kernel`` vectorized partial Fisher-Yates picks k
       positions per seed ([BLOCK, k] lanes, pluggable PRNG);
     - iota-compare extraction materializes picks + counts.
@@ -64,11 +66,14 @@ Scope and contract:
   + ``quant.gather_rows``) — ``fused_hot_hop_reference`` below IS that
   oracle. "tpu" rng swaps in the on-core generator (TPU-only).
 - ``feature_order`` (old id -> storage row) is translated in-kernel via
-  serial 1-element DMAs — correct and interpret-validated, but a known
-  TPU-hardening cost cliff; all-hot identity-order stores skip it.
+  one serial 128-entry-row DMA per frontier slot — correct, but a known
+  cost cliff; all-hot identity-order stores skip it.
+- a quantized (int8) table has no compiled form: Mosaic cannot DMA one
+  row out of four packed to a sublane, so ``interpret=False`` raises.
 
-CPU-interpret-validated behind a TPU flag (``interpret`` defaults to
-True off-TPU), per ROADMAP item 2's scoping.
+Compiles for the v5e at products sizes (``tests/test_chip_compile.py``)
+and runs there (``chip_smoke.py``); interpreted, with the "hash"
+generator, everywhere else (``_dma.default_interpret``).
 """
 
 from __future__ import annotations
@@ -80,11 +85,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._compat import pallas_tpu_compiler_params as _compiler_params
 from .. import quant
 from . import _dma
-from ._dma import align_start, make_rand_bits, pad_feature_dim
-from .sample_kernel import BLOCK, _fy_positions
+from ._dma import ALIGN, make_rand_bits, pad_feature_dim, split_start
+from .sample_kernel import (BLOCK, _extract_picks, _fy_positions, _stage_rows,
+                            _window_scratch)
 from .sample_kernel import sample_layer_pallas
 
 # feature-row DMA pipeline depth (the gather kernel's scheme)
@@ -100,12 +105,12 @@ pad_indices = _dma.pad_indices
 def _make_fused_kernel(*, k, row_cap, rng, n_nodes, n_order=0, tier_n=1,
                        hot_rows=0, dim=0, out_dt=None, quantized=False,
                        has_forder=False, with_gather=True):
-    win = _dma.win(row_cap)
+    n_win = _dma.win_rows(row_cap)
     n_rows = BLOCK * (1 + k)        # seeds first, then flattened picks
 
     def kernel(*refs):
         it = iter(refs)
-        seeds_smem = next(it)
+        seeds_blk = next(it)
         seed_ref = next(it)
         indptr_hbm = next(it)
         indices_hbm = next(it)
@@ -121,8 +126,9 @@ def _make_fused_kernel(*, k, row_cap, rng, n_nodes, n_order=0, tier_n=1,
             pick_rows_ref = next(it)
         ptr_smem = next(it)
         ptr_sems = next(it)
-        rows_vmem = next(it)
+        stage_vmem = next(it)
         row_sems = next(it)
+        rows_vmem = next(it)
         if with_gather:
             picks_smem = next(it)
             pick_sem = next(it)
@@ -135,76 +141,75 @@ def _make_fused_kernel(*, k, row_cap, rng, n_nodes, n_order=0, tier_n=1,
                 zero_sems = next(it)
             if has_forder:
                 tid_smem = next(it)
+                trow_smem = next(it)
                 tid_sem = next(it)
 
         blk = pl.program_id(0)
         rand_bits = make_rand_bits(rng, seed_ref[0], blk)
 
+        def seed_at(i):
+            return seeds_blk[0, 0, i]
+
         # ---- phase A: sample (degrees/starts resolved IN-KERNEL) ----
-        def seed_ptr(i):
-            return jnp.clip(seeds_smem[i], 0, n_nodes - 1)
+        # indptr[s] and indptr[s+1] each arrive as the 128-entry row of
+        # ``indptr_hbm`` that holds them (a pair can straddle two rows)
+        def ptr_copies(i):
+            s = jnp.clip(seed_at(i), 0, n_nodes - 1)
+            return s, [pltpu.make_async_copy(
+                indptr_hbm.at[(s + j) // ALIGN], ptr_smem.at[i, j],
+                ptr_sems.at[i, j]) for j in (0, 1)]
 
         def ptr_start(i, _):
-            pltpu.make_async_copy(
-                indptr_hbm.at[pl.ds(seed_ptr(i), 2)],
-                ptr_smem.at[i], ptr_sems.at[i]).start()
+            for c in ptr_copies(i)[1]:
+                c.start()
             return 0
 
         jax.lax.fori_loop(0, BLOCK, ptr_start, 0)
 
-        def row_start_of(i):
+        def row_copy(i, start):
+            return pltpu.make_async_copy(
+                indices_hbm.at[pl.ds(split_start(start)[0], n_win)],
+                stage_vmem.at[i], row_sems.at[i])
+
+        def start_of(i):
             # same semantics as the split wrapper: invalid seeds read
             # degree 0 at start 0
-            valid = seeds_smem[i] >= 0
-            start = jnp.where(valid, ptr_smem[i, 0], 0)
-            return align_start(start)[0]
+            s = jnp.clip(seed_at(i), 0, n_nodes - 1)
+            return jnp.where(seed_at(i) >= 0,
+                             ptr_smem[i, 0, s % ALIGN], 0)
 
-        b_iota = jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK), 1)
+        b_iota = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
 
         def row_start(i, carry):
             degv, offv = carry
-            pltpu.make_async_copy(
-                indptr_hbm.at[pl.ds(seed_ptr(i), 2)],
-                ptr_smem.at[i], ptr_sems.at[i]).wait()
-            valid = seeds_smem[i] >= 0
-            start = jnp.where(valid, ptr_smem[i, 0], 0)
-            deg = jnp.where(valid, ptr_smem[i, 1] - ptr_smem[i, 0], 0)
-            aligned, off = align_start(start)
-            pltpu.make_async_copy(
-                indices_hbm.at[pl.ds(aligned, win)],
-                rows_vmem.at[i], row_sems.at[i]).start()
+            s, copies = ptr_copies(i)
+            for c in copies:
+                c.wait()
+            start = start_of(i)
+            deg = jnp.where(seed_at(i) >= 0,
+                            ptr_smem[i, 1, (s + 1) % ALIGN] - start, 0)
+            row_copy(i, start).start()
             onehot = b_iota == i
             return (jnp.where(onehot, deg, degv),
-                    jnp.where(onehot, off, offv))
+                    jnp.where(onehot, split_start(start)[1], offv))
 
-        degv, offv = jax.lax.fori_loop(
+        degs, offs = jax.lax.fori_loop(                   # [BLOCK, 1]
             0, BLOCK, row_start,
-            (jnp.zeros((1, BLOCK), jnp.int32),
-             jnp.zeros((1, BLOCK), jnp.int32)))
-        degs = degv[0]
-        offs = offv[0]
+            (jnp.zeros((BLOCK, 1), jnp.int32),
+             jnp.zeros((BLOCK, 1), jnp.int32)))
 
         pos = _fy_positions(degs, k, row_cap, rand_bits)  # [BLOCK, k]
 
         def row_wait(i, _):
-            pltpu.make_async_copy(
-                indices_hbm.at[pl.ds(row_start_of(i), win)],
-                rows_vmem.at[i], row_sems.at[i]).wait()
+            row_copy(i, start_of(i)).wait()
             return 0
 
         jax.lax.fori_loop(0, BLOCK, row_wait, 0)
 
-        rows = rows_vmem[:, :]                            # [BLOCK, win]
-        r_iota = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, win), 1)
+        _stage_rows(stage_vmem, rows_vmem)
         counts = jnp.minimum(degs, k).astype(jnp.int32)
-        shifted = pos + offs[:, None]                     # window coords
-        for i in range(k):
-            sel = jnp.sum(
-                jnp.where(r_iota == shifted[:, i][:, None], rows, 0),
-                axis=1)
-            valid_i = i < counts
-            nbrs_ref[:, i] = jnp.where(valid_i, sel.astype(jnp.int32), -1)
-        cnt_ref[0] = counts
+        nbrs_ref[...] = _extract_picks(rows_vmem[...], pos, offs, counts, k)
+        cnt_ref[...] = counts
 
         if not with_gather:     # sampling-only variant stops here
             return
@@ -223,19 +228,20 @@ def _make_fused_kernel(*, k, row_cap, rng, n_nodes, n_order=0, tier_n=1,
             pi = jnp.where(is_seed, 0, i - BLOCK)
             prow = pi // k
             pcol = pi - prow * k
-            return jnp.where(is_seed, seeds_smem[si],
+            return jnp.where(is_seed, seed_at(si),
                              picks_smem[prow, pcol])
 
         if has_forder:
-            # old id -> storage row, one serial element DMA per row
-            # (documented cost cliff; identity-order stores skip this)
+            # old id -> storage row: the 128-entry row of ``forder_hbm``
+            # holding it, one serial DMA per frontier slot (documented
+            # cost cliff; identity-order stores skip this)
             def translate(i, _):
                 safe = jnp.clip(raw_id(i), 0, n_order - 1)
                 t = pltpu.make_async_copy(
-                    forder_hbm.at[pl.ds(safe, 1)],
-                    tid_smem.at[pl.ds(i, 1)], tid_sem)
+                    forder_hbm.at[safe // ALIGN], trow_smem, tid_sem)
                 t.start()
                 t.wait()
+                tid_smem[i] = trow_smem[safe % ALIGN]
                 return 0
 
             jax.lax.fori_loop(0, n_rows, translate, 0)
@@ -336,6 +342,16 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
 
     data, scale, zero = quant.tier_parts(feat)
     quantized = scale is not None
+    if quantized and not interpret:
+        # asked of the v5e's compiler (PR 23): an int8 table in HBM is
+        # tiled (8,128)(4,1), four rows to a sublane, and Mosaic refuses
+        # the per-row DMA — "Slice shape along dimension 0 must be
+        # aligned to tiling (8), but is 1". No fallback: say so.
+        raise NotImplementedError(
+            "fused_hot_hop cannot compile its per-row DMA over a "
+            f"{data.dtype} table (Mosaic: 'Slice shape along dimension 0 "
+            "must be aligned to tiling (8), but is 1'); use a float32 "
+            "hot tier with fused_hot_hop, or the split path")
     out_dt = quant.tier_dtype(feat)
     tier_n = quant.tier_rows(feat)
     out_dim = data.shape[1]
@@ -351,15 +367,16 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
         quantized=quantized, has_forder=has_forder)
 
     in_specs = [
-        pl.BlockSpec((BLOCK,), lambda b: (b,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, BLOCK), lambda b: (b, 0, 0),
+                     memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [seeds.astype(jnp.int32),
+    operands = [seeds.astype(jnp.int32).reshape(grid, 1, BLOCK),
                 jnp.asarray(seed, jnp.int32).reshape(1),
-                indptr.astype(jnp.int32),
+                _dma.as_rows(indptr.astype(jnp.int32)),
                 indices_padded,
                 data]
     if quantized:
@@ -367,13 +384,12 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
         operands += [scale, zero]
     if has_forder:
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        operands.append(feature_order.astype(jnp.int32))
+        operands.append(_dma.as_rows(feature_order.astype(jnp.int32)))
 
     scratch = [
-        pltpu.SMEM((BLOCK, 2), jnp.int32),        # indptr pairs
-        pltpu.SemaphoreType.DMA((BLOCK,)),
-        pltpu.VMEM((BLOCK, _dma.win(row_cap)), indices_padded.dtype),
-        pltpu.SemaphoreType.DMA((BLOCK,)),
+        pltpu.SMEM((BLOCK, 2, ALIGN), jnp.int32),  # indptr pair rows
+        pltpu.SemaphoreType.DMA((BLOCK, 2)),
+        *_window_scratch(row_cap, indices_padded.dtype),
         pltpu.SMEM((BLOCK, k), jnp.int32),        # picks, on-core
         pltpu.SemaphoreType.DMA,
         pltpu.VMEM((_N_BUF, dim), data.dtype),    # feature-row pipeline
@@ -389,6 +405,7 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
     if has_forder:
         scratch += [
             pltpu.SMEM((n_rows,), jnp.int32),
+            pltpu.SMEM((ALIGN,), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ]
 
@@ -400,10 +417,10 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
     out_item = jnp.dtype(out_dt).itemsize
     bytes_accessed = grid * (
         BLOCK * 4                                  # seeds (SMEM block)
-        + BLOCK * 2 * 4                            # indptr pairs
+        + BLOCK * 2 * ALIGN * 4                    # indptr pair rows
         + BLOCK * _dma.win(row_cap) * idx_item     # CSR staging windows
         + n_rows * quant.row_read_bytes(feat)      # tier rows
-        + (n_rows * 4 if has_forder else 0)        # order translation
+        + (n_rows * ALIGN * 4 if has_forder else 0)  # order translation
         + BLOCK * (k + 1) * 4                      # nbrs + counts out
         + n_rows * dim * out_item)                 # feature rows out
     flops = 2 * grid * n_rows * dim if quantized else 0
@@ -415,7 +432,7 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
         out_specs=[
             pl.BlockSpec((BLOCK, k), lambda b: (b, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK), lambda b: (b, 0),
+            pl.BlockSpec((BLOCK, 1), lambda b: (b, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((BLOCK, dim), lambda b: (b, 0),
                          memory_space=pltpu.VMEM),
@@ -424,7 +441,7 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((padded_bs, k), jnp.int32),
-            jax.ShapeDtypeStruct((grid, BLOCK), jnp.int32),
+            jax.ShapeDtypeStruct((padded_bs, 1), jnp.int32),
             jax.ShapeDtypeStruct((padded_bs, dim), out_dt),
             jax.ShapeDtypeStruct((padded_bs * k, dim), out_dt),
         ],
@@ -433,9 +450,9 @@ def _fused_hot_hop(indptr, indices_padded, seeds, feat, k, seed,
         cost_estimate=pl.CostEstimate(
             flops=flops, transcendentals=0,
             bytes_accessed=int(bytes_accessed)),
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(*operands)
-    return (nbrs[:bs], cnt.reshape(-1)[:bs],
+    return (nbrs[:bs], cnt[:bs, 0],
             seed_rows[:bs, :out_dim], pick_rows[:bs * k, :out_dim])
 
 
@@ -488,25 +505,25 @@ def _fused_sample_hop(indptr, indices_padded, seeds, k, seed,
         with_gather=False)
 
     in_specs = [
-        pl.BlockSpec((BLOCK,), lambda b: (b,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, BLOCK), lambda b: (b, 0, 0),
+                     memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [seeds.astype(jnp.int32),
+    operands = [seeds.astype(jnp.int32).reshape(grid, 1, BLOCK),
                 jnp.asarray(seed, jnp.int32).reshape(1),
-                indptr.astype(jnp.int32),
+                _dma.as_rows(indptr.astype(jnp.int32)),
                 indices_padded]
     scratch = [
-        pltpu.SMEM((BLOCK, 2), jnp.int32),        # indptr pairs
-        pltpu.SemaphoreType.DMA((BLOCK,)),
-        pltpu.VMEM((BLOCK, _dma.win(row_cap)), indices_padded.dtype),
-        pltpu.SemaphoreType.DMA((BLOCK,)),
+        pltpu.SMEM((BLOCK, 2, ALIGN), jnp.int32),  # indptr pair rows
+        pltpu.SemaphoreType.DMA((BLOCK, 2)),
+        *_window_scratch(row_cap, indices_padded.dtype),
     ]
     idx_item = jnp.dtype(indices_padded.dtype).itemsize
     bytes_accessed = grid * (
         BLOCK * 4                                  # seeds (SMEM block)
-        + BLOCK * 2 * 4                            # indptr pairs
+        + BLOCK * 2 * ALIGN * 4                    # indptr pair rows
         + BLOCK * _dma.win(row_cap) * idx_item     # CSR staging windows
         + BLOCK * (k + 1) * 4)                     # nbrs + counts out
 
@@ -517,21 +534,21 @@ def _fused_sample_hop(indptr, indices_padded, seeds, k, seed,
         out_specs=[
             pl.BlockSpec((BLOCK, k), lambda b: (b, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK), lambda b: (b, 0),
+            pl.BlockSpec((BLOCK, 1), lambda b: (b, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((padded_bs, k), jnp.int32),
-            jax.ShapeDtypeStruct((grid, BLOCK), jnp.int32),
+            jax.ShapeDtypeStruct((padded_bs, 1), jnp.int32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=0, transcendentals=0,
             bytes_accessed=int(bytes_accessed)),
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(*operands)
-    return nbrs[:bs], cnt.reshape(-1)[:bs]
+    return nbrs[:bs], cnt[:bs, 0]
 
 
 def fused_sample_hop(indptr, indices_padded, seeds, k, seed,

@@ -7,9 +7,10 @@ from the feature array (resident in HBM) into the output block, with the
 row id list scalar-prefetched so DMA addresses are known before the body
 runs.
 
-Double-buffered: row i+1's DMA is in flight while row i completes.
-Falls back to `jnp.take` when Pallas is unavailable (interpret mode covers
-CPU tests).
+Pipelined ``_N_BUF`` deep: later rows' DMAs are in flight while row i
+completes. There is no fallback: ``interpret=False`` (the default)
+compiles for the TPU or raises; CPU tests pass ``interpret=True``;
+``gather_rows_reference`` is the jnp oracle.
 """
 
 from __future__ import annotations
@@ -21,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._compat import pallas_tpu_compiler_params as _compiler_params
 from ._dma import pad_feature_dim
 
 # rows of the output processed by one grid step
@@ -94,7 +94,7 @@ def gather_rows(feat: jax.Array, ids: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((padded, dim), feat.dtype),
         interpret=interpret,
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(ids.astype(jnp.int32), feat)
     return out[:b, :out_dim]
 

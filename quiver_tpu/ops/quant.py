@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.placement import take_rows
+
 POLICIES = (None, "fp32", "fp16", "bf16", "int8")
 
 # per-row sidecar bytes for int8: fp32 scale + fp32 zero-point
@@ -204,12 +206,14 @@ def gather_rows(t, ids):
     """``jnp.take(t, ids, axis=0)`` with dequantization FUSED: a
     quantized tier reads ``[k, d]`` int8 + two ``[k, 1]`` sidecars and
     converts only the gathered rows — the whole-table width never moves.
-    ``ids`` must already be clipped in-range (callers own masking)."""
+    ``ids`` must already be clipped in-range (callers own masking).
+    A tier in pinned host memory is gathered there and its narrow rows
+    moved to the device before the decode (``placement.take_rows``)."""
     if not is_quantized(t):
-        return jnp.take(t, ids, axis=0)
-    code = jnp.take(t.data, ids, axis=0)
-    scale = jnp.take(t.scale, ids, axis=0)
-    zero = jnp.take(t.zero, ids, axis=0)
+        return take_rows(t, ids)
+    code = take_rows(t.data, ids)
+    scale = take_rows(t.scale, ids)
+    zero = take_rows(t.zero, ids)
     return code.astype(scale.dtype) * scale + zero
 
 

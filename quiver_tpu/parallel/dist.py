@@ -27,7 +27,7 @@ from __future__ import annotations
 from typing import Callable, Sequence
 
 import jax
-from .._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..comm import default_exchange_cap, dist_lookup_local

@@ -21,7 +21,7 @@ from typing import Any, Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import optax
-from .._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.sample_multihop import sample_multihop

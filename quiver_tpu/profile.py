@@ -20,7 +20,7 @@ Everything here runs OFF the hot path, as a separate profile pass:
 - the analytic cost model (``analysis.costmodel``, computed on the
   SAME shared trace qt-verify walks) supplies modeled bytes per stage,
   so every stage gets a roofline efficiency:
-  ``modeled_bytes / measured_time / probed_peak``.
+  ``modeled_bytes / measured_time / probed_rate``.
 
 Because the profiler is a separate pass over the same compiled
 programs, every hot-path invariant (zero per-step host syncs,
@@ -75,11 +75,13 @@ def _best_of(fn, reps: int) -> float:
 
 def machine_probe(quick: bool = False, reps: int = 3,
                   size_mb: Optional[int] = None) -> Dict[str, float]:
-    """One-shot measurement of what this box actually delivers:
-    achieved memcpy GB/s, random-gather GB/s (the tiered lookup's
-    access pattern), and host->device / device->host transfer GB/s.
-    These are the roofline DENOMINATORS — "% of probed peak" is
-    relative to this machine on this day, not a datasheet number.
+    """One-shot measurement of what small jitted programs achieve on
+    the backend this process runs on (``platform`` in the result):
+    memcpy GB/s, random-gather GB/s (the tiered lookup's access
+    pattern), and host->device / device->host transfer GB/s. They are
+    the denominators of the profiler's "% of probe" column — a
+    reference taken on this machine on this day, NOT the peak of any
+    device: nothing here knows a rate for a ``device_kind``.
 
     ``quick`` shrinks the working set (8 MB vs 64 MB) and the rep
     count; both sizes comfortably exceed cache on the bench boxes, so
@@ -308,8 +310,8 @@ class StageProfiler:
             del args
         return min(times), sum(times) / len(times)
 
-    def _peak_for(self, cost: Optional[CostModel]):
-        """The probe peak a stage rooflines against: the random-gather
+    def _probe_for(self, cost: Optional[CostModel]):
+        """The probed rate a stage is set against: the random-gather
         figure when gathers dominate its modeled traffic, memcpy
         otherwise."""
         if cost is None or self.probe is None:
@@ -353,10 +355,10 @@ class StageProfiler:
                     row["modeled"] = st.cost.record()
                     achieved = st.cost.total_bytes / best_s / 1e9
                     row["achieved_gbps"] = round(achieved, 3)
-                    peak_key, peak = self._peak_for(st.cost)
-                    if peak:
-                        row["peak"] = peak_key
-                        row["efficiency"] = round(achieved / peak, 4)
+                    probe_key, probed = self._probe_for(st.cost)
+                    if probed:
+                        row["probe"] = probe_key
+                        row["efficiency"] = round(achieved / probed, 4)
                 stages.append(row)
             records.append({"entry": group.name, "stages": stages,
                             "step_ms": round(ref_ms, 4),
@@ -381,8 +383,8 @@ class StageProfiler:
 
 def render_records(records: List[dict], color: bool = False) -> str:
     """The CLI table: one line per stage —
-    ``stage | mean ms | modeled bytes | achieved GB/s | % of probed
-    peak | % of step`` (shared by ``scripts/qt_prof.py`` and tests)."""
+    ``stage | mean ms | modeled bytes | achieved GB/s | % of probe
+    | % of step`` (shared by ``scripts/qt_prof.py`` and tests)."""
     GREEN, YELLOW, RED, DIM, RESET = ("\x1b[32m", "\x1b[33m",
                                       "\x1b[31m", "\x1b[2m", "\x1b[0m")
 
@@ -417,5 +419,5 @@ def render_records(records: List[dict], color: bool = False) -> str:
                 f"  {st['stage']:<24} {st['mean_ms']:>9.3f} ms  "
                 f"{mod.get('total_bytes', 0):>12,} B  "
                 f"{st.get('achieved_gbps', 0.0):>8.3f} GB/s  "
-                f"{eff_s} peak  {share_s} of step")
+                f"{eff_s} of probe  {share_s} of step")
     return "\n".join(lines)

@@ -557,7 +557,7 @@ def _feature_gather(feature):
         dev = devs[feature.rank if feature.rank < len(devs) else 0]
         leaves, tree = jax.tree_util.tree_flatten(feature.host_part)
         got = pinned_put(leaves, dev, True, "the serving cold tier",
-                         mesh=feature.mesh)
+                         mesh=feature.mesh, usage="gather")
         if got is not None:
             host = jax.tree_util.tree_unflatten(tree, got)
         else:
@@ -637,7 +637,7 @@ def build_sharded_serve_step(model, sizes: Sequence[int], batch_cap: int,
     ``build_serve_step`` over the same rows, not with the split sharded
     step."""
     from .comm import default_exchange_cap, dist_lookup_local
-    from ._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sizes = list(sizes)

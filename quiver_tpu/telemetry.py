@@ -86,7 +86,7 @@ ADVICE_KEYS = ("hot_capacity", "exchange_cap", "dedup_budget",
 
 class SeriesRing:
     """Fixed-capacity scalar time-series: append is O(1), memory is
-    ``capacity`` floats forever (a week-long chip_watch cannot grow
+    ``capacity`` floats forever (a week-long replica cannot grow
     it). Reads reconstruct chronological order from the write cursor;
     ``window_stats`` gives the recent-window mean/p50/p95 and
     ``ewma`` the exponentially-weighted level the detectors and the

@@ -5,24 +5,63 @@ tier. A silently different performance regime is the failure mode the
 reference guards with its CUDA check macros (quiver.cu.hpp:16-26), so
 backends without usable host-offload either warn via the package
 logger (allow_fallback=True) or raise.
+
+jax types the memory space of every value: one op takes operands of ONE
+space, so a device-space index vector cannot gather from a host-space
+table ("memory_space of all inputs passed to `gather` must be the
+same"). ``take_rows`` is the gather that respects that, and what the
+usability probe runs.
 """
 
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.compute_on import compute_on
 
 from ..debug import log as _log
 
-# (platform, mesh?) -> bool; a capability PROBE, not a platform
-# allowlist: the failure mode being guarded (today's CPU backend
-# ACCEPTS the pinned_host placement and then fails compiling any op
-# mixing host- and default-space operands — placement succeeds, every
-# later use raises) is a property of the installed jax/backend pair,
-# so it is probed with a tiny mixed-space op instead of hardcoding a
-# platform string that would silently force the fallback regime on a
-# future jax where CPU host-offload works. Probed per sharding FORM
-# (single-device vs mesh NamedSharding) because the two can differ.
-_USABLE: dict = {}
+
+def take_rows(table, ids):
+    """``jnp.take(table, ids, axis=0)`` for a table in either memory
+    space; ``ids`` must be in range. Rows of a pinned-host table are
+    gathered ON the host (ids moved there, the gather run as host
+    compute) and only the gathered rows cross to the device — the form
+    the v5e accepts; the same gather left to the device aborts XLA's
+    host offloader."""
+    if jax.typeof(table).memory_space != jax.memory.Space.Host:
+        return jnp.take(table, ids, axis=0)
+    ids = jax.device_put(ids, jax.memory.Space.Host)
+    with compute_on("device_host"):
+        rows = table.at[ids].get(mode="promise_in_bounds")
+    return jax.device_put(rows, jax.memory.Space.Device)
+
+# (usage, platform, mesh?) -> refusal; a capability PROBE, not a
+# platform allowlist: whether a backend that ACCEPTS the pinned_host
+# placement can also run what the caller will do with the arrays is a
+# property of the installed jax/backend pair, so it is probed with a
+# tiny instance of that usage instead of hardcoding a platform string.
+# Probed per sharding FORM (single-device vs mesh NamedSharding) because
+# the two can differ. Value: None when usable, else the refusal.
+_REFUSAL: dict = {}
+
+
+def _probe_gather(host, main):
+    """What the feature tiers do: gather host rows by device ids."""
+    import numpy as np
+    ids = jax.device_put(np.array([5, 1], np.int32), main.sharding)
+    got = np.asarray(jax.jit(take_rows)(host.reshape(8, 1), ids))
+    if got.tolist() != [[5.0], [1.0]]:
+        raise NotImplementedError(f"host gather returned {got}")
+
+
+def _probe_mixed(host, main):
+    """What the HOST-mode sampler does: ordinary device ops straight
+    over host-space and device-space operands."""
+    float(jax.jit(lambda h, m: (h + m).sum())(host, main))
+
+
+_PROBES = {"gather": _probe_gather, "mixed": _probe_mixed}
 
 
 def _definitive(e: Exception) -> bool:
@@ -35,10 +74,11 @@ def _definitive(e: Exception) -> bool:
         "memory_kind" in msg or "pinned_host" in msg
 
 
-def _host_offload_usable(dev, mesh=None) -> bool:
-    key = (getattr(dev, "platform", None), mesh is not None)
-    got = _USABLE.get(key)
-    if got is None:
+def _host_offload_refusal(usage, dev, mesh=None):
+    """None when ``usage`` of pinned host arrays works here, else why
+    it does not (the backend's or jax's own words)."""
+    key = (usage, getattr(dev, "platform", None), mesh is not None)
+    if key not in _REFUSAL:
         import numpy as np
         try:
             if mesh is not None:
@@ -50,25 +90,29 @@ def _host_offload_usable(dev, mesh=None) -> bool:
             else:
                 sh = jax.sharding.SingleDeviceSharding(
                     dev, memory_kind="pinned_host")
-                main_sh = dev
-            host = jax.device_put(np.ones((8,), np.float32), sh)
-            main = jax.device_put(np.ones((8,), np.float32), main_sh)
-            # the exact usage pattern the offload tiers need: one jitted
-            # computation over a host-space and a default-space operand
-            float(jax.jit(lambda h, m: (h + m).sum())(host, main))
-            got = True
+                main_sh = jax.sharding.SingleDeviceSharding(dev)
+            _PROBES[usage](
+                jax.device_put(np.arange(8, dtype=np.float32), sh),
+                jax.device_put(np.ones((8,), np.float32), main_sh))
+            _REFUSAL[key] = None
         except Exception as e:  # noqa: BLE001 - classify, maybe cache
+            why = f"{type(e).__name__}: {e}"
             if not _definitive(e):
-                return False    # transient: fail this call, don't cache
-            got = False
-        _USABLE[key] = got
-    return got
+                return why      # transient: fail this call, don't cache
+            _REFUSAL[key] = why
+    return _REFUSAL[key]
 
 
-def pinned_put(arrays, dev, allow_fallback, what, mesh=None):
+def pinned_put(arrays, dev, allow_fallback, what, mesh=None,
+               usage: str = "mixed"):
     """Place ``arrays`` on pinned host memory. Returns the placed list,
     or None after a LOUD log when ``allow_fallback`` and the placement
     is unusable; raises otherwise.
+
+    ``usage`` names what the caller will do with the arrays inside jit,
+    which is what gets probed: ``"gather"`` (rows through ``take_rows``,
+    the feature tiers) or ``"mixed"`` (device ops straight over them,
+    the HOST-mode sampler — which jax 0.9.0 refuses on every backend).
 
     With ``mesh`` the arrays are placed host-replicated over the mesh
     (``NamedSharding(mesh, P(), memory_kind='pinned_host')``) so they
@@ -76,15 +120,15 @@ def pinned_put(arrays, dev, allow_fallback, what, mesh=None):
     single-device pinned arrays and mesh-sharded arrays have
     incompatible device sets and fail at dispatch.
 
-    Usability is established by ``_host_offload_usable``'s probe (one
-    tiny mixed-memory-space op per platform, cached); the TPU side is
-    additionally measured on chip by benchmarks/host_mode_probe.py."""
+    Usability is established by ``_host_offload_refusal``'s probe (one
+    tiny instance of the usage per platform, cached)."""
     try:
         probe_dev = mesh.devices.flat[0] if mesh is not None else dev
-        if not _host_offload_usable(probe_dev, mesh=mesh):
+        why = _host_offload_refusal(usage, probe_dev, mesh=mesh)
+        if why is not None:
             raise NotImplementedError(
-                "this backend accepts pinned_host placement but cannot "
-                "compile mixed-memory-space ops (probed)")
+                f"pinned_host arrays cannot be used this way here "
+                f"(probed {usage!r}: {why[:500]})")
         if mesh is not None:
             sh = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec(),

@@ -8,9 +8,9 @@ history (``{"ts", "kind": "bench", ...}`` records from ``bench.py`` /
 round order:
 
 - records with ``"skipped": true`` or ``value: null`` are SKIPPED, not
-  failed — the r03-r05 rounds were TPU-infra-unavailable, which is an
-  outage, not a regression (``bench.py`` emits the distinguishable
-  skip record for exactly this consumer);
+  failed — the committed r03-r05 records carry no number, which is
+  no evidence of a regression (``bench.py`` itself no longer writes
+  such a record: without a chip it fails);
 - values are grouped by ``(metric, platform)`` so a ``cpu-smoke`` run
   is never compared against a TPU number; beside the headline
   ``value``, the auxiliary rate keys in ``SUB_METRICS``
@@ -124,7 +124,7 @@ def load_trajectory(bench_dir):
 def load_jsonl(path, since=None):
     """``[(label, record)]`` from a shared-schema metrics JSONL file —
     only ``kind: bench`` measurement records (other kinds — step_stats,
-    serving, slo, canary... — are not trajectory points), and only
+    serving, slo... — are not trajectory points), and only
     those with ``ts >= since`` when a scope is given. Reads across the
     ``MetricsSink`` rollover seam: the rolled-over ``<path>.1`` (older
     half) is consumed before ``<path>``, so a size-bounded sink loses
@@ -167,7 +167,7 @@ def emit_verdicts(path, records, kind="regress"):
 
 
 def is_skipped(rec):
-    """The outage convention: an explicitly skipped round, or one that
+    """An explicitly skipped round (older records), or one that
     produced no number at all, is not evidence of a regression."""
     return bool(rec.get("skipped")) or rec.get("value") is None
 

@@ -10,7 +10,7 @@ arities), the analytic cost model on the same shared trace qt-verify
 walks, and a one-shot machine probe (achieved memcpy / random-gather /
 host<->device bandwidth on THIS box) — and prints one line per stage:
 
-    stage | mean ms | modeled bytes | achieved GB/s | % of probed peak
+    stage | mean ms | modeled bytes | achieved GB/s | % of probe
           | % of step
 
 Runs entirely OFF the hot path on the CPU backend (same forced
@@ -53,14 +53,11 @@ def _ensure_cpu_platform():
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     # share the bench/test persistent compile cache: qt_prof runs as a
     # subprocess in tier-1 CLI tests, and its stage programs are
     # identical run to run
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(_ROOT, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from quiver_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
 
 
 def main(argv=None) -> int:

@@ -381,12 +381,12 @@ def render(path, limit, width, color=True, fleet_only=False):
     for (entry, stage) in sorted(prof)[:12]:
         st = prof[(entry, stage)]
         eff = st.get("efficiency")
-        # efficiency colored by threshold: >=50% of the probed peak is
+        # efficiency colored by threshold: >=50% of the probed rate is
         # healthy for a dispatch-bound stage, <15% is leaving the
         # hardware idle
         tint = (DIM if not _num(eff) else GREEN if eff >= 0.5
                 else YELLOW if eff >= 0.15 else RED)
-        eff_s = f"{100 * eff:.1f}% peak" if _num(eff) else "n/a"
+        eff_s = f"{100 * eff:.1f}% of probe" if _num(eff) else "n/a"
         share = st.get("share")
         share_s = f"{100 * share:.0f}% of step" if _num(share) else ""
         lines.append(c(tint,

@@ -1,0 +1,177 @@
+"""Every Pallas kernel of ``quiver_tpu/ops/pallas`` compiles for the chip.
+
+No chip is needed: the TPU's compiler is installed, and it compiles for a
+``v5e:2x2`` device that is described, not attached
+(``jax.experimental.topologies``). What it refuses here (a block shape,
+an unaligned DMA slice, too much scratch) the chip would refuse too, and
+interpret mode never sees it. Sizes are ogbn-products': 2,449,029 nodes,
+123,718,280 edge slots, float32 features, batch 1024, ``row_cap`` 2048,
+the on-core PRNG, ``interpret=False``.
+
+All of them live in this ONE file: only one process at a time can load
+the TPU's library, so the topology is described inside a module-scoped
+fixture, by the one worker this file is given to, and nothing touches it
+at import. A compile for a described device is written to the persistent
+cache but cannot be read back without a chip, so the cache is off around
+these tests.
+"""
+
+import contextlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from quiver_tpu.ops.pallas import _dma, fused, gather, sample_kernel
+
+NODES = 2_449_029
+EDGES = 123_718_280
+BATCH = 1024
+ROW_CAP = 2048
+K = 15
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape_on_chip(topo):
+    """``shape_on_chip(shape, dtype)``: an abstract array on the first
+    described chip, with the persistent compile cache off while the
+    module's tests run."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def graph(shape_on_chip):
+    """(indptr, indices_padded, seeds, kernel seed, key data) shapes."""
+    padded = jax.eval_shape(
+        lambda x: _dma.pad_indices(x, ROW_CAP),
+        jax.ShapeDtypeStruct((EDGES,), jnp.int32))
+    return (shape_on_chip((NODES + 1,), jnp.int32),
+            shape_on_chip(padded.shape, padded.dtype),
+            shape_on_chip((BATCH,), jnp.int32),
+            shape_on_chip((), jnp.int32),
+            shape_on_chip((2,), jnp.uint32))
+
+
+def _kernels_in(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("dim", [100, 128])
+def test_fused_hot_hop(graph, shape_on_chip, dim):
+    indptr, indices, seeds, seed, _ = graph
+    feat = shape_on_chip((NODES, dim), jnp.float32)
+
+    def fn(indptr, indices, seeds, feat, seed):
+        return fused.fused_hot_hop(indptr, indices, seeds, feat, K, seed,
+                                   row_cap=ROW_CAP, rng="tpu",
+                                   interpret=False)
+
+    # width 100 pads the table to 128 lanes (with its trace-time warning)
+    with pytest.warns(UserWarning) if dim % 128 else contextlib.nullcontext():
+        assert _kernels_in(fn, indptr, indices, seeds, feat, seed) == 1
+
+
+def test_fused_hot_hop_feature_order(graph, shape_on_chip):
+    """The tiered serve path: old id -> storage row translated in-kernel,
+    rows past ``hot_rows`` masked."""
+    indptr, indices, seeds, seed, _ = graph
+    hot = NODES // 5
+    feat = shape_on_chip((hot, 128), jnp.float32)
+    order = shape_on_chip((NODES,), jnp.int32)
+
+    def fn(indptr, indices, seeds, feat, seed, order):
+        return fused.fused_hot_hop(indptr, indices, seeds, feat, K, seed,
+                                   row_cap=ROW_CAP, rng="tpu",
+                                   interpret=False, feature_order=order,
+                                   hot_rows=hot)
+
+    assert _kernels_in(fn, indptr, indices, seeds, feat, seed, order) == 1
+
+
+def test_fused_hot_hop_refuses_int8_table_uncompiled(graph):
+    """The one kernel variant the chip's compiler refuses (a per-row DMA
+    out of an int8 table, four rows to a sublane): it raises with the
+    compiler's reason and falls back to nothing."""
+    from quiver_tpu.ops import quant
+    indptr, indices, seeds, seed, _ = graph
+    feat = jax.eval_shape(lambda x: quant.quantize(x, "int8"),
+                          jax.ShapeDtypeStruct((4096, 128), jnp.float32))
+
+    def fn(indptr, indices, seeds, feat, seed):
+        return fused.fused_hot_hop(indptr, indices, seeds, feat, K, seed,
+                                   row_cap=ROW_CAP, rng="tpu",
+                                   interpret=False)
+
+    with pytest.raises(NotImplementedError, match="aligned to tiling"):
+        jax.jit(fn).lower(indptr, indices, seeds, feat, seed)
+
+
+def test_fused_sample_hop(graph):
+    indptr, indices, seeds, seed, _ = graph
+
+    def fn(indptr, indices, seeds, seed):
+        return fused.fused_sample_hop(indptr, indices, seeds, K, seed,
+                                      row_cap=ROW_CAP, rng="tpu",
+                                      interpret=False)
+
+    assert _kernels_in(fn, indptr, indices, seeds, seed) == 1
+
+
+def test_fused_multihop(graph, shape_on_chip):
+    """The whole [15, 10, 5] walk as one program: two sampling-only hops
+    and the sample+gather leaf. Batch 128, not 1024: the kernels' blocks
+    are the same, and the XLA sorts between the hops (which no test here
+    is about) compile in a sixth of the time."""
+    indptr, indices, _, _, key = graph
+    seeds = shape_on_chip((128,), jnp.int32)
+    feat = shape_on_chip((NODES, 128), jnp.float32)
+
+    def fn(indptr, indices, seeds, feat, key):
+        return fused.fused_multihop(
+            indptr, indices, seeds, feat, (15, 10, 5),
+            jax.random.wrap_key_data(key), row_cap=ROW_CAP, rng="tpu",
+            interpret=False)
+
+    assert _kernels_in(fn, indptr, indices, seeds, feat, key) == 3
+
+
+def test_sample_layer_pallas(graph):
+    indptr, indices, seeds, seed, _ = graph
+
+    def fn(indptr, indices, seeds, seed):
+        return sample_kernel.sample_layer_pallas(
+            indptr, indices, seeds, K, seed, row_cap=ROW_CAP, rng="tpu",
+            interpret=False)
+
+    assert _kernels_in(fn, indptr, indices, seeds, seed) == 1
+
+
+@pytest.mark.parametrize("dim", [100, 128])
+def test_gather_rows(graph, shape_on_chip, dim):
+    _, _, seeds, _, _ = graph
+    feat = shape_on_chip((NODES, dim), jnp.float32)
+    # width 100 pads the table to 128 lanes (with its trace-time warning)
+    with pytest.warns(UserWarning) if dim % 128 else contextlib.nullcontext():
+        assert _kernels_in(
+            lambda feat, ids: gather.gather_rows(feat, ids, interpret=False),
+            feat, seeds) == 1

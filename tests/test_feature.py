@@ -946,15 +946,46 @@ class TestOffloadHostTier:
         np.testing.assert_allclose(np.asarray(f2[jnp.asarray(ids)]),
                                    feat[ids], rtol=1e-6)
 
-    def test_offload_on_cpu_falls_back_loudly(self, caplog):
-        import logging
+    def test_offload_pins_the_cold_tier(self):
+        # the host-space gather (placement.take_rows) runs on this
+        # backend, so the cold tier really is a pinned_host array and
+        # the lookup is one fused dispatch
         rng = np.random.default_rng(0)
         feat = rng.standard_normal((50, 8)).astype(np.float32)
         f = qv.Feature(device_cache_size=10 * 8 * 4,
-                       host_placement="offload")
+                       host_placement="offload", allow_fallback=False)
+        f.from_cpu_tensor(feat)
+        assert f.host_part is None
+        assert f._host_offload.sharding.memory_kind == "pinned_host"
+        ids = np.array([0, 9, 10, 49])
+        np.testing.assert_array_equal(np.asarray(f[jnp.asarray(ids)]),
+                                      feat[ids])
+
+    @pytest.mark.parametrize("allow_fallback", [True, False])
+    def test_offload_refused_falls_back_loudly_or_raises(
+            self, caplog, monkeypatch, allow_fallback):
+        # a backend that refuses the host-space gather: the refusal is
+        # logged and the numpy tier serves (allow_fallback), or raised
+        import logging
+        from quiver_tpu.utils import placement
+
+        def refuse(host, main):
+            raise NotImplementedError("no pinned_host gather here")
+
+        monkeypatch.setitem(placement._PROBES, "gather", refuse)
+        monkeypatch.setattr(placement, "_REFUSAL", {})
+        rng = np.random.default_rng(0)
+        feat = rng.standard_normal((50, 8)).astype(np.float32)
+        f = qv.Feature(device_cache_size=10 * 8 * 4,
+                       host_placement="offload",
+                       allow_fallback=allow_fallback)
+        if not allow_fallback:
+            with pytest.raises(ValueError, match="no pinned_host gather"):
+                f.from_cpu_tensor(feat)
+            return
         with caplog.at_level(logging.INFO, logger="quiver_tpu"):
             f.from_cpu_tensor(feat)
-        assert f._host_offload is None                  # CPU: gated out
+        assert f._host_offload is None
         assert any("pinned_host" in r.message for r in caplog.records)
         ids = np.array([0, 9, 10, 49])
         np.testing.assert_allclose(np.asarray(f[jnp.asarray(ids)]),

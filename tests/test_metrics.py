@@ -445,7 +445,7 @@ class TestMetricsSink:
         with qm.MetricsSink(path) as sink:
             sink.emit_stats(stats)
             sink.emit({"usable": True, "h2d_MBps": 120.0},
-                      kind="canary")
+                      kind="bench")
             sink.emit({"value": np.float64(1.5),
                        "arr": np.arange(2)})     # numpy-safe encoding
         with open(path) as f:
@@ -458,7 +458,7 @@ class TestMetricsSink:
         assert recs[1]["kind"] == "step_stats"
         assert recs[1]["counters"]["frontier_valid"] == 30
         assert recs[1]["derived"]["frontier_fill"] == pytest.approx(0.75)
-        assert recs[2]["kind"] == "canary" and recs[2]["usable"] is True
+        assert recs[2]["kind"] == "bench" and recs[2]["usable"] is True
         assert recs[3]["arr"] == [0, 1]
 
 

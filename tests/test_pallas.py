@@ -1,26 +1,16 @@
-"""Pallas kernel tests (interpret mode on CPU via the kernels' own
-`interpret=` arg — version-proof where `force_tpu_interpret_mode` is
-not; the jnp ops are the
-oracles)."""
+"""Pallas kernel tests: interpret mode on CPU via the kernels' own
+``interpret=`` argument, with the portable "hash" PRNG the kernels
+default to off-TPU (``_dma.default_rng``; the on-core generator has no
+CPU lowering). The jnp ops are the oracles; ``tests/test_chip_compile.py``
+asks the chip's compiler about the same kernels."""
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 from quiver_tpu.ops.pallas.gather import gather_rows, gather_rows_reference
 from quiver_tpu.ops.pallas.sample_kernel import (
     BLOCK, pad_indices, sample_layer_pallas)
-
-# the sample kernel uses the TPU-native prng primitives (pltpu.prng_seed
-# / prng_random_bits); only jax versions shipping
-# force_tpu_interpret_mode can emulate those on CPU — older interpret
-# mode has no CPU lowering for them, so the kernel is untestable there
-# (the gather kernel has no prng and interprets everywhere)
-_TPU_PRNG_INTERPRETABLE = hasattr(pltpu, "force_tpu_interpret_mode")
-needs_tpu_prng = pytest.mark.skipif(
-    not _TPU_PRNG_INTERPRETABLE,
-    reason="this jax cannot interpret pltpu prng primitives on CPU")
 
 
 class TestGatherKernel:
@@ -50,7 +40,6 @@ def graph(rng):
     return indptr, indices
 
 
-@needs_tpu_prng
 class TestSampleKernel:
     def test_membership_counts_distinct(self, graph, rng):
         indptr, indices = graph

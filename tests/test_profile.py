@@ -232,9 +232,12 @@ class TestStageProfiler:
 
     def test_ref_stage_share_semantics(self):
         # wide scale separation: both stages are dispatch-bound at
-        # tiny scan lengths, which would let noise push part >= whole
+        # tiny scan lengths, which would let noise push part >= whole.
+        # "whole" runs for milliseconds, so that one scheduler stall
+        # under the six-worker suite (which 120 steps did not survive)
+        # cannot carry a one-matmul "part" past it
         g = ProfileGroup("withref", [_matmul_stage("part", 1),
-                                     _matmul_stage("whole", 120)],
+                                     _matmul_stage("whole", 4000)],
                          ref_stage="whole")
         prof = StageProfiler(reps=2)
         prof.add_group(g)
@@ -269,9 +272,11 @@ class TestInjectedSlowdown:
         base_share = float(np.median(series.values()[-8:]))
         assert base_share == pytest.approx(0.5, abs=0.25)
 
-        # deploy the de-optimized variant of stage B (50x the work)
+        # deploy the de-optimized variant of stage B (1000x the work:
+        # at 50x both stages were still dispatch-bound microseconds, and
+        # under the six-worker suite the share stalled at 0.73)
         slow = StageProfiler(reps=2, hub=hub)
-        slow.add_group(_group(scale_a=2, scale_b=100))
+        slow.add_group(_group(scale_a=2, scale_b=2000))
         for _ in range(8):
             slow.run()
         slow_share = float(np.median(series.values()[-8:]))

@@ -326,16 +326,18 @@ class TestRotationSampler:
         counts = np.zeros(deg, np.int64)
         for t in range(16):
             nbrs, _ = sample_layer_window(
-                jnp.asarray(indptr), rows, jnp.zeros((80,), jnp.int32),
+                jnp.asarray(indptr), rows, jnp.zeros((320,), jnp.int32),
                 8, jax.random.key(t))
             got = np.asarray(nbrs).ravel()
             np.add.at(counts, got[got >= 0], 1)
-        # the deep interior (past the edge ramp) is hit and near-uniform
+        # the deep interior (past the edge ramp) is hit and near-uniform:
+        # ~70 draws land on each position, so one position sits within
+        # 0.8 of the interior's mean by more than 6 sigma (the former
+        # reference, the count of position 300 alone out of ~17, was
+        # as noisy as what it was compared with)
         inner = counts[260:340]
         assert (inner > 0).all()
-        freq = inner / counts.sum()
-        np.testing.assert_allclose(freq, counts[300] / counts.sum(),
-                                   rtol=0.8)
+        np.testing.assert_allclose(inner, inner.mean(), rtol=0.8)
         # positions far beyond the first window are sampled at all —
         # the start-anchored design gave these exactly zero mass
         assert counts[400:].sum() > 0

@@ -401,10 +401,15 @@ class TestQtTraceCli:
         assert out.returncode == 0, out.stdout + out.stderr
         assert "2 kept traces" in out.stdout
         assert "client+r0" in out.stdout         # assembled replicas
-        errs = self.run_cli("--jsonl", p, "--errors")
-        assert "12" in errs.stdout and "11" not in errs.stdout
-        slow = self.run_cli("--jsonl", p, "--slowest", "1")
-        assert "11" in slow.stdout and "12" not in slow.stdout
+        # the header line carries the wall clock, whose digits can spell
+        # either trace id: judge the table rows only
+        def rows(*args):
+            return self.run_cli("--jsonl", p, *args).stdout.split("\n", 1)[1]
+
+        errs = rows("--errors")
+        assert "12" in errs and "11" not in errs
+        slow = rows("--slowest", "1")
+        assert "11" in slow and "12" not in slow
 
     def test_detail_and_export(self, tmp_path):
         p = self._sink(tmp_path)
