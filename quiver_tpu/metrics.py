@@ -40,8 +40,7 @@ payload whose ``wall`` block times BATCH dispatches, plus a ``request``
 block with per-REQUEST admission->result latency percentiles and a
 ``serving`` block with admission/shed/variant-mix counts), ``slo``
 (:class:`SloBudget.snapshot` — error-budget burn rates),
-``scope_timer`` (``profiling.ScopeTimer.emit`` — accumulated wall-clock
-stage timings), ``anomaly`` / ``advice``
+``anomaly`` / ``advice``
 (``telemetry.TelemetryHub`` — change-point detections and advisory
 re-planning records), ``regress`` (``scripts/bench_regress.py`` —
 per-trajectory-group verdicts), ``profile``

@@ -17,20 +17,23 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
+
 
 def masked_mean_aggregate(x_src: jax.Array, edge_index: jax.Array,
                           num_targets: int) -> jax.Array:
     """Mean of neighbor features per target node. edge_index [2, E] with
     row 0 = source local id, row 1 = target local id, -1 fill."""
-    src, dst = edge_index[0], edge_index[1]
-    valid = (src >= 0) & (dst >= 0)
-    s = jnp.where(valid, src, 0)
-    d = jnp.where(valid, dst, 0)
-    msg = x_src[s] * valid[:, None].astype(x_src.dtype)
-    agg = jax.ops.segment_sum(msg, d, num_segments=num_targets)
-    cnt = jax.ops.segment_sum(valid.astype(x_src.dtype), d,
-                              num_segments=num_targets)
-    return agg / jnp.maximum(cnt, 1.0)[:, None]
+    with profiling.scope(profiling.QT_AGGREGATE):
+        src, dst = edge_index[0], edge_index[1]
+        valid = (src >= 0) & (dst >= 0)
+        s = jnp.where(valid, src, 0)
+        d = jnp.where(valid, dst, 0)
+        msg = x_src[s] * valid[:, None].astype(x_src.dtype)
+        agg = jax.ops.segment_sum(msg, d, num_segments=num_targets)
+        cnt = jax.ops.segment_sum(valid.astype(x_src.dtype), d,
+                                  num_segments=num_targets)
+        return agg / jnp.maximum(cnt, 1.0)[:, None]
 
 
 class SAGEConv(nn.Module):

@@ -9,6 +9,7 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
 from .sample import (LayerSample, as_index_rows, as_index_rows_overlapping,
                      compact_layer, edge_rows, permute_csr, sample_layer,
                      sample_layer_exact_wide, sample_layer_rotation,
@@ -145,56 +146,61 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
       # named scope per hop: XProf traces attribute time to hop stages
       # instead of one opaque multihop blob
       with jax.named_scope(f"qt_sample_hop{i}"):
-        sub = jax.random.fold_in(key, i)
         slots = None
-        if edge_weight is not None and windowed and weight_rows is not None:
-            if indices_rows is None:
-                raise ValueError(
-                    "windowed weighted sampling needs indices_rows from "
-                    "the same shuffle as weight_rows (reshuffle_csr with "
-                    "extra=(edge_weight,), then as_index_rows* both)")
-            out = sample_layer_weighted_window(
-                indptr, indices_rows, weight_rows, cur, k, sub,
-                stride=indices_stride, with_slots=track_eid)
-        elif edge_weight is not None:
-            out = sample_layer_weighted(indptr, indices, edge_weight,
-                                        cur, k, sub, with_slots=track_eid)
-        elif method == "rotation":
-            out = sample_layer_rotation(indptr, indices_rows, cur, k, sub,
+        # the hop's two halves, named apart: the one draw (whichever
+        # method arm, with its key) and the compaction with its e_id
+        # bookkeeping
+        with profiling.scope(profiling.QT_DRAW):
+          sub = jax.random.fold_in(key, i)
+          if edge_weight is not None and windowed and weight_rows is not None:
+              if indices_rows is None:
+                  raise ValueError(
+                      "windowed weighted sampling needs indices_rows from "
+                      "the same shuffle as weight_rows (reshuffle_csr with "
+                      "extra=(edge_weight,), then as_index_rows* both)")
+              out = sample_layer_weighted_window(
+                  indptr, indices_rows, weight_rows, cur, k, sub,
+                  stride=indices_stride, with_slots=track_eid)
+          elif edge_weight is not None:
+              out = sample_layer_weighted(indptr, indices, edge_weight,
+                                          cur, k, sub, with_slots=track_eid)
+          elif method == "rotation":
+              out = sample_layer_rotation(indptr, indices_rows, cur, k, sub,
+                                          with_slots=track_eid,
+                                          stride=indices_stride)
+          elif method == "window":
+              out = sample_layer_window(indptr, indices_rows, cur, k, sub,
                                         with_slots=track_eid,
                                         stride=indices_stride)
-        elif method == "window":
-            out = sample_layer_window(indptr, indices_rows, cur, k, sub,
-                                      with_slots=track_eid,
-                                      stride=indices_stride)
-        elif indices_rows is not None:
-            # exact + rows layout = the wide-fetch exact draw (same
-            # contract as sample_layer, fewer scattered loads); the
-            # rows view MUST be of the same un-shuffled ``indices``.
-            # The hub budget is static per hop: frontier width is a
-            # compile-time shape and hub_frac is cached graph metadata
-            out = sample_layer_exact_wide(
-                indptr, indices, indices_rows, cur, k, sub,
-                stride=indices_stride, with_slots=track_eid,
-                hub_cap=suggest_hub_cap(int(cur.shape[0]), hub_frac))
-        else:
-            out = sample_layer(indptr, indices, cur, k, sub,
-                               with_slots=track_eid)
+          elif indices_rows is not None:
+              # exact + rows layout = the wide-fetch exact draw (same
+              # contract as sample_layer, fewer scattered loads); the
+              # rows view MUST be of the same un-shuffled ``indices``.
+              # The hub budget is static per hop: frontier width is a
+              # compile-time shape and hub_frac is cached graph metadata
+              out = sample_layer_exact_wide(
+                  indptr, indices, indices_rows, cur, k, sub,
+                  stride=indices_stride, with_slots=track_eid,
+                  hub_cap=suggest_hub_cap(int(cur.shape[0]), hub_frac))
+          else:
+              out = sample_layer(indptr, indices, cur, k, sub,
+                                 with_slots=track_eid)
         nbrs = out[0]
         if track_eid:
             slots = out[2]
-        # hop >= 1 seeds are the previous hop's n_id — valid-first by
-        # _compact_core's own output invariant — so the cheaper dense
-        # seed path is always safe there; hop 0 takes it only when the
-        # caller promises a valid-first batch (``seeds_dense``)
-        layer = compact_layer(cur, nbrs, seeds_dense=(i > 0) or seeds_dense)
-        if track_eid:
-            flat = slots.reshape(-1)
-            if eid is True:
-                ids = flat
-            else:
-                ids = jnp.asarray(eid)[jnp.clip(flat, 0)]
-            layer = layer._replace(e_id=jnp.where(flat >= 0, ids, -1))
+        with profiling.scope(profiling.QT_COMPACT):
+          # hop >= 1 seeds are the previous hop's n_id — valid-first by
+          # _compact_core's own output invariant — so the cheaper dense
+          # seed path is always safe there; hop 0 takes it only when the
+          # caller promises a valid-first batch (``seeds_dense``)
+          layer = compact_layer(cur, nbrs, seeds_dense=(i > 0) or seeds_dense)
+          if track_eid:
+              flat = slots.reshape(-1)
+              if eid is True:
+                  ids = flat
+              else:
+                  ids = jnp.asarray(eid)[jnp.clip(flat, 0)]
+              layer = layer._replace(e_id=jnp.where(flat >= 0, ids, -1))
         layers.append(layer)
         cur = layer.n_id
     if collector is not None:

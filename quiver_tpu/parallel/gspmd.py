@@ -26,10 +26,9 @@ from __future__ import annotations
 from typing import Callable, Sequence
 
 import jax
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .train import (TrainState, _check_rows, _fused_loss,
+from .train import (TrainState, _apply_update, _check_rows, _fused_loss,
                     cross_entropy_logits)
 
 
@@ -87,9 +86,7 @@ def build_gspmd_train_step(model, tx, sizes: Sequence[int], mesh: Mesh,
                                   rows[0] if rows else None,
                                   indices_stride)
         )(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss
+        return _apply_update(state, tx, grads), loss
 
     repl = NamedSharding(mesh, P())
     data = NamedSharding(mesh, P(data_axis))

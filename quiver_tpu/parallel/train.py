@@ -24,6 +24,7 @@ import optax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import profiling
 from ..ops.sample_multihop import sample_multihop
 from ..profiling import hot_path
 from ..pyg.sage_sampler import Adj, layer_shapes
@@ -65,12 +66,13 @@ def masked_feature_gather(feat, n_id: jax.Array,
     consumes float activations unchanged. ``collector`` is accepted for
     gather-protocol uniformity (single-tier: nothing tiered to count)."""
     from ..ops import quant
-    ids = n_id
-    if feature_order is not None:
-        ids = feature_order[jnp.clip(n_id, 0)]
-    safe = jnp.clip(ids, 0, quant.tier_rows(feat) - 1)
-    x = quant.gather_rows(feat, safe)
-    return x * (n_id >= 0).astype(x.dtype)[:, None]
+    with profiling.scope(profiling.QT_GATHER):
+        ids = n_id
+        if feature_order is not None:
+            ids = feature_order[jnp.clip(n_id, 0)]
+        safe = jnp.clip(ids, 0, quant.tier_rows(feat) - 1)
+        x = quant.gather_rows(feat, safe)
+        return x * (n_id >= 0).astype(x.dtype)[:, None]
 
 
 @hot_path
@@ -93,8 +95,6 @@ def dedup_feature_gather(feat, n_id: jax.Array,
     if budget >= n:
         return masked_feature_gather(feat, n_id, feature_order)
     valid = n_id >= 0
-    uniq, inv, n_uniq = unique_within_budget(n_id, budget, valid=valid,
-                                             collector=collector)
 
     def narrow(_):
         # uniq's int32-max fill clips to the LAST feature row — those
@@ -105,10 +105,14 @@ def dedup_feature_gather(feat, n_id: jax.Array,
         x = jnp.take(rows_u, inv, axis=0)
         return x * valid.astype(x.dtype)[:, None]
 
-    return jax.lax.cond(n_uniq > budget,
-                        lambda _: masked_feature_gather(feat, n_id,
-                                                        feature_order),
-                        narrow, None)
+    # one scope over the unique table, both reads and the expansion
+    with profiling.scope(profiling.QT_GATHER):
+        uniq, inv, n_uniq = unique_within_budget(n_id, budget, valid=valid,
+                                                 collector=collector)
+        return jax.lax.cond(n_uniq > budget,
+                            lambda _: masked_feature_gather(feat, n_id,
+                                                            feature_order),
+                            narrow, None)
 
 
 def _fused_multihop_x(feat, forder, indptr, indices, seeds, sizes, key,
@@ -211,9 +215,11 @@ def _fused_loss(model, loss_fn, sizes, batch_size, params, feat, forder,
         x = (gather or masked_feature_gather)(feat, n_id, forder,
                                               collector=collector)
     adjs = layers_to_adjs(layers, batch_size, sizes)
-    logits = model.apply(params, x, adjs, train=True,
-                         rngs={"dropout": jax.random.fold_in(key, 1000)})
-    return loss_fn(logits[:batch_size], labels)
+    with profiling.scope(profiling.QT_FORWARD):
+        logits = model.apply(params, x, adjs, train=True,
+                             rngs={"dropout": jax.random.fold_in(key, 1000)})
+    with profiling.scope(profiling.QT_LOSS):
+        return loss_fn(logits[:batch_size], labels)
 
 
 def _check_rows(method: str, indices_rows, kind: str) -> bool:
@@ -233,14 +239,20 @@ def _check_rows(method: str, indices_rows, kind: str) -> bool:
     return windowed
 
 
+def _apply_update(state, tx, grads) -> TrainState:
+    """The optimizer's step, shared by every builder."""
+    with profiling.scope(profiling.QT_OPTIMIZER):
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
+    return TrainState(params, opt_state, state.step + 1)
+
+
 def _pmean_update(state, tx, grads, loss, axis):
     """Cross-shard gradient/loss reduction + optimizer update (shared by
     the shard_map builders)."""
     grads = jax.lax.pmean(grads, axis)
     loss = jax.lax.pmean(loss, axis)
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, state.step + 1), loss
+    return _apply_update(state, tx, grads), loss
 
 
 def _check_donatable(kind, fn, checked, state, *args, **kwargs):
@@ -411,9 +423,7 @@ def build_train_step(model, tx, sizes: Sequence[int], batch_size: int,
                                        gather=gather, hub_frac=hub_frac,
                                        collector=col, fused=fused))
         loss, counters, grads = unpack(loss_of(state.params))
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(params, opt_state, state.step + 1)
+        new_state = _apply_update(state, tx, grads)
         if collect_metrics:
             return new_state, loss, counters
         return new_state, loss
@@ -581,14 +591,14 @@ def build_split_train_step(model, tx, sizes: Sequence[int], batch_size: int,
 
     def step_fn_raw(state: TrainState, x, adjs, labels, key):
         def loss_of(p):
-            logits = model.apply(p, x, adjs, train=True,
-                                 rngs={"dropout": key})
-            return loss_fn(logits[:batch_size], labels)
+            with profiling.scope(profiling.QT_FORWARD):
+                logits = model.apply(p, x, adjs, train=True,
+                                     rngs={"dropout": key})
+            with profiling.scope(profiling.QT_LOSS):
+                return loss_fn(logits[:batch_size], labels)
 
         loss, grads = jax.value_and_grad(loss_of)(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss
+        return _apply_update(state, tx, grads), loss
 
     jitted = jax.jit(step_fn_raw, donate_argnums=(0,) if donate else ())
     if not donate:

@@ -51,7 +51,10 @@ def _worker(q: "queue.Queue", stats: dict, lock: "threading.Lock",
         # restart (``_ensure_worker``) resumes the queue with every
         # future intact
         faults.fire("pipeline.worker")
-        item = q.get()
+        # the worker with nothing to run: in a profile, the device gaps
+        # that fall in this span are the ones no faster stage would close
+        with tracing.stage("pipeline.idle"):
+            item = q.get()
         if item is _STOP:
             return
         fut, fn, args, kwargs, t_enq = item
@@ -59,24 +62,19 @@ def _worker(q: "queue.Queue", stats: dict, lock: "threading.Lock",
             with lock:
                 stats["cancelled"] += 1
             continue                     # cancelled while queued
-        t_run = time.perf_counter()
-        wait = t_run - t_enq
-        # span hooks ride the stats plumbing's own clock reads: when
-        # tracing is off this adds one bool check per item, nothing else
-        traced = tracing.enabled()
-        if traced:
-            tracing.record("pipeline.queue_wait", t_enq, wait,
-                           args={"pipeline": name})
-        try:
-            fut.set_result(fn(*args, **kwargs))
-            ok = True
-        except BaseException as e:       # surfaces via fut.result()
-            fut.set_exception(e)
-            ok = False
-        if traced:
-            tracing.record("pipeline.execute", t_run,
-                           time.perf_counter() - t_run,
-                           args={"pipeline": name, "ok": ok})
+        # the wait is only known now, so it is a ring record and no stage
+        with tracing.stage("pipeline.execute") as run:
+            wait = run.t0 - t_enq
+            if tracing.enabled():
+                tracing.record("pipeline.queue_wait", t_enq, wait,
+                               args={"pipeline": name})
+            try:
+                fut.set_result(fn(*args, **kwargs))
+                ok = True
+            except BaseException as e:   # surfaces via fut.result()
+                fut.set_exception(e)
+                ok = False
+            run.args = {"pipeline": name, "ok": ok}
         with lock:
             stats["completed" if ok else "failed"] += 1
             stats["total_wait_s"] += wait

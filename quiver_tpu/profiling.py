@@ -1,38 +1,33 @@
-"""Profiling / tracing hooks.
+"""Device-side naming hooks.
 
-Replaces the reference's compile-time ``TRACE_SCOPE`` macros + RAII timer
-(trace.hpp:1-14, timer.hpp:7-29, enabled via QUIVER_ENABLE_TRACE +
-stdtracer FetchContent) with jax's built-in profiler: named scopes land
-in the XLA trace viewer, ``trace`` dumps a TensorBoard-compatible
-profile, and ``ScopeTimer`` gives the wall-clock numbers the reference
-printed ad hoc (sage_sampler.py:324-348).
+Replaces the reference's compile-time ``TRACE_SCOPE`` macros
+(trace.hpp:1-14, enabled via QUIVER_ENABLE_TRACE + stdtracer
+FetchContent) with jax's built-in profiler: a named scope lands in
+every instruction's ``op_name``, which is how an XProf trace (and
+``chipbench/trace.py``) attributes device time to a stage. Capture a
+profile with ``jax.profiler.trace(log_dir)``; time a HOST block with
+``quiver_tpu.tracing.stage``.
 """
 
 from __future__ import annotations
 
-import contextlib
-import functools
-import time
-from collections import defaultdict
-from typing import Dict
-
 import jax
 
-from . import tracing
-
-# named scope: annotates ops for the profiler (the TRACE_SCOPE equivalent)
+# named scope: annotates ops for the profiler (the TRACE_SCOPE
+# equivalent). The step builders reach it as ``profiling.scope`` so a
+# test can swap it for a null context and show that nothing but names
+# changes.
 scope = jax.named_scope
 
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a device profile: ``with qt.profiling.trace('/tmp/prof'):``
-    then inspect with TensorBoard/XProf."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+# the scopes of the fused steps, in one place. ``qt_draw``/``qt_compact``
+# sit beneath ``qt_sample_hop{i}`` (ops/sample_multihop.py), which
+# together with ``qt_serve_forward`` (serving.py) predates them; under
+# ``value_and_grad`` an op reads ``jvp(<scope>)``, the backward's
+# ``transpose(jvp(<scope>))``.
+(QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
+ QT_AGGREGATE) = DEVICE_SCOPES = (
+    "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
+    "qt_optimizer", "qt_aggregate")
 
 
 def hot_path(fn):
@@ -45,85 +40,3 @@ def hot_path(fn):
     the marked set."""
     fn.__qt_hot_path__ = True
     return fn
-
-
-def annotate(name: str):
-    """Decorator form of ``scope`` for hot functions.
-
-    ``functools.wraps`` preserves the wrapped function's full identity
-    (signature, docstring, ``__module__``, ``__wrapped__``) — name-only
-    copying broke ``inspect.signature`` on decorated hot functions and
-    made XProf/jaxpr dumps attribute time to anonymous wrappers."""
-    def wrap(fn):
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with jax.named_scope(name):
-                return fn(*args, **kwargs)
-        return inner
-    return wrap
-
-
-class ScopeTimer:
-    """Accumulating wall-clock timer with block-until-ready semantics.
-
-    Every measured block also lands as a ``scope.<name>`` span in
-    ``quiver_tpu.tracing`` when tracing is enabled (same timestamps —
-    the timer's clock reads are reused), so ad-hoc stage timings show
-    up on the same Perfetto timeline as the serving/pipeline spans.
-
-    >>> t = ScopeTimer()
-    >>> with t.measure("sample"):
-    ...     out = sampler.sample(seeds)
-    >>> t.summary()                    # printable
-    >>> t.summary_dict()               # JSONL-ready payload
-    >>> t.emit(sink)                   # -> {"kind": "scope_timer", ...}
-    """
-
-    def __init__(self):
-        self.totals: Dict[str, float] = defaultdict(float)
-        self.counts: Dict[str, int] = defaultdict(int)
-
-    @contextlib.contextmanager
-    def measure(self, name: str, block_on=None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if block_on is not None:
-                jax.block_until_ready(block_on)
-            dt = time.perf_counter() - t0
-            self.totals[name] += dt
-            self.counts[name] += 1
-            tracing.record(f"scope.{name}", t0, dt)
-
-    def mean(self, name: str) -> float:
-        # .get on BOTH maps: indexing the defaultdicts here would
-        # insert a phantom 0.0/0 row for a never-measured name, which
-        # summary()/summary_dict() would then report as a real scope
-        c = self.counts.get(name, 0)
-        return self.totals.get(name, 0.0) / c if c else 0.0
-
-    def summary(self) -> str:
-        lines = [f"{k}: {self.totals[k]:.4f}s total, "
-                 f"{self.mean(k) * 1e3:.2f} ms/call x{self.counts[k]}"
-                 for k in sorted(self.totals)]
-        return "\n".join(lines)
-
-    def summary_dict(self) -> Dict[str, dict]:
-        """The same numbers :meth:`summary` prints, as one JSONL-ready
-        mapping: ``{name: {total_s, calls, mean_ms}}``."""
-        return {k: {"total_s": round(self.totals[k], 6),
-                    "calls": self.counts[k],
-                    "mean_ms": round(self.mean(k) * 1e3, 3)}
-                for k in sorted(self.totals)}
-
-    def emit(self, sink, kind: str = "scope_timer") -> dict:
-        """Append the accumulated timings to a ``metrics.MetricsSink``
-        under the shared ``{ts, kind, ...}`` schema (kind
-        ``scope_timer``) — the structured form of the string
-        :meth:`summary` only printed."""
-        return sink.emit({"scopes": self.summary_dict()}, kind=kind)
-
-    def reset(self):
-        self.totals.clear()
-        self.counts.clear()

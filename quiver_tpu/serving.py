@@ -102,7 +102,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import faults, tracing
+from . import faults, profiling, tracing
 from .parallel.train import (_fused_knobs, _fused_multihop_x,
                              dedup_feature_gather, layers_to_adjs,
                              masked_feature_gather)
@@ -319,8 +319,9 @@ def build_serve_step(model, sizes: Sequence[int], batch_cap: int,
                 t = forder[jnp.clip(n_id, 0)] if forder is not None \
                     else jnp.clip(n_id, 0)
                 is_cold = (n_id >= 0) & (t >= fused_hot_rows)
-                x_cold = gather(feat, jnp.where(is_cold, n_id, -1),
-                                forder, collector=collector)
+                with profiling.scope(profiling.QT_GATHER):
+                    x_cold = gather(feat, jnp.where(is_cold, n_id, -1),
+                                    forder, collector=collector)
                 x = jnp.where(is_cold[:, None], x_cold, x)
         else:
             n_id, layers = sample_multihop_serving(
@@ -365,6 +366,19 @@ def sample_multihop_serving(indptr, indices, seeds, sizes, key,
 
 
 # -- the engine: params + tiers + pre-compiled variants ----------------------
+
+
+def _put_and_launch(engine, step, seeds, *args):
+    """The two host stages of an engine's ``run``: the seed block onto
+    the device (``serve.put``), then the jitted step's Python call with
+    it as last argument (``serve.launch``; returns before the device
+    ends). Their seconds land on ``engine.last_stage_s``."""
+    with tracing.stage("serve.put") as put:
+        block = jnp.asarray(seeds)
+    with tracing.stage("serve.launch") as launch:
+        out = step(*args, block)
+    engine.last_stage_s = (put.dur, launch.dur)
+    return out
 
 
 class ServeEngine:
@@ -421,6 +435,9 @@ class ServeEngine:
         self.method = method
         self.collect_metrics = bool(collect_metrics)
         self.last_counters = None
+        # host seconds of the last run's two stages (seed block onto the
+        # device, the step's Python call), for the server's counters
+        self.last_stage_s = (0.0, 0.0)
         indptr, indices = (topo.indptr, topo.indices) \
             if hasattr(topo, "indptr") else topo
         self._indptr = jnp.asarray(indptr, jnp.int32)
@@ -479,13 +496,14 @@ class ServeEngine:
         logits device array (no host sync — callers ``device_get`` when
         they scatter). ``seeds`` shorter than ``batch_cap`` are padded
         here; with ``collect_metrics`` the counter vector lands on
-        ``last_counters``."""
+        ``last_counters``. The host seconds of ``serve.put`` and
+        ``serve.launch`` land on ``last_stage_s``."""
         seeds = np.asarray(seeds, np.int32)
         if seeds.shape[0] != self.batch_cap:
             seeds = self.pad_seeds(seeds)
-        out = self._steps[variant](
-            self.params, self._key, self._feat, self._forder,
-            self._indptr, self._indices, jnp.asarray(seeds))
+        out = _put_and_launch(
+            self, self._steps[variant], seeds, self.params, self._key,
+            self._feat, self._forder, self._indptr, self._indices)
         if self.collect_metrics:
             self._key, logits, self.last_counters = out
         else:
@@ -768,6 +786,9 @@ class ShardedServeEngine:
         self.partitions = int(dist.info.hosts)
         self.collect_metrics = bool(collect_metrics)
         self.last_counters = None
+        # host seconds of the last run's two stages (seed block onto the
+        # device, the step's Python call), for the server's counters
+        self.last_stage_s = (0.0, 0.0)
         indptr, indices = (topo.indptr, topo.indices) \
             if hasattr(topo, "indptr") else topo
         self._indptr = jnp.asarray(indptr, jnp.int32)
@@ -797,9 +818,10 @@ class ShardedServeEngine:
         seeds = np.asarray(seeds, np.int32)
         if seeds.shape[0] != self.batch_cap:
             seeds = self.pad_seeds(seeds)
-        out = self._steps[variant](
-            self.params, self._key, self.dist._spmd_feat, self._g2h,
-            self._g2l, self._indptr, self._indices, jnp.asarray(seeds))
+        out = _put_and_launch(
+            self, self._steps[variant], seeds, self.params, self._key,
+            self.dist._spmd_feat, self._g2h, self._g2l, self._indptr,
+            self._indices)
         if self.collect_metrics:
             self._key, logits, self.last_counters = out
         else:
@@ -1014,6 +1036,11 @@ class MicroBatchServer:
             "deadline_expired": 0, "displaced": 0,
             "batches": 0, "coalesced": 0,
             "variant_batches": [0] * len(engine.variants),
+            # host seconds of each stage, summed over batches (and
+            # queue_wait_s over requests): see docs/observability.md
+            "coalesce_s": 0.0, "pipe_submit_s": 0.0, "execute_s": 0.0,
+            "put_s": 0.0, "launch_s": 0.0, "get_s": 0.0, "scatter_s": 0.0,
+            "queue_wait_s": 0.0,
         }
         self._counts_lock = threading.Lock()
         # register into the unified qt.metrics.report() LAST — a
@@ -1397,108 +1424,115 @@ class MicroBatchServer:
             if self._tenants is not None and (
                     self._shed_level > 0 or self._shed_floor > 0):
                 bcls = self._tenants[first.tenant]
-            # span plumbing: one enabled-check per batch when tracing is
-            # off; when on, each request gets admission_wait (queue time
-            # before the coalescer saw it) and coalesce_wait (time spent
-            # waiting for batch company) spans carrying its trace_id +
-            # the batch id — the request<->batch correlation the
-            # Perfetto view pivots on
+            # span plumbing: the batch's two stages on this thread,
+            # serve.batch_coalesce (first pop -> batch closed) and
+            # serve.pipe_submit (closed -> the pipeline took it), go
+            # through tracing.stage: profiler, counters and ring see
+            # the same interval. When the ring is on, each request also
+            # gets admission_wait (queue time before the coalescer saw
+            # it) and coalesce_wait (time spent waiting for batch
+            # company) records carrying its trace_id + the batch id —
+            # the request<->batch correlation the Perfetto view pivots on
             traced = tracing.enabled()
             bid = tracing.new_trace_id() if traced else None
-            t_first = time.perf_counter()
-            pops = [(first, t_first)]
-            if traced:
-                tracing.record("serve.admission_wait", first.t_enq,
-                               t_first - first.t_enq, first.trace_id,
-                               {"batch": bid, "node": first.node_id})
-            batch = [first]
-            slots = {first.node_id: 0}
-            if bcls is not None and self._held:
-                # sweep already-deferred requests of THIS class into
-                # the batch up front (one pass — the rest stay held)
-                keep = []
-                for r in self._held:
-                    if (len(slots) < cap
-                            and self._tenants[r.tenant] is bcls):
-                        if self._shed_expired(r):
-                            continue
-                        batch.append(r)
-                        slots.setdefault(r.node_id, len(slots))
-                        if traced:
-                            t_pop = time.perf_counter()
-                            pops.append((r, t_pop))
-                            tracing.record(
-                                "serve.admission_wait", r.t_enq,
-                                t_pop - r.t_enq, r.trace_id,
-                                {"batch": bid, "node": r.node_id})
-                    else:
-                        keep.append(r)
-                self._held = keep
-            deadline = t_first + max_wait
-            # drain until the seed block is full or the first request's
-            # wait budget is spent — a lone request ships at deadline,
-            # a burst splits into back-to-back full batches
-            while len(slots) < cap:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    if bcls is None:
-                        req = self._pop_next(remaining)
-                    else:
-                        # class-pure: pull from the queue only (held
-                        # was filtered above and now holds only other
-                        # classes — re-popping it here would spin)
-                        req = self._q.get(timeout=remaining)
-                        self._note_popped(req)
-                except queue.Empty:
-                    break
-                if self._shed_expired(req):
-                    continue
-                if bcls is not None and \
-                        self._tenants[req.tenant] is not bcls:
-                    self._held.append(req)
-                    continue
-                batch.append(req)
-                slots.setdefault(req.node_id, len(slots))
+            with tracing.stage("serve.batch_coalesce", bid) as coalesce:
+                t_first = coalesce.t0
+                pops = [(first, t_first)]
                 if traced:
-                    t_pop = time.perf_counter()
-                    pops.append((req, t_pop))
-                    tracing.record("serve.admission_wait", req.t_enq,
-                                   t_pop - req.t_enq, req.trace_id,
-                                   {"batch": bid, "node": req.node_id})
-            # the seed block keeps the engine's COMPILED width whatever
-            # the fill cap — a fill-cap swap changes padding, never the
-            # program shape
-            seeds = np.full((self.engine.batch_cap,), -1, np.int32)
-            for nid, s in slots.items():
-                seeds[s] = nid
-            variant = self._select_variant()
-            if bcls is not None:
-                # per-class quality-shed order: this class ignores
-                # shed_grace ladder steps of the local shed level; the
-                # fleet-planned floor still lower-bounds everyone
-                top = len(self.engine.variants) - 1
-                graced = max(0, min(self._shed_level, top)
-                             - bcls.shed_grace)
-                variant = max(graced, min(self._shed_floor, top))
+                    tracing.record("serve.admission_wait", first.t_enq,
+                                   t_first - first.t_enq, first.trace_id,
+                                   {"batch": bid, "node": first.node_id})
+                batch = [first]
+                slots = {first.node_id: 0}
+                if bcls is not None and self._held:
+                    # sweep already-deferred requests of THIS class into
+                    # the batch up front (one pass — the rest stay held)
+                    keep = []
+                    for r in self._held:
+                        if (len(slots) < cap
+                                and self._tenants[r.tenant] is bcls):
+                            if self._shed_expired(r):
+                                continue
+                            batch.append(r)
+                            slots.setdefault(r.node_id, len(slots))
+                            if traced:
+                                t_pop = time.perf_counter()
+                                pops.append((r, t_pop))
+                                tracing.record(
+                                    "serve.admission_wait", r.t_enq,
+                                    t_pop - r.t_enq, r.trace_id,
+                                    {"batch": bid, "node": r.node_id})
+                        else:
+                            keep.append(r)
+                    self._held = keep
+                deadline = t_first + max_wait
+                # drain until the seed block is full or the first
+                # request's wait budget is spent — a lone request ships
+                # at deadline, a burst splits into back-to-back full
+                # batches
+                while len(slots) < cap:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        if bcls is None:
+                            req = self._pop_next(remaining)
+                        else:
+                            # class-pure: pull from the queue only (held
+                            # was filtered above and now holds only other
+                            # classes — re-popping it here would spin)
+                            req = self._q.get(timeout=remaining)
+                            self._note_popped(req)
+                    except queue.Empty:
+                        break
+                    if self._shed_expired(req):
+                        continue
+                    if bcls is not None and \
+                            self._tenants[req.tenant] is not bcls:
+                        self._held.append(req)
+                        continue
+                    batch.append(req)
+                    slots.setdefault(req.node_id, len(slots))
+                    if traced:
+                        t_pop = time.perf_counter()
+                        pops.append((req, t_pop))
+                        tracing.record("serve.admission_wait", req.t_enq,
+                                       t_pop - req.t_enq, req.trace_id,
+                                       {"batch": bid, "node": req.node_id})
+                # the seed block keeps the engine's COMPILED width
+                # whatever the fill cap — a fill-cap swap changes
+                # padding, never the program shape
+                seeds = np.full((self.engine.batch_cap,), -1, np.int32)
+                for nid, s in slots.items():
+                    seeds[s] = nid
+                variant = self._select_variant()
+                if bcls is not None:
+                    # per-class quality-shed order: this class ignores
+                    # shed_grace ladder steps of the local shed level;
+                    # the fleet-planned floor still lower-bounds everyone
+                    top = len(self.engine.variants) - 1
+                    graced = max(0, min(self._shed_level, top)
+                                 - bcls.shed_grace)
+                    variant = max(graced, min(self._shed_floor, top))
+                coalesce.args = {"requests": len(batch),
+                                 "fill": len(slots), "variant": variant}
             # the pipeline submit blocks at depth: device-side
             # backpressure propagates here, the queue absorbs it, and a
             # full queue sheds at admission — bounded everywhere
             try:
-                pf = self._pipe.submit(self._execute, batch, slots,
-                                       seeds, variant, bid)
+                with tracing.stage("serve.pipe_submit", bid) as handoff:
+                    pf = self._pipe.submit(self._execute, batch, slots,
+                                           seeds, variant, bid)
             except RuntimeError:
                 if self._closed:       # close() raced the coalescer
                     self._fail_batch(batch)
                     return
                 raise
+            with self._counts_lock:
+                self._counts["coalesce_s"] += coalesce.dur
+                self._counts["pipe_submit_s"] += handoff.dur
             if traced:
-                t_sub = time.perf_counter()
-                tracing.record("serve.batch_coalesce", t_first,
-                               t_sub - t_first, bid,
-                               {"requests": len(batch),
-                                "fill": len(slots), "variant": variant})
+                t_sub = handoff.t0 + handoff.dur
                 for req, t_pop in pops:
                     tracing.record("serve.coalesce_wait", t_pop,
                                    t_sub - t_pop, req.trace_id,
@@ -1587,107 +1621,128 @@ class MicroBatchServer:
                         st.budget.record(ok=False)
 
     def _execute(self, batch, slots, seeds, variant, bid=None):
-        # claim every request's future up front: a caller-side cancel()
-        # that lands after this point loses the race cleanly (set_result
-        # on a RUNNING future is legal; on a CANCELLED one it raises)
-        batch = [r for r in batch
-                 if r.future.set_running_or_notify_cancel()]
-        if not batch:
-            return
-        t0 = time.perf_counter()
-        try:
-            faults.fire("serve.execute")
-            logits = self.engine.run(seeds, variant)
-            rows = np.asarray(jax.device_get(logits))
-        except BaseException as e:
-            # request-failure propagation: the batch's requests all see
-            # the step's exception; the pipeline records the failure and
-            # stays up for the next batch
-            for req in batch:
-                if not req.future.done():
-                    req.future.set_exception(e)
-            if self.slo is not None:
-                for _ in batch:
-                    self.slo.record(ok=False)
-            with self._counts_lock:
-                self._counts["failed"] += len(batch)
-                for req in batch:
-                    st = self._tenant_states.get(req.tenant)
-                    if st is not None:
-                        st.counts["failed"] += 1
-            if self._tenants is not None:
-                for req in batch:
-                    st = self._tenant_states.get(req.tenant)
-                    if st is not None and st.budget is not None:
-                        st.budget.record(ok=False)
-            if tracing.enabled():
-                # error-stamped terminal spans: the failed requests'
-                # traces complete with the outcome, so the tail
-                # sampler's `error` policy keeps exactly these
-                now = time.perf_counter()
-                for req in batch:
-                    if req.trace_id is not None:
-                        tracing.record("serve.request", req.t_enq,
-                                       now - req.t_enq, req.trace_id,
-                                       {"batch": bid,
-                                        "node": req.node_id,
-                                        "error": type(e).__name__})
-            raise
-        done = time.perf_counter()
-        traced = tracing.enabled() and bid is not None
-        if traced:
-            tracing.record("serve.dispatch", t0, done - t0, bid,
-                           {"variant": variant, "fill": len(slots),
-                            "requests": len(batch)})
-        counters = (self.engine.last_counters
-                    if self.engine.collect_metrics else None)
-        self.stats.record_step(done - t0, counters)
-        if self.hub is not None:
-            # per-batch series for the telemetry hub's detectors and
-            # the serving advisor (batch_cap from observed fill,
-            # max_wait from observed latency); counters ride the hub's
-            # own lazy fold — still no sync on the dispatch path
-            self.hub.observe("serve_batch_fill", len(slots))
-            self.hub.observe("serve_batch_ms", 1e3 * (done - t0))
-            self.hub.observe("serve_shed_level", variant)
-            if counters is not None:
-                self.hub.observe_counters(counters)
-        # stats and counts land BEFORE the futures resolve: a client
-        # woken by result() may immediately snapshot(), and must see
-        # its own batch counted
-        for req in batch:
-            lat = done - req.t_enq
-            self.stats.record_request(lat)
-            if self.slo is not None:
-                self.slo.record(lat)
-            if self._tenants is not None:
-                st = self._tenant_states.get(req.tenant)
-                if st is not None and st.budget is not None:
-                    st.budget.record(lat)
-        with self._counts_lock:
-            self._counts["completed"] += len(batch)
-            self._counts["batches"] += 1
-            self._counts["coalesced"] += len(batch)
-            self._counts["variant_batches"][variant] += 1
-            if self._tenants is not None:
-                for req in batch:
-                    st = self._tenant_states.get(req.tenant)
-                    if st is not None:
-                        st.counts["completed"] += 1
-                        st.hist.add(done - req.t_enq)
-        for req in batch:
-            req.future.set_result(rows[slots[req.node_id]])
-        if traced:
-            t_end = time.perf_counter()
+        # serve.dispatch is the whole of this call, claim to last future
+        # resolved; its children (serve.put and serve.launch inside
+        # engine.run, serve.get, serve.scatter) inherit the batch id
+        with tracing.stage("serve.dispatch", bid) as run:
+            # claim every request's future up front: a caller-side
+            # cancel() that lands after this point loses the race
+            # cleanly (set_result on a RUNNING future is legal; on a
+            # CANCELLED one it raises)
+            batch = [r for r in batch
+                     if r.future.set_running_or_notify_cancel()]
+            if not batch:
+                return
+            t0 = run.t0
+            run.args = {"variant": variant, "fill": len(slots),
+                        "requests": len(batch)}
+            try:
+                faults.fire("serve.execute")
+                logits = self.engine.run(seeds, variant)
+                with tracing.stage("serve.get") as get:
+                    rows = np.asarray(jax.device_get(logits))
+            except BaseException as e:
+                self._fail_dispatched(batch, bid, e)
+                raise
+            done = get.t0 + get.dur
             # scatter = stats filing + future resolution (the wake-up
             # cost requests pay after the device answer is back)
-            tracing.record("serve.scatter", done, t_end - done, bid,
-                           {"requests": len(batch)})
+            with tracing.stage("serve.scatter",
+                               args={"requests": len(batch)}) as scatter:
+                counters = (self.engine.last_counters
+                            if self.engine.collect_metrics else None)
+                self.stats.record_step(done - t0, counters)
+                if self.hub is not None:
+                    # per-batch series for the telemetry hub's detectors
+                    # and the serving advisor (batch_cap from observed
+                    # fill, max_wait from observed latency); counters
+                    # ride the hub's own lazy fold — still no sync on
+                    # the dispatch path
+                    self.hub.observe("serve_batch_fill", len(slots))
+                    self.hub.observe("serve_batch_ms", 1e3 * (done - t0))
+                    self.hub.observe("serve_shed_level", variant)
+                    if counters is not None:
+                        self.hub.observe_counters(counters)
+                # stats and counts land BEFORE the futures resolve: a
+                # client woken by result() may immediately snapshot(),
+                # and must see its own batch counted
+                queue_wait = 0.0
+                for req in batch:
+                    lat = done - req.t_enq
+                    queue_wait += t0 - req.t_enq
+                    self.stats.record_request(lat)
+                    if self.slo is not None:
+                        self.slo.record(lat)
+                    if self._tenants is not None:
+                        st = self._tenant_states.get(req.tenant)
+                        if st is not None and st.budget is not None:
+                            st.budget.record(lat)
+                with self._counts_lock:
+                    self._counts["completed"] += len(batch)
+                    self._counts["batches"] += 1
+                    self._counts["coalesced"] += len(batch)
+                    self._counts["variant_batches"][variant] += 1
+                    if self._tenants is not None:
+                        for req in batch:
+                            st = self._tenant_states.get(req.tenant)
+                            if st is not None:
+                                st.counts["completed"] += 1
+                                st.hist.add(done - req.t_enq)
+                for req in batch:
+                    req.future.set_result(rows[slots[req.node_id]])
+        # the stages' seconds are whole only now, so they are filed
+        # together one lock later than the batch's count: a snapshot in
+        # between reads one batch more than its seconds cover
+        put_s, launch_s = self.engine.last_stage_s
+        with self._counts_lock:
+            c = self._counts
+            c["execute_s"] += run.dur
+            c["put_s"] += put_s
+            c["launch_s"] += launch_s
+            c["get_s"] += get.dur
+            c["scatter_s"] += scatter.dur
+            c["queue_wait_s"] += queue_wait
+        if bid is not None and tracing.enabled():
+            t_end = t0 + run.dur
             for req in batch:
                 tracing.record("serve.request", req.t_enq,
                                t_end - req.t_enq, req.trace_id,
                                {"batch": bid, "node": req.node_id,
                                 "variant": variant})
+
+    def _fail_dispatched(self, batch, bid, e) -> None:
+        """Request-failure propagation: the batch's requests all see
+        the step's exception; the pipeline records the failure and
+        stays up for the next batch."""
+        for req in batch:
+            if not req.future.done():
+                req.future.set_exception(e)
+        if self.slo is not None:
+            for _ in batch:
+                self.slo.record(ok=False)
+        with self._counts_lock:
+            self._counts["failed"] += len(batch)
+            for req in batch:
+                st = self._tenant_states.get(req.tenant)
+                if st is not None:
+                    st.counts["failed"] += 1
+        if self._tenants is not None:
+            for req in batch:
+                st = self._tenant_states.get(req.tenant)
+                if st is not None and st.budget is not None:
+                    st.budget.record(ok=False)
+        if tracing.enabled():
+            # error-stamped terminal spans: the failed requests'
+            # traces complete with the outcome, so the tail
+            # sampler's `error` policy keeps exactly these
+            now = time.perf_counter()
+            for req in batch:
+                if req.trace_id is not None:
+                    tracing.record("serve.request", req.t_enq,
+                                   now - req.t_enq, req.trace_id,
+                                   {"batch": bid,
+                                    "node": req.node_id,
+                                    "error": type(e).__name__})
 
     # -- observability ------------------------------------------------------
     def health(self) -> dict:
@@ -1733,6 +1788,9 @@ class MicroBatchServer:
         coalesced = c.pop("coalesced")
         rec["serving"] = {
             **c,
+            # batch handed to the pipeline -> _execute starts (the time
+            # submit blocked at depth is inside it)
+            "pipeline_wait_s": self._pipe.stats()["total_wait_s"],
             "batches": b,
             "mean_batch_fill": coalesced / b if b else 0.0,
             "queue_depth": self._q.qsize(),

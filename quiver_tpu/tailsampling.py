@@ -36,9 +36,10 @@ Three pieces:
   | ``head_sample`` | the seeded probabilistic floor (``head_rate``) |
 
   Everything else drops. Batch-scoped spans (``serve.batch_coalesce``
-  / ``serve.dispatch`` / ``serve.scatter`` — their ``trace_id`` is a
-  batch id that never completes) live in a separate small LRU buffer
-  and are MERGED into a kept request trace through the root span's
+  / ``serve.pipe_submit`` / ``serve.dispatch`` and its children
+  ``serve.put`` / ``serve.launch`` / ``serve.get`` / ``serve.scatter``
+  — their ``trace_id`` is a batch id that never completes) live in a
+  separate small LRU buffer and are MERGED into a kept request trace through the root span's
   ``batch`` arg, so a kept trace shows its batch's dispatch timeline
   without batch ids ever occupying (or thrashing) the pending table.
 
@@ -106,15 +107,17 @@ DEFAULT_ROOT_SPANS = ("serve.request", "rpc.lookup")
 #: batch-scoped span names: their trace_id is a serving BATCH id (the
 #: ``batch`` arg request spans carry), buffered separately and merged
 #: into kept request traces — never pending-table entries
-BATCH_SPAN_NAMES = ("serve.batch_coalesce", "serve.dispatch",
-                    "serve.scatter")
+BATCH_SPAN_NAMES = ("serve.batch_coalesce", "serve.pipe_submit",
+                    "serve.dispatch", "serve.put", "serve.launch",
+                    "serve.get", "serve.scatter")
 
 #: the queue-vs-execute split vocabulary (the profile/costmodel
 #: framing: time spent WAITING vs time spent DOING)
 QUEUE_SPAN_NAMES = ("serve.admission_wait", "serve.coalesce_wait",
                     "pipeline.queue_wait", "rpc.backoff")
+# (serve.dispatch spans its children, serve.scatter among them)
 EXECUTE_SPAN_NAMES = ("serve.dispatch", "pipeline.execute",
-                      "rpc.attempt", "rpc.hedge", "serve.scatter")
+                      "rpc.attempt", "rpc.hedge")
 
 
 def latency_source_from(slo=None, stats=None,

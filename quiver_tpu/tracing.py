@@ -10,12 +10,12 @@ invisible in percentiles but obvious in a trace.
 
 Design constraints, in order:
 
-1. **Zero cost when off.** Tracing is opt-in (``QT_TRACE=1`` /
+1. **Cheap when off.** The ring is opt-in (``QT_TRACE=1`` /
    ``QT_TRACE=/path/out.json`` / :func:`enable`); disabled, every hook
-   is one attribute check (``record``) or a shared no-op context
-   manager (``span``) — the instrumented hot paths (the serving
-   coalescer, the pipeline worker) reuse timestamps they already take
-   for ``stats()``, so no extra clock reads either.
+   is one attribute check (``record``). The per-BATCH stages (below)
+   are timed whether it is on or off, since the server's counters read
+   them: two clock reads and one ``TraceAnnotation`` each, about a
+   microsecond, nine a batch. Nothing is timed per request.
 2. **Lock-cheap when on.** Records land in a fixed-capacity ring
    buffer: one atomic ``next(itertools.count())`` for the slot, one
    list-item store for the record (both single bytecode effects under
@@ -28,6 +28,17 @@ Design constraints, in order:
    per-step host syncs, bit-identical outputs with tracing on/off,
    donation intact) hold trivially — and are still pinned explicitly in
    ``tests/test_serving.py``.
+
+**Stages** (:func:`stage`) are the spans of work that is happening
+NOW on the serving path, one call site each and three readers: the
+profiler (a ``jax.profiler.TraceAnnotation`` of the same name, on the
+device trace's clock, when a profiler session is on), the caller (the
+duration, always: ``MicroBatchServer`` sums them into
+``snapshot()["serving"]``), and this ring (when enabled). :data:`STAGES`
+lists them. Waits that are only known afterwards
+(``serve.admission_wait``, ``serve.coalesce_wait``, ``serve.request``,
+``pipeline.queue_wait``) cannot be annotations; they stay
+:func:`record`-only.
 
 A span record is ``(name, tid, t0, dur, trace_id, args)``: ``t0``/
 ``dur`` in ``time.perf_counter()`` seconds, ``tid`` the recording
@@ -63,7 +74,7 @@ Usage::
 
     from quiver_tpu import tracing
     tracing.enable()
-    with tracing.span("stage.load", args={"rows": 4096}):
+    with tracing.stage("stage.load", args={"rows": 4096}):
         ...
     tracing.export_chrome_trace("/tmp/trace.json")   # -> Perfetto
 
@@ -71,7 +82,7 @@ Usage::
     meta = tracing.inject({})                  # -> request metadata
     # replica process (its spans carry meta's trace_id):
     ctx = tracing.extract(meta)
-    with tracing.span("serve.request", trace_id=ctx.trace_id):
+    with tracing.stage("serve.request", trace_id=ctx.trace_id):
         ...
 """
 
@@ -84,6 +95,8 @@ import os
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 Record = Tuple[str, int, float, float, Optional[int], Optional[dict]]
 
@@ -120,42 +133,6 @@ def set_replica(name: Optional[str]) -> None:
 
 def get_replica() -> Optional[str]:
     return _replica
-
-
-class _NullSpan:
-    """The shared do-nothing context manager handed out while tracing
-    is disabled — no per-call allocation on the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    __slots__ = ("_tracer", "name", "trace_id", "args", "t0")
-
-    def __init__(self, tracer: "Tracer", name: str,
-                 trace_id: Optional[int], args: Optional[dict]):
-        self._tracer = tracer
-        self.name = name
-        self.trace_id = trace_id
-        self.args = args
-
-    def __enter__(self) -> "_Span":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._tracer.record(self.name, self.t0,
-                            time.perf_counter() - self.t0,
-                            self.trace_id, self.args)
 
 
 class Tracer:
@@ -241,14 +218,6 @@ class Tracer:
         s = self._sampler
         if s is not None:
             s.offer(name, tid, t0, dur, trace_id, args)
-
-    def span(self, name: str, trace_id: Optional[int] = None,
-             args: Optional[dict] = None):
-        """Context manager timing its block into one record; the shared
-        no-op instance when disabled."""
-        if not self._enabled:
-            return _NULL_SPAN
-        return _Span(self, name, trace_id, args)
 
     def set_sampler(self, sampler) -> None:
         """Attach (or, with ``None``, detach) a tail sampler — an
@@ -352,6 +321,62 @@ def new_global_trace_id() -> int:
     return _tracer.new_global_trace_id()
 
 
+# -- stages: one call site, three readers -------------------------------------
+
+# every span filed through stage(), per batch: the serving coalescer's
+# two, the pipeline worker's two, and dispatch with its four children
+STAGES = ("serve.batch_coalesce", "serve.pipe_submit", "pipeline.idle",
+          "pipeline.execute", "serve.dispatch", "serve.put", "serve.launch",
+          "serve.get", "serve.scatter")
+
+_open_stage = threading.local()
+
+
+class stage:
+    """Context manager for a stage that is happening now.
+
+    Entering opens a ``jax.profiler.TraceAnnotation(name)`` (an atomic
+    check while no profiler session is on) and reads ``perf_counter``;
+    leaving reads it again, keeps the duration on ``.dur`` for the
+    caller, and, when the ring is enabled, files the record
+    :func:`record` would. ``trace_id`` defaults to the enclosing
+    stage's on this thread, so the children of a batch's
+    ``serve.dispatch`` carry its batch id wherever they are written.
+    ``args`` may be set on the object before it closes (a batch's fill
+    is only known at the end).
+
+        with tracing.stage("serve.get") as st:
+            rows = np.asarray(jax.device_get(logits))
+        get_s += st.dur
+    """
+
+    __slots__ = ("name", "trace_id", "args", "t0", "dur", "_ann", "_outer")
+
+    def __init__(self, name: str, trace_id: Optional[int] = None,
+                 args: Optional[dict] = None):
+        self.name = name
+        self.trace_id = trace_id
+        self.args = args
+        self.dur = 0.0
+
+    def __enter__(self) -> "stage":
+        self._outer = outer = getattr(_open_stage, "top", None)
+        if self.trace_id is None and outer is not None:
+            self.trace_id = outer.trace_id
+        _open_stage.top = self
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        _open_stage.top = self._outer
+        _tracer.record(self.name, self.t0, self.dur, self.trace_id,
+                       self.args)
+
+
 # -- cross-process propagation ------------------------------------------------
 
 
@@ -446,11 +471,6 @@ def record(name: str, t0: float, dur: float,
            trace_id: Optional[int] = None,
            args: Optional[dict] = None) -> None:
     _tracer.record(name, t0, dur, trace_id, args)
-
-
-def span(name: str, trace_id: Optional[int] = None,
-         args: Optional[dict] = None):
-    return _tracer.span(name, trace_id, args)
 
 
 def records() -> List[Record]:

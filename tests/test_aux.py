@@ -1,4 +1,4 @@
-"""Auxiliary subsystem tests: checkpointing, profiling hooks, debug
+"""Auxiliary subsystem tests: checkpointing, debug
 utils, pickle reductions, async per-layer sampler."""
 
 import pickle
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import optax
 
 import quiver_tpu as qv
-from quiver_tpu import checkpoint, profiling
+from quiver_tpu import checkpoint
 from quiver_tpu.parallel.train import TrainState
 
 
@@ -33,36 +33,6 @@ class TestCheckpoint:
         art = checkpoint.load_artifact(path)
         np.testing.assert_array_equal(art["book"], np.arange(10))
         np.testing.assert_array_equal(art["order"], np.arange(5)[::-1])
-
-
-class TestProfiling:
-    def test_scope_timer(self):
-        t = profiling.ScopeTimer()
-        with t.measure("op"):
-            _ = jnp.arange(10).sum()
-        assert t.counts["op"] == 1
-        assert "op" in t.summary()
-
-    def test_named_scope_wraps(self):
-        @profiling.annotate("my_op")
-        def f(x):
-            return x * 2
-        assert int(f(jnp.asarray(3))) == 6
-
-    def test_annotate_preserves_identity(self):
-        import inspect
-
-        @profiling.annotate("hot_fn")
-        def hot(x, k: int = 2):
-            """Doubles, roughly."""
-            return x * k
-
-        # functools.wraps: signature, doc, name, and __wrapped__ all
-        # survive — introspection (and XProf attribution) stay intact
-        assert hot.__name__ == "hot"
-        assert hot.__doc__ == "Doubles, roughly."
-        assert list(inspect.signature(hot).parameters) == ["x", "k"]
-        assert hot.__wrapped__ is not hot
 
 
 class TestDebugLogger:

@@ -1,85 +1,40 @@
-"""Direct coverage for ``quiver_tpu.profiling`` — the module qt-prof
-leans on (ScopeTimer feeds the scope spans/JSONL, ``hot_path`` is the
-host-lint contract marker, ``annotate`` wraps hot functions)."""
+"""``quiver_tpu.profiling``: the ``hot_path`` marker (the host-lint
+contract) and the device scopes of the fused steps.
 
-import inspect
+The scope contracts:
+
+1. every scope of ``profiling.DEVICE_SCOPES`` reaches the ``op_name``s
+   of the compiled train steps (the forward-only serve step has no
+   loss, backward or optimizer); ``qt_draw``/``qt_compact`` sit beneath
+   a ``qt_sample_hop<i>`` and nowhere else; the backward's ops read
+   ``transpose(jvp(qt_forward))``.
+2. the scopes are names and nothing else: with ``profiling.scope``
+   swapped for a null context the lowered program is the same text.
+"""
+
+import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import optax
 import pytest
+from jax.sharding import Mesh
 
 from quiver_tpu import profiling
-from quiver_tpu.profiling import ScopeTimer, annotate, hot_path
+from quiver_tpu.models import GraphSAGE
+from quiver_tpu.ops.sample_multihop import sample_multihop
+from quiver_tpu.parallel.train import (build_e2e_train_step, build_train_step,
+                                       init_state, layers_to_adjs,
+                                       masked_feature_gather)
+from quiver_tpu.profiling import hot_path
+from quiver_tpu.serving import build_serve_step
 
-
-class TestScopeTimer:
-    def test_mean_of_unmeasured_name_does_not_pollute(self):
-        # the mutation-on-read bug class: reading a never-measured
-        # name off the defaultdicts must not insert a phantom 0.0 row
-        # that summary()/summary_dict() then report as a real scope
-        t = ScopeTimer()
-        with t.measure("real"):
-            pass
-        assert t.mean("never-measured") == 0.0
-        assert "never-measured" not in t.totals
-        assert "never-measured" not in t.counts
-        assert set(t.summary_dict()) == {"real"}
-        assert "never-measured" not in t.summary()
-
-    def test_mean_on_empty_timer(self):
-        t = ScopeTimer()
-        assert t.mean("anything") == 0.0
-        assert t.summary_dict() == {}
-        assert t.totals == {} and t.counts == {}
-
-    def test_measure_accumulates(self):
-        t = ScopeTimer()
-        for _ in range(3):
-            with t.measure("s"):
-                pass
-        assert t.counts["s"] == 3
-        assert t.totals["s"] >= 0.0
-        assert t.mean("s") == pytest.approx(t.totals["s"] / 3)
-
-    def test_measure_blocks_on_full_pytree(self):
-        # block_on takes a whole pytree (dict/tuple/leaf mix), not
-        # just a single array — jax.block_until_ready semantics
-        t = ScopeTimer()
-        tree = {"a": jnp.arange(8.0),
-                "b": (jnp.ones((4, 4)), jnp.zeros(3)),
-                "c": None}
-        with t.measure("tree", block_on=tree):
-            tree["a"] = tree["a"] * 2
-        assert t.counts["tree"] == 1
-        assert t.totals["tree"] > 0.0
-
-    def test_reset(self):
-        t = ScopeTimer()
-        with t.measure("x"):
-            pass
-        t.reset()
-        assert t.summary_dict() == {}
-
-
-class TestAnnotate:
-    def test_preserves_signature_and_identity(self):
-        def hot_fn(a, b=2, *, c: int = 3):
-            """The docstring."""
-            return a + b + c
-
-        wrapped = annotate("my_scope")(hot_fn)
-        assert inspect.signature(wrapped) == inspect.signature(hot_fn)
-        assert wrapped.__doc__ == "The docstring."
-        assert wrapped.__name__ == "hot_fn"
-        assert wrapped.__wrapped__ is hot_fn
-
-    def test_wrapped_fn_still_works_under_jit(self):
-        @annotate("scoped_add")
-        def f(a, b):
-            return a + b
-
-        out = jax.jit(f)(jnp.arange(4), jnp.arange(4))
-        assert (jax.device_get(out) == [0, 2, 4, 6]).all()
+N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
+TRAIN_SCOPES = set(profiling.DEVICE_SCOPES)
+SERVE_SCOPES = {profiling.QT_DRAW, profiling.QT_COMPACT, profiling.QT_GATHER,
+                profiling.QT_AGGREGATE}
 
 
 class TestHotPath:
@@ -94,3 +49,89 @@ class TestHotPath:
 
     def test_scope_is_jax_named_scope(self):
         assert profiling.scope is jax.named_scope
+
+
+@pytest.fixture(scope="module")
+def world():
+    rng = np.random.default_rng(0)
+    deg = rng.integers(1, 8, N)
+    indptr = jnp.asarray(np.concatenate([[0], np.cumsum(deg)]), jnp.int32)
+    indices = jnp.asarray(rng.integers(0, N, int(deg.sum())), jnp.int32)
+    feat = jnp.asarray(rng.normal(size=(N, DIM)), jnp.float32)
+    model = GraphSAGE(hidden_dim=8, out_dim=4, num_layers=len(SIZES))
+    tx = optax.adam(1e-3)
+    key = jax.random.key(0)
+    seeds = jnp.arange(BATCH, dtype=jnp.int32)
+    n_id, layers = sample_multihop(indptr, indices, seeds, SIZES, key,
+                                   seeds_dense=True)
+    state = init_state(model, tx, masked_feature_gather(feat, n_id),
+                       layers_to_adjs(layers, BATCH, SIZES), key)
+    return {"model": model, "tx": tx, "state": state, "feat": feat,
+            "indptr": indptr, "indices": indices, "key": key}
+
+
+def _lower(builder: str, w):
+    """The lowered tiny step of one builder, built NOW (so that a patched
+    ``profiling.scope`` is the one it traces with)."""
+    graph = (w["feat"], None, w["indptr"], w["indices"])
+    if builder == "train":
+        fn = build_train_step(w["model"], w["tx"], SIZES, BATCH,
+                              donate=False)
+        return fn.lower(w["state"], *graph, jnp.arange(BATCH, dtype=jnp.int32),
+                        jnp.zeros((BATCH,), jnp.int32), w["key"])
+    if builder == "e2e":
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        step = build_e2e_train_step(w["model"], w["tx"], SIZES, BATCH, mesh,
+                                    donate=False)
+        return step.jitted_fns[-1].lower(
+            w["state"], *graph, jnp.arange(2 * BATCH, dtype=jnp.int32),
+            jnp.zeros((2 * BATCH,), jnp.int32), w["key"])
+    fn = build_serve_step(w["model"], SIZES, BATCH)
+    return fn.lower(w["state"].params, w["key"], *graph,
+                    jnp.arange(BATCH, dtype=jnp.int32))
+
+
+def _op_names(lowered):
+    return re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("builder,scopes", [
+    ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES)])
+def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
+    names = _op_names(_lower(builder, world))
+    for scope in scopes:
+        assert any(scope in n for n in names), scope
+    # draw and compact are the two halves of a hop, never on their own
+    for n in names:
+        if profiling.QT_DRAW in n or profiling.QT_COMPACT in n:
+            assert re.search(r"qt_sample_hop\d\)?/qt_(draw|compact)", n), n
+    hops = {m.group(0) for n in names
+            for m in [re.search(r"qt_sample_hop\d", n)] if m}
+    assert hops == {f"qt_sample_hop{i}" for i in range(len(SIZES))}
+    if builder == "serve":
+        assert any("qt_serve_forward" in n for n in names)
+        assert not any("transpose(" in n for n in names)
+    else:
+        back = [n for n in names if "transpose(jvp(qt_forward))" in n]
+        fwd = [n for n in names if "jvp(qt_forward)" in n
+               and "transpose(" not in n]
+        assert back and fwd
+        # the optimizer is outside value_and_grad: no jvp around it
+        assert any(re.search(r"(^|/)qt_optimizer/", n) for n in names)
+
+
+@pytest.mark.parametrize("builder", ["train", "serve"])
+def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
+    named = _lower(builder, world).as_text()
+    monkeypatch.setattr(profiling, "scope",
+                        lambda name: contextlib.nullcontext())
+    lowered = _lower(builder, world)
+    # the lowered text carries no op_name (locations are not printed),
+    # so what is left to differ is the program itself
+    assert "qt_" not in named
+    assert named == lowered.as_text()
+    # and the patch did reach the builders: the names are gone from the
+    # locations too (read before any compile cache has a say)
+    located = lowered.as_text(debug_info=True)
+    assert not any(s in located for s in profiling.DEVICE_SCOPES)
+    assert "qt_sample_hop0" in located

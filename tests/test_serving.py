@@ -689,6 +689,7 @@ class _GateEngine:
 
     collect_metrics = False
     jitted_fns = ()
+    last_stage_s = (0.0, 0.0)
 
     def __init__(self, n_variants=2):
         self.batch_cap = 1

@@ -503,6 +503,7 @@ class TestUnifiedReport:
             jitted_fns = ()
             collect_metrics = False
             last_counters = None
+            last_stage_s = (0.0, 0.0)
 
             def run(self, seeds, variant):
                 return np.zeros((4, 3), np.float32)

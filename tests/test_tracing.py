@@ -11,10 +11,7 @@ The contracts:
    short window (above ``shed_burn_rate``) and the long window (above
    1.0); rejections/failures (``ok=False``) consume budget; the
    snapshot emits through ``MetricsSink`` as kind ``slo``.
-3. **ScopeTimer** — ``summary_dict``/``emit`` land the wall-clock
-   numbers in the shared JSONL schema (kind ``scope_timer``), and each
-   measured block becomes a ``scope.*`` span when tracing is on.
-4. **bench_regress** — the committed ``BENCH_r*.json`` trajectory
+3. **bench_regress** — the committed ``BENCH_r*.json`` trajectory
    passes; a synthetic 20%-regressed record fails (exit 1); skipped /
    ``value: null`` outage rounds are ignored, not failed.
 """
@@ -29,7 +26,6 @@ import pytest
 
 from quiver_tpu import tracing
 from quiver_tpu.metrics import MetricsSink, SloBudget
-from quiver_tpu.profiling import ScopeTimer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -53,11 +49,7 @@ def global_tracing():
 class TestTracer:
     def test_disabled_records_nothing(self, tracer):
         tracer.record("a", 0.0, 1.0)
-        with tracer.span("b"):
-            pass
         assert len(tracer) == 0
-        # and the disabled span is the shared no-op (no allocation)
-        assert tracer.span("c") is tracer.span("d")
 
     def test_ring_keeps_most_recent_after_wrap(self, tracer):
         tracer.enable()
@@ -66,18 +58,17 @@ class TestTracer:
         assert len(tracer) == 16             # bounded, not 40
         assert [r[4] for r in tracer.records()] == list(range(24, 40))
 
-    def test_span_context_manager_times_block(self, tracer):
-        tracer.enable()
-        with tracer.span("work", trace_id=7, args={"k": 1}):
+    def test_stage_times_block_into_one_record(self, global_tracing):
+        with tracing.stage("work", trace_id=7, args={"k": 1}) as st:
             time.sleep(0.002)
-        (name, tid, t0, dur, trace_id, args), = tracer.records()
+        (name, tid, t0, dur, trace_id, args), = global_tracing.records()
         assert name == "work" and trace_id == 7 and args == {"k": 1}
-        assert dur >= 0.002
+        assert dur == st.dur >= 0.002
 
     def test_export_chrome_trace_loads(self, tracer, tmp_path):
         tracer.enable()
-        with tracer.span("phase.load", trace_id=3, args={"rows": 8}):
-            pass
+        tracer.record("phase.load", 0.5, 0.125, trace_id=3,
+                      args={"rows": 8})
         tracer.record("phase.run", 1.0, 0.25)
         path = tmp_path / "trace.json"
         n = tracer.export_chrome_trace(str(path))
@@ -274,38 +265,6 @@ class TestSloBudget:
         assert got["windows"]["short"]["bad"] == 1
         assert got["total"] == {"requests": 31, "bad": 1}
         assert "budget_remaining" in got and "shedding" in got
-
-
-class TestScopeTimer:
-    def test_summary_dict_and_emit(self, tmp_path):
-        t = ScopeTimer()
-        with t.measure("stage_a"):
-            time.sleep(0.001)
-        with t.measure("stage_a"):
-            pass
-        with t.measure("stage_b"):
-            pass
-        d = t.summary_dict()
-        assert set(d) == {"stage_a", "stage_b"}
-        assert d["stage_a"]["calls"] == 2
-        assert d["stage_a"]["total_s"] >= 0.001
-        assert d["stage_a"]["mean_ms"] == pytest.approx(
-            d["stage_a"]["total_s"] / 2 * 1e3, rel=1e-2)
-        path = tmp_path / "m.jsonl"
-        with MetricsSink(str(path)) as sink:
-            rec = t.emit(sink)
-        assert rec["kind"] == "scope_timer"
-        lines = [json.loads(l) for l in path.read_text().splitlines()]
-        assert [l["kind"] for l in lines] == ["meta", "scope_timer"]
-        got = lines[1]                    # past the sink's meta header
-        assert got["scopes"]["stage_b"]["calls"] == 1
-
-    def test_measure_feeds_spans_when_tracing(self, global_tracing):
-        t = ScopeTimer()
-        with t.measure("gather"):
-            pass
-        names = [r[0] for r in global_tracing.records()]
-        assert "scope.gather" in names
 
 
 class TestBenchRegress:
