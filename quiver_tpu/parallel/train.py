@@ -42,14 +42,21 @@ def cross_entropy_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
-    """LayerSamples (sampling order) -> Adj list (outermost hop first)."""
+    """LayerSamples (sampling order) -> Adj list (outermost hop first).
+
+    Precondition: every layer is a ``compact_layer`` output over dense
+    seeds (``seeds_dense=True``'s promise: the hop-0 batch is valid-first
+    with -1 at the tail only; hops >= 1 always are). A valid seed's local
+    id is then its position, so edge slot ``e`` targets ``e // fanout``
+    or nothing, which is what each ``Adj.fanout`` set here states. A
+    caller that cannot promise it builds its ``Adj``s without a fanout."""
     shapes = layer_shapes(batch_size, sizes)
     adjs = []
     for layer, shape in zip(layers, shapes):
         adjs.append(Adj(edge_index=jnp.stack([layer.col, layer.row]),
                         e_id=layer.e_id,
                         size=(shape.n_id_cap, shape.num_seeds),
-                        mask=layer.col >= 0))
+                        mask=layer.col >= 0, fanout=shape.fanout))
     return adjs[::-1]
 
 

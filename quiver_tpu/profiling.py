@@ -23,11 +23,14 @@ scope = jax.named_scope
 # sit beneath ``qt_sample_hop{i}`` (ops/sample_multihop.py), which
 # together with ``qt_serve_forward`` (serving.py) predates them; under
 # ``value_and_grad`` an op reads ``jvp(<scope>)``, the backward's
-# ``transpose(jvp(<scope>))``.
+# ``transpose(jvp(<scope>))``. ``qt_aggregate_dense`` sits inside
+# ``qt_aggregate`` and is there exactly when the mean ran as a reduce
+# over the fanout axis (models/sage.py): a choice made at trace time
+# leaves its record in the program's names.
 (QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
- QT_AGGREGATE) = DEVICE_SCOPES = (
+ QT_AGGREGATE, QT_AGGREGATE_DENSE) = DEVICE_SCOPES = (
     "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
-    "qt_optimizer", "qt_aggregate")
+    "qt_optimizer", "qt_aggregate", "qt_aggregate_dense")
 
 
 def hot_path(fn):

@@ -51,17 +51,25 @@ class Adj:
                 don't have to rederive it).
     size:       (cap_source_nodes, cap_target_nodes) static capacities —
                 pytree aux data, so Adjs cross jit boundaries safely.
+    fanout:     static like ``size``; ``None`` promises nothing. An int
+                ``k`` states the slot layout and nothing else: every
+                valid edge slot ``e`` targets node ``e // k``, and
+                ``edge_index.shape[1] == size[1] * k``. Consumers may
+                then reduce over the fanout axis without reading row 1
+                (``models.sage.masked_mean_aggregate``). Set where the
+                producer guarantees it (``parallel.train.layers_to_adjs``).
 
     Supports PyG-style destructuring: ``edge_index, e_id, size = adj``.
     """
 
-    __slots__ = ("edge_index", "e_id", "size", "mask")
+    __slots__ = ("edge_index", "e_id", "size", "mask", "fanout")
 
-    def __init__(self, edge_index, e_id, size, mask=None):
+    def __init__(self, edge_index, e_id, size, mask=None, fanout=None):
         self.edge_index = edge_index
         self.e_id = e_id
         self.size = tuple(size)
         self.mask = mask if mask is not None else edge_index[0] >= 0
+        self.fanout = fanout
 
     def __iter__(self):
         return iter((self.edge_index, self.e_id, self.size))
@@ -70,11 +78,13 @@ class Adj:
         return self
 
     def tree_flatten(self):
-        return (self.edge_index, self.e_id, self.mask), self.size
+        return ((self.edge_index, self.e_id, self.mask),
+                (self.size, self.fanout))
 
     @classmethod
-    def tree_unflatten(cls, size, leaves):
-        return cls(leaves[0], leaves[1], size, leaves[2])
+    def tree_unflatten(cls, aux, leaves):
+        size, fanout = aux
+        return cls(leaves[0], leaves[1], size, leaves[2], fanout)
 
 
 class _LayerShape(NamedTuple):

@@ -7,7 +7,9 @@ The scope contracts:
    of the compiled train steps (the forward-only serve step has no
    loss, backward or optimizer); ``qt_draw``/``qt_compact`` sit beneath
    a ``qt_sample_hop<i>`` and nowhere else; the backward's ops read
-   ``transpose(jvp(qt_forward))``.
+   ``transpose(jvp(qt_forward))``; every builder's layers state their
+   fanout, so all of ``qt_aggregate`` lies under ``qt_aggregate_dense``
+   and the forward holds no scatter.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -34,7 +36,7 @@ from quiver_tpu.serving import build_serve_step
 N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
 TRAIN_SCOPES = set(profiling.DEVICE_SCOPES)
 SERVE_SCOPES = {profiling.QT_DRAW, profiling.QT_COMPACT, profiling.QT_GATHER,
-                profiling.QT_AGGREGATE}
+                profiling.QT_AGGREGATE, profiling.QT_AGGREGATE_DENSE}
 
 
 class TestHotPath:
@@ -105,6 +107,9 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
     for n in names:
         if profiling.QT_DRAW in n or profiling.QT_COMPACT in n:
             assert re.search(r"qt_sample_hop\d\)?/qt_(draw|compact)", n), n
+        # the aggregation ran as the dense reduce, all of it
+        if profiling.QT_AGGREGATE in n:
+            assert "qt_aggregate/qt_aggregate_dense/" in n, n
     hops = {m.group(0) for n in names
             for m in [re.search(r"qt_sample_hop\d", n)] if m}
     assert hops == {f"qt_sample_hop{i}" for i in range(len(SIZES))}
