@@ -5,16 +5,18 @@ Not part of a run: the builder of a benchmark PR calls it on the chip.
     python3 -m chipbench.prove sweep --workload <serve cell> --seed 1
 
 ``readings`` sets one cell up once per seed in ONE process (its programs
-compile once) and prints, for each seed, the numbers of a sound run (the
-lower readings) and, for the control seeds, the control's (the float32
-reference rerun in bfloat16 and put in the program's place) and each
-fault's, planted in the reference put in the program's place, each with
-what ``harness.finish`` says of it under the cell's limits (``correct``,
+compile once; what is read is the entry's ``Run.readings``) and prints,
+for each seed, the numbers of a sound run (the lower readings) and, for
+the control seeds, the control's (the float32 reference rerun in
+bfloat16 and put in the program's place) and each fault's, planted in
+the reference put in the program's place, each with what
+``harness.finish`` says of it under the cell's limits (``correct``,
 ``over``): the control and the faults have to come out not correct. Training
 reads its first three steps; serving reads a short window at the cell's
 own load.
 
-``sweep`` finds the knee of an open-loop serve cell: rates doubling from
+``sweep`` finds the knee of an open-loop serve cell (an entry whose ``Run``
+has ``start_server`` and a ``window(seconds, mix)``): rates doubling from
 ``--start``, then bisecting; the highest rate with no refusal, no backlog
 left at the close and p95 under 5 x the median latency of a lone request.
 """
@@ -44,7 +46,7 @@ def _judged(cell, numbers: dict) -> dict:
 
 
 def readings(args):
-    from . import check, harness, spec
+    from . import harness, spec
     cell = spec.Cell(args.workload)
     devices, _, _ = harness.claim_devices(cell.chips)
     import jax
@@ -53,46 +55,13 @@ def readings(args):
     seeds = [int(s) for s in args.seeds.split(",")]
     control = {int(s) for s in args.control_seeds.split(",")} \
         if args.control_seeds else set()
-    is_train = cell.entry in ("train_step", "dp_train_step")
+    entry = spec.plugin("entries", cell.entry)
     for seed in seeds:
         t = time.perf_counter()
-        if is_train:
-            from . import train_cell as tc
-            run = tc.TrainRun(cell, seed, devices)
-            kept = run.first_steps()
-            run.free()
-            ref, facts = tc.follow(run, kept)
-
-            def read(kind, numbers):
-                out = check.train_numbers(numbers, ref, facts)
-                out.pop("facts")
-                _say(seed=seed, kind=kind, **_judged(cell, out))
-
-            read("program", tc.program_numbers(kept))
-            if seed in control:
-                read("control_bfloat16", tc.follow(
-                    run, kept, precision="bfloat16", verify=False)[0])
-                for fault in ["half_batch"] + (["no_exchange"]
-                                               if cell.chips > 1 else []):
-                    read("fault_" + fault, tc.follow(
-                        run, kept, fault=fault, verify=False)[0])
-        else:
-            from . import serve_cell as sc
-            run = sc.ServeRun(cell, seed, devices)
-            run.warm()
-            win = run.window(args.seconds)
-            run.stop_server()
-            out = sc.compare(run, win, int(cell.cell["check_batches"]),
-                             control=seed in control)
-            facts = out.pop("facts")
-            low = out.pop("control_gap", None)
-            _say(seed=seed, kind="program", p95_ms=sc.p95_ms(win["latency_s"])
-                 if "latency_s" in win else None,
-                 req_per_s=win["answered_in_window"] / win["seconds"],
-                 **facts, **_judged(cell, out))
-            if low is not None:
-                _say(seed=seed, kind="control_bfloat16",
-                     **_judged(cell, dict(out, row_gap=low)))
+        run = entry.Run(cell, seed, devices)
+        for kind, numbers, shown in run.readings(args.seconds,
+                                                 seed in control):
+            _say(seed=seed, kind=kind, **shown, **_judged(cell, numbers))
         del run
         gc.collect()
         _say(seed=seed, took_s=time.perf_counter() - t)
@@ -105,15 +74,15 @@ def sweep(args):
     import jax
     jax.config.update("jax_default_matmul_precision",
                       cell.config["precision"]["matmul"])
-    run = sc.ServeRun(cell, args.seed, devices)
-    run.warm()
-    run.stop_server()
+    run = spec.plugin("entries", cell.entry).Run(cell, args.seed, devices)
+    run.setup()
+    run.stop()
 
     def trial(rate, seconds):
         mix = dict(cell.traffic, rate_per_s=rate)
         run.start_server()
         win = run.window(seconds, mix)
-        snap = run.stop_server()
+        snap = run.stop()
         lat = np.asarray(win["latency_s"])
         fin = lat[np.isfinite(lat)]
         # a backlog that grows: the second half's median well over the first's

@@ -1,6 +1,10 @@
 """Per-layer metrics. Each metric is a file ``layer_metrics/<name>.json``
-that names one of these reducers and its arguments; a metric that reads an
-existing scope, counter or host series is added as a file, with no code.
+that names a reducer and its arguments; a metric that reads an existing
+scope, counter or host series is added as a file, with no code. A reducer
+is one of ``REDUCERS`` below or, found by name, ``reducers/<name>.py``'s
+``reduce(ctx, **args)``. A count of work (bytes, FLOPs) comes from the
+cell's shapes through ``work/<name>.py``'s ``work(cell, **args)``, never
+from the operations the program happens to use.
 
 A reducer gets the traced run (``ctx``) and returns a number, or None
 where it finds nothing to read: the harness then leaves the metric out
@@ -17,20 +21,24 @@ from __future__ import annotations
 import re
 import statistics
 
-from . import flops
+from . import flops, spec
 
 
 def _fill(template: str, cell) -> str:
     """``{nodes}``, ``{dim}``, ``{frontier}`` ... from the cell's shapes."""
     cfg = cell.config
-    batch = int(cell.traffic["batch"] if "batch" in cell.traffic
-                else cell.cell["server"]["batch_cap"])
-    caps = flops.frontier_caps(batch, cfg["fanout"])
+    caps = flops.frontier_caps(cell.batch, cfg["fanout"])
     return template.format(nodes=cfg["nodes"], dim=cfg["feature_dim"],
-                           frontier=caps[-1], batch=batch)
+                           frontier=caps[-1], batch=cell.batch)
 
 
-def _per(ctx, per):
+def work_of(cell, name: str, **args) -> dict:
+    """``{"bytes": ..., "flops": ...}`` (either or both) of one execution,
+    from ``work/<name>.py``."""
+    return spec.plugin("work", name).work(cell, **args)
+
+
+def count_of(ctx, per):
     if per is None:
         return 1.0
     n = ctx["facts"].get(per)
@@ -42,7 +50,7 @@ def scope_ms(ctx, pattern, per=None):
     divided by the run's count ``per`` (``steps``, ``batches``)."""
     rx = re.compile(pattern)
     s = ctx["trace"].seconds(lambda o: rx.search(o.scope) is not None)
-    n = _per(ctx, per)
+    n = count_of(ctx, per)
     return None if s is None or n is None else 1e3 * s / n
 
 
@@ -59,7 +67,7 @@ def opcode_ms(ctx, pattern, per=None):
     ``pattern`` (an opcode such as ``all-reduce``), in ms per ``per``."""
     rx = re.compile(pattern)
     s = ctx["trace"].seconds(lambda o: rx.search(o.text) is not None)
-    n = _per(ctx, per)
+    n = count_of(ctx, per)
     return None if s is None or n is None else 1e3 * s / n
 
 
@@ -84,27 +92,26 @@ def gather_roofline(ctx, operand, result, per):
         return head.split(" = ", 1)[-1].startswith(result) and operand in args
 
     s = ctx["trace"].seconds(pick)
-    n = _per(ctx, per)
-    if s is None or n is None or s <= 0:
+    n = count_of(ctx, per)
+    if s is None or n is None or s <= 0 or not ctx.get("peaks"):
         return None
-    rows = int(_fill("{frontier}", cell))
-    least = flops.gather_bytes(rows, cell.config["feature_dim"]) \
+    least = work_of(cell, "frontier_gather")["bytes"] \
         / ctx["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least / (s / n)
 
 
 def step_mfu(ctx, per, train):
-    """The whole step's share of the chips' peak: the SAGE layers'
-    matmul FLOPs at the cell's shapes, times the executions in the traced
-    window, over window x chips x peak."""
+    """The whole step's share of the chips' peak: the model's FLOPs at
+    the cell's shapes (the work function the configuration names under
+    ``step_flops``; absent: the SAGE layers' matmuls), times the
+    executions in the traced window, over window x chips x peak."""
     cell = ctx["cell"]
-    n = _per(ctx, per)
+    n = count_of(ctx, per)
     t = ctx["trace"]
-    if n is None or t.window_s <= 0:
+    if n is None or t.window_s <= 0 or not ctx.get("peaks"):
         return None
-    batch = int(_fill("{batch}", cell))
-    per_exec = flops.sage_matmul_flops(batch, cell.config["fanout"],
-                                       cell.dims, bool(train))
+    per_exec = work_of(cell, cell.named("step_flops"),
+                       train=bool(train))["flops"]
     # a data-parallel step runs one such batch on every chip
     work = per_exec * n * ctx["chips"]
     return 100.0 * work / (t.window_s * ctx["chips"]
@@ -146,11 +153,16 @@ REDUCERS = {f.__name__: f for f in (
     host_stat, counter_ratio)}
 
 
+def reducer(name: str):
+    """``REDUCERS[name]``, else ``reducers/<name>.py``'s ``reduce``."""
+    return REDUCERS.get(name) or spec.plugin("reducers", name).reduce
+
+
 def read_all(ctx) -> dict:
     """Every per-layer metric of the cell that finds something to read."""
     out = {}
     for m in ctx["cell"].per_layer:
-        value = REDUCERS[m["reducer"]](ctx, **m.get("args", {}))
+        value = reducer(m["reducer"])(ctx, **m.get("args", {}))
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out

@@ -8,6 +8,21 @@ The last line of standard output is the result. With ``--trace 1`` the
 window is the cell's ``trace_seconds`` at most, the profiler is on, and
 the metrics are the per-layer ones. It needs the chip: on any other
 backend it exits 3 and prints no result.
+
+What runs is the cell's entry, ``entries/<entry>.py``, found by name
+(``spec.plugin``). This file drives ONE interface and knows no entry:
+
+    Run(cell, seed, devices, faults)   the world, the weights, the program
+    .setup()                           first steps or warm batches
+    .window(seconds) -> win            the measured window; ``win`` is the entry's own
+    .stop() -> counters or None        stop what runs beside the loop
+    .program_text() -> str             compiled text of what the window drove
+    .free()                            drop the program's state, keep the world
+    .outcome(win) -> dict              ``numbers`` (compared, each under a limit of
+                                       the cell file), ``values`` (end-to-end, by
+                                       metric name), ``attempted``, ``failed``,
+                                       ``facts`` (what the reducers read) and
+                                       ``shown`` (the run's record, optional)
 """
 
 from __future__ import annotations
@@ -40,20 +55,9 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool, *,
     window_s = min(seconds, float(cell.cell["trace_seconds"])) if traced \
         else seconds
     profile = harness.Profile(traced)
-    is_train = cell.entry in ("train_step", "dp_train_step")
-    extra = {}
 
-    if is_train:
-        from . import train_cell
-        run = train_cell.TrainRun(cell, seed, devices, faults)
-        kept = run.first_steps()
-        for _ in range(2):              # settle: the loop's own rhythm
-            run.call(run.feed())
-        jax.block_until_ready(run.state)
-    else:
-        from . import serve_cell
-        run = serve_cell.ServeRun(cell, seed, devices, faults)
-        run.warm()
+    run = spec.plugin("entries", cell.entry).Run(cell, seed, devices, faults)
+    run.setup()
     # what set-up built stays out of the collector's way: a full collection
     # over jax's million objects would stall every thread of the window
     gc.collect()
@@ -66,37 +70,21 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool, *,
         win = run.window(window_s)
     profile.stop()
     in_window = compiles.count - before
-    counters = None if is_train else run.stop_server()
+    counters = run.stop()
     device = harness.device_block(devices, cell.chips)
 
-    hlo = _program_text(run, is_train) if traced else None
+    hlo = run.program_text() if traced else None
     run.free()
-    if is_train:
-        numbers = train_cell.compare(run, kept)
-        values = {"train_seeds_per_s": win["seeds_per_s"], "setup_s": setup_s}
-        attempted, failed = win["steps"], win["nonfinite"]
-        facts = {"steps": win["steps"], "enqueue_s": win["enqueue_s"]}
-        numbers["nonfinite_losses"] = float(win["nonfinite"])
-    else:
-        numbers = serve_cell.compare(run, win, int(cell.cell["check_batches"]))
-        values = {"setup_s": setup_s,
-                  "serve_req_per_s": win["answered_in_window"] / win["seconds"]}
-        if "latency_s" in win:
-            values["serve_p95_ms"] = serve_cell.p95_ms(win["latency_s"])
-            extra = serve_cell.latency_facts(win)
-        attempted, failed = win["attempted"], win["failed"]
-        facts = {"batches": win["batches"],
-                 "gen_late_s": win.get("gen_late_s"),
-                 "queue_wait_s": win.get("queue_wait_s")}
-    numbers["compiles_in_window"] = float(in_window)
-    shown = dict(numbers.pop("facts", {}), **extra)
+    out = run.outcome(win)
+    numbers = dict(out["numbers"], compiles_in_window=float(in_window))
     compared = {k: (v, float(cell.limits[k])) for k, v in numbers.items()}
+    values = dict(out["values"], setup_s=setup_s)
 
-    result = {"attempted": int(attempted), "failed": int(failed)}
+    result = {"attempted": int(out["attempted"]), "failed": int(out["failed"])}
     if traced:
         tr = trace.Trace(profile.xplane(), trace.scopes_of(hlo),
                          chips=cell.chips)
-        ctx = {"trace": tr, "facts": facts, "counters": counters,
+        ctx = {"trace": tr, "facts": out["facts"], "counters": counters,
                "cell": cell, "peaks": peaks, "chips": cell.chips}
         result["metrics"] = readers.read_all(ctx)
         device.update(busy_s=tr.busy_s, window_s=tr.window_s)
@@ -107,33 +95,12 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool, *,
         result["metrics"] = _metrics(cell, values)
     result["device"] = device
     stats = devices[0].memory_stats() or {}
-    result["run"] = dict(shown, workload=name, seed=seed, window_s=window_s,
+    result["run"] = dict(out.get("shown", {}), workload=name, seed=seed,
+                         window_s=window_s,
                          memory_stats={k: int(v) for k, v in stats.items()
                                        if isinstance(v, (int, float))},
-                         setup_s=setup_s,
-                         **{k: v for k, v in values.items() if k != "setup_s"})
+                         **values)
     return result, compared
-
-
-def _program_text(run, is_train):
-    """The compiled text of the program the window drove, for the scopes
-    of the trace's instructions (the persistent cache has it)."""
-    import jax.numpy as jnp
-    w = run.world
-    if is_train:
-        fed = run.feed()
-        fn = run.step.jitted_fns[-1] if hasattr(run.step, "jitted_fns") \
-            else None
-        if fn is None:
-            return ""
-        return fn.lower(run.state, w["feat"], None, w["indptr"], w["indices"],
-                        fed[1], fed[2], fed[3]).compile().as_text()
-    import jax
-    from .train_cell import program_tree
-    fn = run.engine.jitted_fns[0]
-    return fn.lower(program_tree(run.layers), jax.random.key(0), w["feat"],
-                    None, w["indptr"], w["indices"],
-                    jnp.zeros((run.cap,), jnp.int32)).compile().as_text()
 
 
 def main(argv=None) -> int:

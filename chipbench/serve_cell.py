@@ -1,4 +1,5 @@
-"""Serving cells: ``MicroBatchServer.submit`` over a ``ServeEngine``.
+"""What the entry ``micro_batch_server`` is made of:
+``MicroBatchServer.submit`` over a ``ServeEngine``.
 
 The traffic's ``kind`` chooses the loop. ``open_loop`` sends on the mix's
 schedule whatever the server does, and times every request from when it
@@ -70,12 +71,18 @@ class _Tally:
 
 
 class ServeRun:
+    """The engine, its server and the loops that load it; ``run.py``'s
+    interface (``setup``, ``window``, ``stop``, ``program_text``, ``free``,
+    ``outcome``) and ``prove.py``'s (``readings``; ``start_server`` and a
+    ``window`` that takes a mix, for the sweep)."""
+
     def __init__(self, cell, seed: int, devices, faults=()):
         import jax
         import quiver_tpu as qv
         from quiver_tpu.models import GraphSAGE
         cfg = cell.config
         self.cell, self.seed = cell, seed
+        self.ref = cell.reference
         self.devices = devices[:1]
         self.server_cfg = dict(cell.cell["server"])
         self.cap = int(self.server_cfg.pop("batch_cap"))
@@ -83,7 +90,7 @@ class ServeRun:
         self.nodes = int(cfg["nodes"])
         self.world = world.make_world(cfg, seed)
         self.layers = jax.jit(
-            lambda k: reference.init_layers(k, cell.dims))(
+            lambda k: self.ref.init_layers(k, cell.dims))(
                 jax.random.fold_in(world.seed_key(seed), 7))
         model = GraphSAGE(hidden_dim=cfg["hidden_dim"],
                           out_dim=cfg["num_classes"],
@@ -105,7 +112,7 @@ class ServeRun:
             self.engine, self.qv.ServeConfig(**self.server_cfg))
         return self.server
 
-    def warm(self):
+    def setup(self):
         """A few full batches through the server itself, so that the
         window's first requests find every thread and path warm."""
         srv = self.start_server()
@@ -222,14 +229,58 @@ class ServeRun:
                                    - (t0 + due[ok])).tolist()
         return out
 
-    def stop_server(self):
+    def stop(self):
+        """Close the server and hand over its counters."""
         snap = self.server.snapshot()["serving"]
         self.server.close()
         self.server = None
         return snap
 
+    def program_text(self) -> str:
+        """The compiled text of the serve step the window drove (the
+        persistent cache has it)."""
+        import jax
+        import jax.numpy as jnp
+        w = self.world
+        fn = self.engine.jitted_fns[0]
+        return fn.lower(program_tree(self.layers), jax.random.key(0),
+                        w["feat"], None, w["indptr"], w["indices"],
+                        jnp.zeros((self.cap,), jnp.int32)).compile().as_text()
+
     def free(self):
         self.engine = None
+
+    def outcome(self, win: dict) -> dict:
+        """A sample of the window's batches against the reference, and
+        what the window counted."""
+        numbers = compare(self, win, int(self.cell.cell["check_batches"]))
+        shown = numbers.pop("facts")
+        values = {"serve_req_per_s": win["answered_in_window"] / win["seconds"]}
+        if "latency_s" in win:
+            values["serve_p95_ms"] = p95_ms(win["latency_s"])
+            shown.update(latency_facts(win))
+        return {"numbers": numbers, "shown": shown, "values": values,
+                "attempted": win["attempted"], "failed": win["failed"],
+                "facts": {"batches": win["batches"],
+                          "gen_late_s": win.get("gen_late_s"),
+                          "queue_wait_s": win.get("queue_wait_s")}}
+
+    def readings(self, seconds: float, control: bool):
+        """``(kind, numbers, shown)`` of a short window at the cell's own
+        load and, with ``control``, of the bfloat16 reference put in the
+        program's place on the same batches."""
+        self.setup()
+        win = self.window(seconds)
+        self.stop()
+        out = compare(self, win, int(self.cell.cell["check_batches"]),
+                      control=control)
+        facts = out.pop("facts")
+        low = out.pop("control_gap", None)
+        yield "program", out, dict(
+            facts, p95_ms=p95_ms(win["latency_s"]) if "latency_s" in win
+            else None, req_per_s=win["answered_in_window"] / win["seconds"])
+        if low is not None:
+            yield "control_bfloat16", dict(out, row_gap=low), {}
 
 
 def p95_ms(latency_s) -> float:
@@ -296,8 +347,8 @@ def compare(run: ServeRun, win: dict, batches: int, *, control=False) -> dict:
         def f(layers, feat, sample):
             hops = sample.hops
             targets = [run.cap] + [h.n_id.shape[0] for h in hops[:-1]]
-            x = reference.gather_rows(feat, hops[-1].n_id)
-            return reference.forward(layers, x, hops, targets, dtype=dtype)
+            x = run.ref.gather_rows(feat, hops[-1].n_id)
+            return run.ref.forward(layers, x, hops, targets, dtype=dtype)
         return jax.jit(f)
 
     ref_fwd = fwd(jnp.float32)

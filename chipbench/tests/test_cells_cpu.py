@@ -62,10 +62,10 @@ def test_control_bfloat16_fails_training(tiny, name):
     from chipbench import check, harness, spec, train_cell as tc
     cell = spec.Cell(name)
     jax.config.update("jax_default_matmul_precision", "highest")
-    r = tc.TrainRun(cell, SEED, jax.devices())
+    r = spec.plugin("entries", cell.entry).Run(cell, SEED, jax.devices())
     kept = r.first_steps()
     ref, facts = tc.follow(r, kept)
-    sound = check.train_numbers(tc.program_numbers(kept), ref, facts)
+    sound = check.train_numbers(tc.program_numbers(r, kept), ref, facts)
     low, _ = tc.follow(r, kept, precision="bfloat16", verify=False)
     control = check.train_numbers(low, ref, facts)
     for k in ("loss_gap", "grad_gap"):
@@ -83,10 +83,10 @@ def test_control_bfloat16_fails_serving(tiny):
     from chipbench import harness, serve_cell as sc, spec
     cell = spec.Cell("tiny-flood")
     jax.config.update("jax_default_matmul_precision", "highest")
-    r = sc.ServeRun(cell, SEED, jax.devices())
-    r.warm()
+    r = spec.plugin("entries", cell.entry).Run(cell, SEED, jax.devices())
+    r.setup()
     win = r.window(0.5)
-    r.stop_server()
+    r.stop()
     out = sc.compare(r, win, 4, control=True)
     assert out["row_gap"] <= cell.limits["row_gap"] < out["control_gap"]
     out.pop("facts")

@@ -1,7 +1,7 @@
 """The per-layer metrics as data: every ``layer_metrics/*.json`` names a
 reducer that exists and sits under the end-to-end metric its suffix
 says; the counter metrics read the server's own ``snapshot()["serving"]``
-of a tiny run; the device-scope metrics read a pair of small traces
+of a tiny run (``tiny/BENCHMARK.json`` enters them under the tiny cells); the device-scope metrics read a pair of small traces
 recorded on the chip (PR 25: three steps of a 200,000-node two-hop train
 step, batch 256, and three batches of the serve step over the same graph
 through ``MicroBatchServer``, each with its compiled text).
@@ -32,27 +32,11 @@ COUNTER_METRICS = {
 TRAIN, STEADY = "papers100m-sage-train", "papers100m-sage-serve-steady"
 
 
-@pytest.fixture
-def tiny_counters(tiny, tmp_path, monkeypatch):
-    """The tiny cells with BENCHMARK.json's counter metrics entered under
-    them: ``tiny/BENCHMARK.json`` itself dates from PR 24 and stays as it
-    is, so the entries are made here from the real file's."""
-    with open(spec.BENCHMARK_FILE) as f:
-        bench = json.load(f)
-    real = {e["name"]: e for e in json.load(
-        open(os.path.join(spec.ROOT, "BENCHMARK.json")))["per_layer"]}
-    for cell, names in COUNTER_METRICS.items():
-        bench["per_layer"] += [dict(real[n], workloads=[cell]) for n in names]
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(bench))
-    monkeypatch.setattr(spec, "BENCHMARK_FILE", str(path))
-
-
 @pytest.mark.parametrize("name", METRIC_FILES)
 def test_metric_file_names_a_reducer_and_its_suffix_agrees(name):
     with open(os.path.join(spec.HERE, "layer_metrics", name + ".json")) as f:
         m = json.load(f)
-    assert m["reducer"] in readers.REDUCERS
+    assert callable(readers.reducer(m["reducer"]))
     assert m["reads"]
     tiny = os.path.join(HERE, "tiny", "BENCHMARK.json")
     entries = [e for path in (spec.BENCHMARK_FILE, tiny)
@@ -73,14 +57,14 @@ class _NoDevice:
 
 
 @pytest.mark.parametrize("name", sorted(COUNTER_METRICS))
-def test_counter_metrics_read_the_servers_snapshot(tiny_counters, name):
+def test_counter_metrics_read_the_servers_snapshot(tiny, name):
     import jax
-    from chipbench import serve_cell
     cell = spec.Cell(name)
-    run = serve_cell.ServeRun(cell, 2**31 + 5, jax.devices())
-    run.warm()
+    run = spec.plugin("entries", cell.entry).Run(cell, 2**31 + 5,
+                                                 jax.devices())
+    run.setup()
     win = run.window(0.5)
-    counters = run.stop_server()
+    counters = run.stop()
     got = readers.read_all({
         "trace": _NoDevice(), "facts": {"batches": win["batches"]},
         "counters": counters, "cell": cell, "peaks": None, "chips": 1})
@@ -139,6 +123,34 @@ def test_recorded_train_scopes(facts):
     top = [k for k, _ in tr.top_ops()]
     assert "qt_gather" in top and "qt_forward" in top
     assert not any("jvp(GraphSAGE)" in k for k in top)
+
+
+def test_scope_roofline_on_the_recorded_trace(facts):
+    """``scope_roofline`` over ``qt_gather`` with the frontier gather's bytes
+    from the recorded step's shapes: the same least time as
+    ``gather_roofline`` takes over a longer one (the scope also holds the
+    mask multiply), so a little under it, and far under 100 %."""
+    import types
+    cfg = facts["cfg"]
+    cell = types.SimpleNamespace(config=cfg, batch=facts["batch"])
+    tr, _ = _recorded("train", TRAIN, {"steps": facts["steps"]})
+    ctx = {"trace": tr, "facts": {"steps": facts["steps"]}, "cell": cell,
+           "peaks": spec.peaks("TPU v5 lite")}
+    by_scope = readers.reducer("scope_roofline")(
+        ctx, pattern="qt_gather", per="steps", work="frontier_gather",
+        peak="hbm_bytes_per_s")
+    by_shape = readers.gather_roofline(
+        ctx, operand="f32[{nodes},{dim}]", result="f32[{frontier},{dim}]",
+        per="steps")
+    assert 0 < by_scope < by_shape < 100
+    rows = 256 * 11 * 6
+    least = rows * (2 * 64 * 4 + 4) / 819e9
+    assert by_scope == pytest.approx(
+        100 * least / (1e-3 * readers.scope_ms(ctx, "qt_gather", "steps")))
+    with pytest.raises(ValueError):
+        readers.reducer("scope_roofline")(
+            ctx, pattern="qt_gather", per="steps", work="frontier_gather",
+            peak="int8_ops_per_s")
 
 
 def test_recorded_serve_scopes(facts):

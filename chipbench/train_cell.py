@@ -1,5 +1,7 @@
-"""Training cells: ``build_train_step`` on one chip, ``build_e2e_train_step``
-data-parallel over a ``("data",)`` mesh.
+"""What the training entries share (``entries/train_step.py``,
+``entries/dp_train_step.py``): a GraphSAGE step of ``quiver_tpu`` over a
+replicated world, one batch a chip. An entry is ``TrainRun`` with its own
+``build_step``.
 
 Set-up builds ONE object, the compiled step with its state, drives it
 from the seed through its first steps (the ones the reference follows)
@@ -38,7 +40,13 @@ def reference_layers(tree):
 
 
 class TrainRun:
-    """The compiled step, its state and its feed."""
+    """The compiled step, its state and its feed; ``run.py``'s interface
+    (``setup``, ``window``, ``stop``, ``program_text``, ``free``,
+    ``outcome``) and ``prove.py``'s (``readings``)."""
+
+    def build_step(self, model, tx, mesh, **extra):
+        """The program the window drives: the entry's own."""
+        raise NotImplementedError
 
     def __init__(self, cell, seed: int, devices, faults=()):
         import jax
@@ -46,11 +54,11 @@ class TrainRun:
         import optax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from quiver_tpu.models import GraphSAGE
-        from quiver_tpu.parallel.train import (TrainState, build_e2e_train_step,
-                                               build_train_step)
+        from quiver_tpu.parallel.train import TrainState
 
         cfg, mix = cell.config, cell.traffic
         self.cell, self.seed = cell, seed
+        self.ref = cell.reference
         self.chips = cell.chips
         self.batch = int(mix["batch"])
         self.global_batch = self.batch * self.chips
@@ -75,7 +83,7 @@ class TrainRun:
         dims = cell.dims
 
         def make_state(key):
-            params = program_tree(reference.init_layers(key, dims))
+            params = program_tree(self.ref.init_layers(key, dims))
             return TrainState(params, tx.init(params),
                               jnp.zeros((), jnp.int32))
 
@@ -83,16 +91,8 @@ class TrainRun:
             jax.random.fold_in(world.seed_key(seed), 7))
         extra = {"loss_fn": _half_batch_loss} if "half_batch" in faults \
             else {}
-        if cell.entry == "train_step":
-            self.step = build_train_step(model, tx, self.fanout, self.batch,
-                                         method="exact", **extra)
-        elif cell.entry == "dp_train_step":
-            self.step = build_e2e_train_step(model, tx, self.fanout,
-                                             self.batch, mesh, **extra)
-        else:
-            raise SystemExit(f"chipbench: unknown train entry {cell.entry!r}")
         self.faults = tuple(faults)
-        self.step = _plant(self.step, faults)
+        self.step = _plant(self.build_step(model, tx, mesh, **extra), faults)
         self.labels = np.asarray(self.world["labels"])
         self.batches = traffic.train_batches(mix, cfg, seed, self.global_batch)
         self.base_key = jax.random.fold_in(world.seed_key(seed), 11)
@@ -162,10 +162,66 @@ class TrainRun:
                 "nonfinite": int((~np.isfinite(losses)).sum()),
                 "first_loss": float(losses[0]), "last_loss": float(losses[-1])}
 
+    def setup(self):
+        """The first steps, kept for the check, then two settling calls:
+        the loop's own rhythm."""
+        import jax
+        self.kept = self.first_steps()
+        for _ in range(2):
+            self.call(self.feed())
+        jax.block_until_ready(self.state)
+
+    def stop(self):
+        """Nothing runs beside the loop, and a step keeps no counters."""
+        return None
+
+    def program_text(self) -> str:
+        """The compiled text of the step the window drove, for the scopes
+        of the trace's instructions (the persistent cache has it)."""
+        if not hasattr(self.step, "jitted_fns"):    # a planted fault's wrapper
+            return ""
+        fn, w, fed = self.step.jitted_fns[-1], self.world, self.feed()
+        return fn.lower(self.state, w["feat"], None, w["indptr"], w["indices"],
+                        fed[1], fed[2], fed[3]).compile().as_text()
+
     def free(self):
         """Drop the program's state; the world stays for the reference."""
         self.state = None
         self.step = None
+
+    def outcome(self, win: dict) -> dict:
+        """The timed steps against the reference, and what the window
+        counted."""
+        numbers = compare(self, self.kept)
+        shown = numbers.pop("facts")
+        numbers["nonfinite_losses"] = float(win["nonfinite"])
+        return {"numbers": numbers, "shown": shown,
+                "values": {"train_seeds_per_s": win["seeds_per_s"]},
+                "attempted": win["steps"], "failed": win["nonfinite"],
+                "facts": {"steps": win["steps"],
+                          "enqueue_s": win["enqueue_s"]}}
+
+    def readings(self, seconds: float, control: bool):
+        """``(kind, numbers, shown)`` of a sound run's first steps and,
+        with ``control``, of the bfloat16 control and of each fault planted
+        in the reference put in the program's place. No window is needed."""
+        kept = self.first_steps()
+        self.free()
+        ref, facts = follow(self, kept)
+
+        def read(numbers):
+            out = check.train_numbers(numbers, ref, facts)
+            out.pop("facts")
+            return out
+
+        yield "program", read(program_numbers(self, kept)), {}
+        if control:
+            yield "control_bfloat16", read(follow(
+                self, kept, precision="bfloat16", verify=False)[0]), {}
+            for fault in ["half_batch"] + (["no_exchange"]
+                                           if self.chips > 1 else []):
+                yield "fault_" + fault, read(follow(
+                    self, kept, fault=fault, verify=False)[0]), {}
 
 
 def _plant(step, faults):
@@ -205,12 +261,12 @@ def _half_batch_loss(logits, labels):
     return cross_entropy_logits(logits[:h], labels[:h])
 
 
-def program_numbers(kept: dict) -> dict:
+def program_numbers(run: TrainRun, kept: dict) -> dict:
     """What the timed steps produced, in the reference's terms."""
     import jax
     return {"losses": [s["loss"] for s in kept["steps"]],
             "grad1": jax.tree.map(
-                lambda m: np.asarray(m) / (1 - reference.ADAM_B1),
+                lambda m: np.asarray(m) / (1 - run.ref.ADAM_B1),
                 reference_layers(kept["mu1"])),
             "params0": reference_layers(kept["params0"]),
             "params3": reference_layers(kept["params3"])}
@@ -235,10 +291,10 @@ def follow(run: TrainRun, kept: dict, *, precision="float32", fault=None,
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[precision]
     rows = slice(0, run.batch // 2) if fault == "half_batch" else None
     grad_fn = jax.jit(lambda layers, feat, sample, labels, key:
-                      reference.loss_and_grads(layers, feat, sample, labels,
-                                               key, dtype=dtype, rows=rows))
+                      run.ref.loss_and_grads(layers, feat, sample, labels,
+                                             key, dtype=dtype, rows=rows))
     layers0 = jax.device_put(reference_layers(kept["params0"]), dev0)
-    layers, opt = layers0, reference.adam_init(layers0)
+    layers, opt = layers0, run.ref.adam_init(layers0)
     losses, first_grads, facts = [], None, check.SampleFacts()
     # with the exchange left out, device 0 keeps its own shard's gradients
     shards = range(1) if fault == "no_exchange" else range(run.chips)
@@ -263,7 +319,7 @@ def follow(run: TrainRun, kept: dict, *, precision="float32", fault=None,
             *[o[1] for o in outs])
         if t == 0:
             first_grads = grads
-        layers, opt = reference.adam_update(layers, grads, opt, run.lr)
+        layers, opt = run.ref.adam_update(layers, grads, opt, run.lr)
     numbers = {"losses": losses, "grad1": jax.device_get(first_grads),
                "params0": jax.device_get(layers0),
                "params3": jax.device_get(layers)}
@@ -274,7 +330,7 @@ def compare(run: TrainRun, kept: dict) -> dict:
     """The numbers `correct` compares: the timed steps against the
     reference (``check.train_numbers``)."""
     ref, facts = follow(run, kept)
-    return check.train_numbers(program_numbers(kept), ref, facts)
+    return check.train_numbers(program_numbers(run, kept), ref, facts)
 
 
 def _on(x, device):
