@@ -25,6 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 from .ops import quant
 from .ops.dedup import I32_MAX, unique_within_budget
+from . import profiling
 from .profiling import hot_path
 
 
@@ -177,28 +178,40 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
 
     ``exchange_cap`` (None = dense) switches the collectives to the
     COMPACT deduplicated layout: the frontier's valid ids dedup once
-    into a static table (``ops.dedup.unique_within_budget``, budget
-    ``min(cap*H, B)``), the *unique* ids bucket by owner into a
-    [H, cap] request block — the same shape ``build_exchange_fn``
-    uses — and the wire carries [H, cap] requests + [H, cap, width]
-    responses instead of [H, B] / [H, B, width]; the inverse map
-    expands the unique rows back to batch order. A multi-hop frontier
-    is mostly -1 padding plus repeated hub ids, so ``B/cap``-ish fewer
-    bytes cross DCN while each distinct remote row moves exactly once.
-    When the unique count overflows the table or any per-owner bucket
-    overflows ``cap``, a ``lax.cond`` falls back to the dense path —
-    bit-identical output in every case (dequant is elementwise, so
-    expand-after-dequant equals dequant-after-expand). The overflow
-    flag is ``pmax``-reduced over ``axis`` first: the branch must be
-    UNIFORM across shards or the collectives inside it would deadlock.
+    (``ops.dedup.unique_within_budget``, integer work over the batch),
+    the *unique* ids bucket by owner into a [H, cap] request block —
+    the same shape ``build_exchange_fn`` uses — and the wire carries
+    [H, cap] requests + [H, cap, width] responses instead of [H, B] /
+    [H, B, width]; each batch slot then reads its row out of the
+    response block. A multi-hop frontier is mostly -1 padding plus
+    repeated hub ids, so ``B/cap``-ish fewer bytes cross DCN while
+    each distinct remote row moves exactly once. The exchange's
+    transient memory is BOUNDED BY ``cap``: no branch of the program
+    holds an [H, B, width] block. When an owner's bucket overflows
+    ``cap``, the ids past it are served by further rounds of the same
+    [H, cap] exchange (a ``fori_loop`` whose trip count is the
+    ``pmax`` over ``axis`` of ``ceil(fullest bucket / cap)``: the loop
+    carries collectives, so it must be UNIFORM across shards or they
+    would deadlock) — never by the dense blocks and never by dropping
+    rows. Output is bit-identical to the dense path in every case
+    (rows are copied; dequant is elementwise and runs on the rows in
+    batch order, as the dense path's does). A cap sized for the
+    traffic runs ONE round; each further round costs one more pass
+    over the [B, width] output.
 
-    ``collector`` (optional ``metrics.Collector``) records the branch
-    telemetry the cap planner flies blind on: whether the dense
-    fallback fired, the peak per-owner bucket load vs ``cap``, and the
-    dedup dup statistics — all from values this function already
-    computes OUTSIDE the ``lax.cond`` (the shard-uniform pmax'd flag
+    ``collector`` (optional ``metrics.Collector``) records the
+    telemetry the cap planner flies blind on: whether the lookup took
+    rounds beyond the first (``exchange_fallback``: a cap too small
+    for this batch), the peak per-owner bucket load vs ``cap``, and
+    the dedup dup statistics — all from values this function already
+    computes OUTSIDE the loop (the shard-uniform pmax'd count
     included), so collection adds no host sync and cannot perturb the
-    branch decision or the output.
+    rounds or the output.
+
+    Every op sits under the scope ``qt_exchange``
+    (``profiling.QT_EXCHANGE``), its stages beneath it: ``_route``,
+    ``_dedup``, ``_bucket``, ``_requests``, ``_gather``,
+    ``_responses``, ``_expand``.
     """
     batch = ids.shape[0]
     valid = ids >= 0
@@ -221,99 +234,115 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
             local = jnp.where(r, bases[me] + rep_rank[safe], local)
         return owner, local
 
-    def bucket(owner, local, valid_, cap_):
-        """Scatter ids into a [H, cap_] per-owner request block.
-        Returns (req, my_pos, counts): counts[h] = valid ids owned by
-        h — the compact path's overflow test; slots past ``cap_`` are
-        positively out-of-bounds and dropped."""
+    def bucket_pos(owner):
+        """Each id's place in its owner's bucket, and counts[h] = valid
+        ids owned by h (the compact path's count of rounds)."""
         onehot = owner[None, :] == jnp.arange(
             h_count, dtype=owner.dtype)[:, None]            # [H, n]
         bucket_pos = jnp.cumsum(onehot, axis=1) - 1         # [H, n]
         my_pos = jnp.sum(jnp.where(onehot, bucket_pos, 0), axis=0)
-        # invalid (-1 fill) entries must route to a POSITIVELY
-        # out-of-bounds row: `.at[...].set(mode="drop")` resolves
-        # negative indices NumPy-style BEFORE the bounds check, so
-        # owner=-1 would silently overwrite host H-1's bucket slot 0
-        owner_idx = jnp.where(valid_, owner, h_count)
-        req = jnp.zeros((h_count, cap_), jnp.int32).at[
-            owner_idx, my_pos].set(local, mode="drop")
-        return req, my_pos, jnp.sum(onehot, axis=1)
+        return my_pos, jnp.sum(onehot, axis=1)
 
-    def exchange(req, owner, my_pos):
+    def fill(owner, local, keep, slot, cap_):
+        """Scatter local rows into a [H, cap_] per-owner request block.
+        Entries outside ``keep`` (-1 fill, another round's ids) must
+        route to a POSITIVELY out-of-bounds row:
+        `.at[...].set(mode="drop")` resolves negative indices
+        NumPy-style BEFORE the bounds check, so owner=-1 would silently
+        overwrite host H-1's bucket slot 0."""
+        owner_idx = jnp.where(keep, owner, h_count)
+        return jnp.zeros((h_count, cap_), jnp.int32).at[
+            owner_idx, slot].set(local, mode="drop")
+
+    def ship(req):
         """The collective pair: requests out, local gather, responses
-        back, unbucket to the caller's slot order ([n, dim])."""
-        with jax.named_scope("qt_exchange_requests"):
+        back. Returns the [H, cap_, ...] response block, leaf by leaf:
+        the narrow payload + sidecars cross the collective, dequant
+        happens on the unbucketed rows, after the exchange."""
+        with profiling.scope(profiling.QT_EXCHANGE_REQUESTS):
             incoming = jax.lax.all_to_all(
                 req, axis, split_axis=0, concat_axis=0)
             read = jnp.clip(incoming, 0, rows_per_host - 1)
 
-        def ship(leaf):
-            with jax.named_scope("qt_exchange_gather"):
+        def leaf_round(leaf):
+            with profiling.scope(profiling.QT_EXCHANGE_GATHER):
                 rows = leaf[read]
-            with jax.named_scope("qt_exchange_responses"):
-                resp = jax.lax.all_to_all(
+            with profiling.scope(profiling.QT_EXCHANGE_RESPONSES):
+                return jax.lax.all_to_all(
                     rows, axis, split_axis=0, concat_axis=0)
-            return resp[jnp.clip(owner, 0), my_pos]
 
-        # narrow payload + sidecars cross the collective; dequant
-        # happens on the unbucketed result, after the exchange
-        return quant.dequantize(quant.tree_map_tier(ship, feat))
+        return quant.tree_map_tier(leaf_round, feat)
 
-    with jax.named_scope("qt_exchange_route"):
-        owner, local = route(ids, valid)
-    if collector is not None:
-        from .metrics import EXCH_CALLS
-        collector.add(EXCH_CALLS, 1)
+    def unbucket(resp, owner, slot):
+        """Response block -> the caller's slot order ([n, dim])."""
+        return quant.dequantize(quant.tree_map_tier(
+            lambda leaf: leaf[jnp.clip(owner, 0), slot], resp))
 
-    def dense_bucket():
-        with jax.named_scope("qt_exchange_bucket"):
-            return bucket(owner, local, valid, batch)
-
-    def dense(_=None):
-        # the lax.cond fallback body: must NOT touch the collector —
-        # entries recorded inside a cond branch would leak its tracers
-        req, my_pos, _counts = dense_bucket()
-        return exchange(req, owner, my_pos)
-
-    if exchange_cap is None or int(exchange_cap) >= batch:
-        req, my_pos, counts = dense_bucket()
+    def dense():
+        with profiling.scope(profiling.QT_EXCHANGE_BUCKET):
+            my_pos, counts = bucket_pos(owner)
+            req = fill(owner, local, valid, my_pos, batch)
         if collector is not None:
             from .metrics import EXCH_BUCKET_MAX
             collector.peak(EXCH_BUCKET_MAX, jnp.max(counts))
-        out = exchange(req, owner, my_pos)
-    else:
-        cap = int(exchange_cap)
-        u_budget = min(cap * h_count, batch)
-        uniq, inv, n_uniq = unique_within_budget(ids, u_budget,
-                                                 valid=valid,
-                                                 collector=collector)
+        return unbucket(ship(req), owner, my_pos)
+
+    def compact(cap):
+        """Rounds of the SAME [H, cap] exchange over the frontier's
+        distinct ids: round ``k`` carries, for every owner, the ids at
+        places ``[k * cap, (k + 1) * cap)`` of its bucket, and writes
+        their rows to the batch slots that asked for them. One round
+        when every bucket fits ``cap``; the count is ``pmax``-reduced
+        over ``axis`` first, since the loop carries collectives and
+        every shard must run it as often."""
+        with profiling.scope(profiling.QT_EXCHANGE_DEDUP):
+            uniq, inv, _ = unique_within_budget(
+                ids, batch, valid=valid, collector=collector)
         u_valid = uniq != I32_MAX
-        with jax.named_scope("qt_exchange_bucket"):
+        with profiling.scope(profiling.QT_EXCHANGE_BUCKET):
             owner_u, local_u = route(uniq, u_valid)
-            req_u, my_pos_u, counts = bucket(owner_u, local_u, u_valid,
-                                             cap)
-        bad = (n_uniq > u_budget) | (jnp.max(counts) > cap)
-        # the branch carries collectives: every shard must take the
-        # same one, so one scalar pmax unifies the overflow flag
-        bad = jax.lax.pmax(bad.astype(jnp.int32), axis) > 0
+            pos_u, counts = bucket_pos(owner_u)
+            pos = pos_u[inv]             # the place each batch slot reads
+            fullest = jnp.max(counts)
+            rounds = jax.lax.pmax(-(-fullest // cap), axis)
         if collector is not None:
-            # recorded OUTSIDE the cond, on the already-pmax'd flag —
-            # the predicate itself is untouched
+            # recorded outside the loop, the flag on the pmax'd count
             from .metrics import EXCH_BUCKET_MAX, EXCH_CAP, EXCH_FALLBACK
-            collector.add(EXCH_FALLBACK, bad)
-            collector.peak(EXCH_BUCKET_MAX, jnp.max(counts))
+            collector.add(EXCH_FALLBACK, rounds > 1)
+            collector.peak(EXCH_BUCKET_MAX, fullest)
             collector.peak(EXCH_CAP, cap)
 
-        def compact(_):
-            rows_u = exchange(req_u, owner_u,
-                              jnp.minimum(my_pos_u, cap - 1))
-            return jnp.take(rows_u, inv, axis=0)
+        def one_round(k, out):
+            base = k * cap
+            with profiling.scope(profiling.QT_EXCHANGE_BUCKET):
+                req = fill(owner_u, local_u, u_valid & (pos_u // cap == k),
+                           jnp.clip(pos_u - base, 0, cap - 1), cap)
+            resp = ship(req)
+            with profiling.scope(profiling.QT_EXCHANGE_EXPAND):
+                rows = unbucket(resp, owner,
+                                jnp.clip(pos - base, 0, cap - 1))
+                return jnp.where((valid & (pos // cap == k))[:, None],
+                                 rows, out)
 
-        out = jax.lax.cond(bad, dense, compact, None)
+        return jax.lax.fori_loop(
+            0, rounds, one_round,
+            jnp.zeros((batch, quant.tier_dim(feat)),
+                      quant.tier_dtype(feat)))
 
-    if dtype is None:
-        dtype = out.dtype
-    return jnp.where(valid[:, None], out, 0).astype(dtype)
+    with profiling.scope(profiling.QT_EXCHANGE):
+        with profiling.scope(profiling.QT_EXCHANGE_ROUTE):
+            owner, local = route(ids, valid)
+        if collector is not None:
+            from .metrics import EXCH_CALLS
+            collector.add(EXCH_CALLS, 1)
+        if exchange_cap is None or int(exchange_cap) >= batch:
+            out = dense()
+        else:
+            out = compact(int(exchange_cap))
+        if dtype is None:
+            dtype = out.dtype
+        with profiling.scope(profiling.QT_EXCHANGE_EXPAND):
+            return jnp.where(valid[:, None], out, 0).astype(dtype)
 
 
 def build_dist_lookup_fn(mesh: Mesh, axis: str, rows_per_host: int,

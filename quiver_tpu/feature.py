@@ -1213,14 +1213,33 @@ class PartitionInfo:
     set; ``global2local`` translates global -> host-local row."""
 
     def __init__(self, device=None, host: int = 0, hosts: int = 1,
-                 global2host=None, replicate=None):
+                 global2host=None, replicate=None, global2local=None):
+        """``global2local`` (optional, [N] int32) is the book of a store
+        whose shards are laid out already (``DistFeature.from_shards``):
+        node -> its row on its owner, taken as given and kept where it
+        is (a device array stays on its devices; only the per-host row
+        counts come to the host). Absent, a node's local row is its rank
+        among its owner's nodes in ascending id order, computed on the
+        host — the layout ``DistFeature.from_partition`` builds."""
         self.host = host
         self.hosts = hosts
         self.global2host = jnp.asarray(global2host, jnp.int32)
         self.replicate = None if replicate is None else \
             jnp.asarray(replicate, jnp.int32)
         self.node_count = int(self.global2host.shape[0])
-        self._init_global2local()
+        if global2local is None:
+            self._init_global2local()
+            return
+        if replicate is not None:
+            raise ValueError(
+                "a given global2local describes shards that exist; lay "
+                "the replicated rows into them and leave `replicate` "
+                "out, or let PartitionInfo compute the book")
+        self.global2local = jnp.asarray(global2local, jnp.int32)
+        if self.global2local.shape != self.global2host.shape:
+            raise ValueError("global2local and global2host differ in shape")
+        self.local_sizes = [int(c) for c in jax.device_get(
+            jnp.bincount(self.global2host, length=hosts))]
 
     def _init_global2local(self):
         g2h = np.asarray(jax.device_get(self.global2host))
@@ -1251,9 +1270,9 @@ class PartitionInfo:
         count when ``degree`` is omitted. ``cap`` is the heaviest
         owner's expected unique-request load times ``slack``; pass it
         as ``exchange_cap`` to the dist step / ``DistFeature``.
-        Overflow never costs correctness (the exchange falls back to
-        the dense block), only the traffic bound — so ``slack`` trades
-        wire bytes against fallback frequency."""
+        Overflow never costs correctness (the exchange takes further
+        rounds of the same block), only time — so ``slack`` trades
+        wire bytes against how often a second round runs."""
         uniq = max(int(frontier_cap / max(dup_factor, 1.0)), self.hosts)
         g2h = np.asarray(jax.device_get(self.global2host))
         if degree is not None:
@@ -1328,9 +1347,9 @@ class DistFeature:
         # exchange_cap: run the exchange itself over the compact
         # deduplicated [H, cap] request block (comm.dist_lookup_local)
         # instead of the dense [H, B] one — dedup + bucketing + the
-        # overflow fallback all happen INSIDE the jitted program (no
-        # host sync; the fallback decision is a shard-uniform
-        # lax.cond). True sizes cap per batch shape
+        # further rounds an overflowing bucket takes all happen INSIDE
+        # the jitted program (no host sync; the count of rounds is
+        # shard-uniform). True sizes cap per batch shape
         # (comm.default_exchange_cap); an int pins it — prefer
         # info.plan_exchange_cap(...).cap. Composes with dedup_cold
         # (the compact table then sees the already-unique ids).
@@ -1413,15 +1432,66 @@ class DistFeature:
             quant.quantize(store.reshape(hosts * rows_per_host, dim),
                            quant.resolve_policy(dtype_policy)))
         self._rows_per_host = rows_per_host
-        if rep is not None:
-            n = info.node_count
-            is_rep = np.zeros(n, bool)
-            is_rep[rep] = True
-            rep_rank = np.zeros(n, np.int32)
-            rep_rank[rep] = np.arange(rep_rows, dtype=np.int32)
-            bases = np.asarray(info.local_sizes, np.int32)
-            self._rep_args = (jnp.asarray(is_rep), jnp.asarray(rep_rank),
-                              jnp.asarray(bases))
+        self._rep_args = cls._rep_args_of(info)
+        return self
+
+    @staticmethod
+    def _rep_args_of(info: PartitionInfo):
+        """(is_rep [N], rep_rank [N], bases [H]) of the lookup's
+        replicated-node resolution; None without a replicated set."""
+        if info.replicate is None:
+            return None
+        rep = np.asarray(jax.device_get(info.replicate))
+        n = info.node_count
+        is_rep = np.zeros(n, bool)
+        is_rep[rep] = True
+        rep_rank = np.zeros(n, np.int32)
+        rep_rank[rep] = np.arange(rep.size, dtype=np.int32)
+        bases = np.asarray(info.local_sizes, np.int32)
+        return (jnp.asarray(is_rep), jnp.asarray(rep_rank),
+                jnp.asarray(bases))
+
+    @classmethod
+    def from_shards(cls, spmd_feat, info: PartitionInfo, comm,
+                    dedup_cold=False, exchange_cap=None,
+                    collect_metrics=False,
+                    merge_counters=False) -> "DistFeature":
+        """Build the SPMD store from shards that are ALREADY on their
+        devices: ``spmd_feat`` [H*rows_per_host, dim], row-sharded over
+        ``comm.mesh``'s ``comm.axis`` (a plain array or a
+        ``QuantizedTensor`` whose leaves are sharded alike), host ``h``'s
+        rows at ``[h*rows_per_host, (h+1)*rows_per_host)`` in the order
+        ``info.global2local`` says. No copy of the table is made, on the
+        host or anywhere: this is the constructor for a table that no
+        one host (or chip) holds — the loader writes each shard where
+        it lives and hands over the array. A ``PartitionInfo`` built
+        with its own ``global2local`` describes any such layout; one
+        that computed its book expects ``from_partition``'s (ascending
+        id order on each owner; a replicated set in every shard's tail,
+        from row ``info.local_sizes[h]``). The other arguments are
+        ``from_partition``'s."""
+        if comm.mesh is None:
+            raise ValueError("from_shards needs a comm with a mesh")
+        hosts = info.hosts
+        if comm.mesh.shape[comm.axis] != hosts:
+            raise ValueError(
+                f"the mesh has {comm.mesh.shape[comm.axis]} shards over "
+                f"{comm.axis!r}, the partition {hosts} hosts")
+        rows = quant.tier_rows(spmd_feat)
+        rep_rows = 0 if info.replicate is None \
+            else int(info.replicate.shape[0])
+        need = max(info.local_sizes) + rep_rows
+        if rows % hosts or rows // hosts < need:
+            raise ValueError(
+                f"spmd_feat has {rows} rows over {hosts} hosts; the "
+                f"partition needs {need} rows on its fullest host")
+        self = cls(None, info, comm, dedup_cold=dedup_cold,
+                   exchange_cap=exchange_cap,
+                   collect_metrics=collect_metrics,
+                   merge_counters=merge_counters)
+        self._spmd_feat = spmd_feat
+        self._rows_per_host = rows // hosts
+        self._rep_args = cls._rep_args_of(info)
         return self
 
     def _getitem_spmd(self, ids):

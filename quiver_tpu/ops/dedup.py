@@ -133,26 +133,25 @@ def unique_np(ids, valid=None) -> np.ndarray:
 
 def compact_exchange_slots(ids, cap: int, hosts: int,
                            owner=None) -> int:
-    """Analytic mirror of ``comm.dist_lookup_local``'s compact-exchange
-    branch structure for one shard's batch: USEFUL request slots
-    shipped per collective direction — ``cap * hosts`` on the compact
-    path, the full batch on overflow (unique valid count > the
-    ``min(cap*hosts, batch)`` table, or any per-owner bucket > cap),
-    or when ``cap`` can't beat the dense block. ``owner`` maps id ->
-    owning host (``PartitionInfo.global2host``); None models a
+    """Analytic mirror of ``comm.dist_lookup_local``'s compact exchange
+    for one shard's batch: request slots shipped per collective
+    direction — ``cap * hosts`` a round, and as many rounds as the
+    fullest per-owner bucket of the batch's distinct valid ids needs
+    (``ceil(fullest / cap)``; one when every bucket fits) — or the full
+    batch when ``cap`` can't beat the dense block. The program runs
+    the rounds of the neediest SHARD on all of them (the count is
+    ``pmax``'d); this mirrors one shard's own need. ``owner`` maps id
+    -> owning host (``PartitionInfo.global2host``); None models a
     balanced hash partition (``id % hosts``). The benches' exchange
-    bytes/batch figures come from this ONE copy of the branch logic;
-    the structural (jaxpr-level) pin of the same bound lives in
+    bytes/batch figures come from this ONE copy of the logic; the
+    structural (jaxpr-level) pin of the same bound lives in
     tests/_traffic.py::collective_payloads."""
     ids = np.asarray(jax.device_get(ids))
     n = int(ids.shape[0])
     if cap is None or cap >= n:
         return n
     uniq = np.unique(ids[ids >= 0])
-    if uniq.size > min(cap * hosts, n):
-        return n
     own = (uniq % hosts if owner is None
            else np.asarray(jax.device_get(owner))[uniq])
-    if np.bincount(own, minlength=hosts).max(initial=0) > cap:
-        return n
-    return cap * hosts
+    fullest = int(np.bincount(own, minlength=hosts).max(initial=0))
+    return max(1, -(-fullest // cap)) * cap * hosts

@@ -54,7 +54,9 @@ def build_dist_train_step(model, tx, sizes: Sequence[int],
     key[, indices_rows][, is_rep, rep_rank, bases]) -> (state, loss).
 
     ``spmd_feat`` [H*rows_per_host, dim] is the partition-sharded store
-    (``DistFeature.from_partition``'s layout — pass ``dist._spmd_feat``;
+    (``DistFeature.from_partition``'s layout, or shards already on
+    their devices through ``DistFeature.from_shards`` — pass
+    ``dist._spmd_feat``;
     a ``dtype_policy`` store passes its QuantizedTensor pytree whole:
     the P(axis) spec shards its leaves together and the exchange ships
     the narrow payload, dequantizing after the collective);
@@ -80,9 +82,14 @@ def build_dist_train_step(model, tx, sizes: Sequence[int],
     from the frontier cap and host count
     (``comm.default_exchange_cap``); an int pins it — prefer
     ``PartitionInfo.plan_exchange_cap(...).cap``, which sizes from the
-    partition's degree mass. Overflowing batches (unique count or any
-    per-owner bucket) fall back to the dense path via a shard-uniform
-    ``lax.cond`` — loss-identical in every case.
+    partition's degree mass. The exchange's memory is bounded by
+    ``cap``: a per-owner bucket that overflows it is served by further
+    rounds of the same [H, cap] exchange (a shard-uniform loop), never
+    by the dense blocks — loss-identical in every case.
+
+    The lookup's ops sit under the scope ``qt_exchange``
+    (``profiling.QT_EXCHANGE``), where the one-chip steps have
+    ``qt_gather``.
     """
     sizes = list(sizes)
     h_count = mesh.shape[axis]

@@ -28,9 +28,23 @@ scope = jax.named_scope
 # over the fanout axis (models/sage.py): a choice made at trace time
 # leaves its record in the program's names.
 (QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
- QT_AGGREGATE, QT_AGGREGATE_DENSE) = DEVICE_SCOPES = (
+ QT_AGGREGATE, QT_AGGREGATE_DENSE, QT_EXCHANGE) = DEVICE_SCOPES = (
     "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
-    "qt_optimizer", "qt_aggregate", "qt_aggregate_dense")
+    "qt_optimizer", "qt_aggregate", "qt_aggregate_dense", "qt_exchange")
+
+# the row-sharded store's lookup (comm.dist_lookup_local) stands where a
+# one-chip step has ``qt_gather``: ALL of it sits under ``qt_exchange``,
+# its stages beneath that, in the order they run. ``_dedup`` and
+# ``_expand`` are the compact layout's ends (the sort that finds the
+# distinct ids; each batch slot reading its row out of the response
+# block, and the -1 mask), ``_requests`` and ``_responses`` hold the two
+# ``all_to_all``s.
+(QT_EXCHANGE_ROUTE, QT_EXCHANGE_DEDUP, QT_EXCHANGE_BUCKET,
+ QT_EXCHANGE_REQUESTS, QT_EXCHANGE_GATHER, QT_EXCHANGE_RESPONSES,
+ QT_EXCHANGE_EXPAND) = EXCHANGE_STAGES = tuple(
+    QT_EXCHANGE + "_" + stage for stage in (
+        "route", "dedup", "bucket", "requests", "gather", "responses",
+        "expand"))
 
 
 def hot_path(fn):

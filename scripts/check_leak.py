@@ -22,9 +22,9 @@ buffers across 50 batches.
 Phase 4 drives 50 pipelined COMPACT-EXCHANGE dist lookups (the
 ``exchange_cap`` [H, cap] collective, virtual 8-host mesh) alongside
 donated compact-exchange dist train steps, alternating duplicate-heavy
-batches (narrow branch) with unique-heavy ones (dense ``lax.cond``
-fallback): both branches live in ONE compiled program, so the
-executable cache must not grow no matter which branch a batch takes.
+batches (one round) with unique-heavy ones (further rounds of the same
+[H, cap] exchange): all rounds live in ONE compiled program, so the
+executable cache must not grow however many rounds a batch takes.
 
 Phase 5 pins the METRICS path itself: 50 pipelined ``collect=True``
 tiered lookups + donated ``collect_metrics=True`` train steps, every
@@ -118,10 +118,10 @@ flat.
 Phase 14 pins SHARDED SERVING (qt-shard): 50 serves through a
 ``ShardedServeEngine`` over a 2-partition ``DistFeature`` store,
 alternating duplicate-heavy batches (the compact narrow exchange) with
-unique-heavy ones that overflow the per-shard unique table (the
-pmax'd dense ``lax.cond`` fallback) — both branches live in the ONE
-warmed shard_map program, so the executable cache must not grow no
-matter which branch a batch takes, and every batch's logits are
+unique-heavy ones that overflow the per-owner cap (the pmax'd count
+of further rounds) — all rounds live in the ONE warmed shard_map
+program, so the executable cache must not grow however many rounds a
+batch takes, and every batch's logits are
 bit-compared against an UNSHARDED single-store engine replaying the
 identical seed sequence (same PRNG chain): partitioning changes where
 rows live, never what the model computes.
@@ -341,10 +341,10 @@ def main():
     dinfo = qv.PartitionInfo(host=0, hosts=hosts, global2host=dg2h)
     dcomm = qv.TpuComm(rank=0, world_size=hosts, mesh=mesh, axis="host")
     # cap small enough that a unique-heavy batch overflows its
-    # per-shard unique table (dense fallback) while a duplicate-heavy
-    # one stays narrow — self-checked against the analytic branch
-    # mirror below, so the phase can't silently stop exercising one
-    # branch
+    # per-owner buckets (further rounds of the same exchange) while a
+    # duplicate-heavy one fits in one — self-checked against the
+    # analytic mirror below, so the phase can't silently stop
+    # exercising either
     cap = 8
     ddist = qv.DistFeature.from_partition(dfeat, dinfo, dcomm,
                                           exchange_cap=cap)
@@ -357,9 +357,9 @@ def main():
     size = hosts * 96
 
     def make_batch(i):
-        # even i: duplicate-heavy (16 distinct -> narrow branch);
+        # even i: duplicate-heavy (16 distinct -> one round);
         # odd i: unique-heavy (~85 distinct per 96-id shard slice,
-        # > the min(cap*H, 96)=64 unique table -> fallback)
+        # > cap*H=64 request slots -> further rounds)
         if i % 2 == 0:
             pool = rng.integers(0, dn, 16)
             ids = pool[rng.integers(0, pool.size, size)]
@@ -372,9 +372,9 @@ def main():
             yield jnp.asarray(make_batch(i))
 
     # the phase's premise, pinned analytically (one shared copy of the
-    # branch logic): every even batch fits the narrow path on every
-    # shard, every odd batch overflows on at least one shard (the
-    # pmax'd flag then sends ALL shards down the dense fallback)
+    # rounds logic): every even batch fits one round on every shard,
+    # every odd batch overflows on at least one shard (the pmax'd
+    # count then takes ALL shards through the further rounds)
     from quiver_tpu.ops.dedup import compact_exchange_slots
 
     def shard_fits(ids):
@@ -385,7 +385,7 @@ def main():
     probe_rng_state = rng.bit_generator.state
     assert all(shard_fits(make_batch(0))), "even batch must fit narrow"
     assert not all(shard_fits(make_batch(1))), \
-        "odd batch must trip the dense fallback"
+        "odd batch must overflow the cap"
     rng.bit_generator.state = probe_rng_state
 
     dsizes, dbs = [3, 2], 8
@@ -1162,7 +1162,7 @@ def main():
     # The qt-shard correctness contract, measured: the serve step over
     # the partitioned store is the SAME computation as the single-store
     # engine (only row placement differs), and its one warmed program
-    # holds both the compact narrow exchange and the dense fallback.
+    # serves a batch in one round of the compact exchange or in several.
     from quiver_tpu import metrics as qmetrics
     from quiver_tpu.serving import ServeEngine, ShardedServeEngine
 
@@ -1195,10 +1195,10 @@ def main():
 
     def sh_batch(i):
         # even i: duplicate-heavy — <=4 distinct seeds, so the whole
-        # frontier has <=40 uniques: <= the per-owner cap (40) AND the
-        # unique budget (min(cap*2, 192)=80) — the narrow branch by
-        # construction. odd i: 16 distinct seeds, whose 2-hop frontier
-        # exceeds the 80-unique budget — the dense fallback (pinned at
+        # frontier has <=40 uniques: <= the per-owner cap (40) — one
+        # round by construction. odd i: 16 distinct seeds, whose 2-hop
+        # frontier exceeds the 80 request slots of a round (cap*2), so
+        # some owner's bucket overflows — further rounds (pinned at
         # runtime via the per-batch counters below).
         if i % 2 == 0:
             pool = rng.integers(0, dn, 4)
@@ -1237,8 +1237,8 @@ def main():
             narrow += 1
         else:
             assert c[qmetrics.EXCH_FALLBACK] > 0, \
-                "phase premise: unique-heavy batch must trip the " \
-                "dense fallback"
+                "phase premise: unique-heavy batch must overflow " \
+                "the cap"
             fallback += 1
     gc.collect()
     arrays = len(jax.live_arrays())
@@ -1247,13 +1247,13 @@ def main():
           f"sharded-serve executable-cache growth: {grew}; "
           f"batches: {narrow} narrow / {fallback} fallback")
     assert narrow == 25 and fallback == 25
-    # both cond branches live in the ONE warmed shard_map executable
+    # every count of rounds runs in the ONE warmed shard_map executable
     assert grew == 0, \
         "sharded serving recompiled mid-loop (branch/shape leak)"
     assert arrays <= base_arrays + 16, \
         "device buffer leak across sharded serves"
     print("no leak detected (phase 14: 50 sharded serves alternating "
-          "narrow exchange and dense fallback, logits bit-identical "
+          "one-round and overflowing exchanges, logits bit-identical "
           "to the unsharded replay)")
 
     # ---- phase 15: fused multi-hop walk — 50 train + serve steps, ----

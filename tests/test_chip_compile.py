@@ -175,3 +175,91 @@ def test_gather_rows(graph, shape_on_chip, dim):
         assert _kernels_in(
             lambda feat, ids: gather.gather_rows(feat, ids, interpret=False),
             feat, seeds) == 1
+
+
+def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
+    """The row-sharded papers100M step (``build_dist_train_step`` at the
+    shapes of the cell ``papers100m-sage-train-dist4``: 55.5 M nodes, 808 M
+    edge slots, 13.9 M rows of 512 B a chip, 1024 seeds a chip, the cell's
+    ``exchange_cap``) compiles for the four described chips, fits one, and
+    no branch of it holds a block of the frontier's size a chip
+    (``[4, 1081344, 128]`` float32 = 2.2 GB: the dense exchange holds two)."""
+    import json
+    import os
+
+    import numpy as np
+    import optax
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from quiver_tpu.models import GraphSAGE
+    from quiver_tpu.parallel.dist import build_dist_train_step
+    from quiver_tpu.parallel.train import TrainState
+    from quiver_tpu.pyg.sage_sampler import layer_shapes
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(bench, "configs",
+                           "papers100m-sage-4of8.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "cells",
+                           "papers100m-sage-train-dist4.json")) as f:
+        cell = json.load(f)
+    chips, batch, sizes = cell["chips"], 1024, cfg["fanout"]
+    nodes, edges, dim = cfg["nodes"], cfg["edges"], cfg["feature_dim"]
+    rows = -(-nodes // chips)
+    frontier = layer_shapes(batch, sizes)[-1].n_id_cap
+    assert frontier == 1_081_344 and cell["exchange_cap"] < frontier // 4
+
+    mesh = Mesh(np.array(topo.devices[:chips]), ("host",))
+    on = lambda spec: NamedSharding(mesh, spec)
+    arr = lambda shape, dtype, spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=on(spec))
+    model = GraphSAGE(hidden_dim=cfg["hidden_dim"],
+                      out_dim=cfg["num_classes"],
+                      num_layers=cfg["num_layers"], dropout=cfg["dropout"])
+    tx = optax.adam(cfg["optimizer"]["learning_rate"])
+
+    dims = [dim] + [cfg["hidden_dim"]] * (cfg["num_layers"] - 1) \
+        + [cfg["num_classes"]]
+
+    def make_state():
+        # GraphSAGE's parameter tree, by its shapes
+        params = {"params": {f"conv{i}": {
+            "lin_root": {"kernel": jnp.zeros((a, b)), "bias": jnp.zeros((b,))},
+            "lin_nbr": {"kernel": jnp.zeros((a, b))}}
+            for i, (a, b) in enumerate(zip(dims[:-1], dims[1:]))}}
+        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = jax.tree.map(lambda a: arr(a.shape, a.dtype, P()),
+                         jax.eval_shape(make_state))
+    step = build_dist_train_step(model, tx, sizes, batch, mesh,
+                                 rows_per_host=rows,
+                                 exchange_cap=cell["exchange_cap"],
+                                 collect_metrics=True)
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_default_matmul_precision)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision",
+                      cfg["precision"]["matmul"])
+    compilation_cache.reset_cache()
+    try:
+        compiled = step.jitted_fns[-1].lower(
+            state, arr((chips * rows, dim), jnp.float32, P("host", None)),
+            arr((nodes,), jnp.int32, P()), arr((nodes,), jnp.int32, P()),
+            arr((nodes + 1,), jnp.int32, P()), arr((edges,), jnp.int32, P()),
+            arr((chips * batch,), jnp.int32, P("host")),
+            arr((chips * batch,), jnp.int32, P("host")),
+            arr(key.shape, key.dtype, P())).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was[0])
+        jax.config.update("jax_default_matmul_precision", was[1])
+        compilation_cache.reset_cache()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3e9, memory
+    assert memory.argument_size_in_bytes \
+        + memory.temp_size_in_bytes < 15e9, memory
+    text = compiled.as_text()
+    assert f"f32[{chips},{frontier},{dim}]" not in text
+    assert f"f32[{chips},{cell['exchange_cap']},{dim}]" in text

@@ -340,8 +340,8 @@ class TestCompactExchangeTrafficPin:
     traced program (no compile/run — bench fanouts trace in well under
     a second): the compact [H, cap] collectives must carry <= 1/4 the
     payload bytes of the dense [H, B] path at bench shapes, and the
-    dense shapes must never appear on the unconditional path of the
-    compact program (they live only in the lax.cond fallback)."""
+    dense shapes must never appear in the compact program at all (an
+    overflow takes further [H, cap] rounds, never the dense blocks)."""
 
     def _trace_args(self, rng, per_host, hosts=8, n=1200, dim=16):
         deg = rng.integers(1, 9, n)
@@ -405,14 +405,14 @@ class TestCompactExchangeTrafficPin:
         assert dense_bytes
         assert {s[1] for s, _, b, d in dense} == {frontier}
         assert all(d == 0 for *_x, d in dense)
-        # compact program: narrow [H, cap] collectives; the dense
-        # shapes survive ONLY inside the cond fallback, and nothing
-        # rides the unconditional path
+        # compact program: narrow [H, cap] collectives and NOTHING
+        # dense-shaped on any path: an overflowing bucket is served by
+        # further rounds of the same [H, cap] exchange, so the
+        # exchange's memory is bounded by the cap
         narrow_bytes = sum(b for s, _, b, d in compact if s[1] == cap)
-        fallback = [(s, d) for s, _, b, d in compact if s[1] == frontier]
-        assert narrow_bytes and fallback
-        assert all(d >= 1 for _, d in fallback)
-        assert all(d >= 1 for *_x, d in compact)
+        assert narrow_bytes
+        assert {s[1] for s, _, b, d in compact} == {cap}
+        assert not [s for s, _, b, d in compact if frontier in s]
         # the acceptance pin: <= 1/4 of the dense wire bytes (actual
         # ratio at these shapes is ~frontier/cap ~ 40x)
         assert narrow_bytes * 4 <= dense_bytes, (narrow_bytes,
@@ -420,20 +420,20 @@ class TestCompactExchangeTrafficPin:
 
     def test_compact_branch_conditions_analytic_mirror(self):
         """ops.dedup.compact_exchange_slots is the ONE analytic copy of
-        the branch logic the benches report from — pin its conditions:
-        duplicate-heavy fits (cap*hosts slots), unique-table overflow
-        and per-owner bucket overflow fall back to the full batch."""
+        the rounds logic the benches report from — pin it: a batch
+        whose buckets fit ships cap*hosts slots, one whose fullest
+        bucket overflows ships that again for every further round."""
         from quiver_tpu.ops.dedup import compact_exchange_slots
         hosts, cap = 8, 4
         dup_heavy = np.tile(np.arange(16, dtype=np.int32), 64)  # 16 uniq
         assert compact_exchange_slots(dup_heavy, cap, hosts) == cap * hosts
-        # unique count 64 > cap*hosts=32 -> dense
+        # 64 distinct ids, 8 an owner -> two rounds
         wide = np.arange(64, dtype=np.int32).repeat(16)
-        assert compact_exchange_slots(wide, cap, hosts) == wide.size
-        # 8 uniq ids all owned by host 0 (> cap=4) -> dense
-        skew = np.tile(np.arange(8, dtype=np.int32) * hosts, 128)
-        assert compact_exchange_slots(skew, cap, hosts) == skew.size
-        # -1 padding doesn't count against the table
+        assert compact_exchange_slots(wide, cap, hosts) == 2 * cap * hosts
+        # 9 uniq ids all owned by host 0 -> three rounds
+        skew = np.tile(np.arange(9, dtype=np.int32) * hosts, 128)
+        assert compact_exchange_slots(skew, cap, hosts) == 3 * cap * hosts
+        # -1 padding doesn't count against the buckets
         padded = np.full(1024, -1, np.int32)
         padded[:16] = np.arange(16)
         assert compact_exchange_slots(padded, cap, hosts) == cap * hosts
@@ -483,3 +483,138 @@ class TestCompactExchangeTrafficPin:
             np.asarray(compact[jnp.asarray(ids)]), want)
         np.testing.assert_array_equal(
             np.asarray(both[jnp.asarray(ids)]), want)
+
+
+class TestBoundedExchange:
+    """The compact exchange's memory is bounded by its cap: a bucket
+    that overflows is served by further rounds of the same [H, cap]
+    exchange, and the rows are the dense path's bit for bit whether the
+    buckets overflow never, once or twice, with a replicated set and
+    with a quantised store."""
+
+    N, DIM, HOSTS = 320, 8, 8
+
+    def _stores(self, store, cap):
+        n, hosts = self.N, self.HOSTS
+        rng = np.random.default_rng(3)      # the same table and book a call
+        feat = rng.standard_normal((n, self.DIM)).astype(np.float32)
+        g2h = rng.integers(0, hosts, n).astype(np.int32)
+        g2h[:hosts] = np.arange(hosts)
+        rep = np.array([5, 90, 211], np.int32) if store == "replicate" \
+            else None
+        mesh = Mesh(np.array(jax.devices()), axis_names=("host",))
+        info = qv.PartitionInfo(host=0, hosts=hosts, global2host=g2h,
+                                replicate=rep)
+        comm = qv.TpuComm(rank=0, world_size=hosts, mesh=mesh, axis="host")
+        policy = "int8" if store == "int8" else None
+        make = lambda **kw: qv.DistFeature.from_partition(
+            feat, info, comm, dtype_policy=policy, **kw)
+        return make(), make(exchange_cap=cap, collect_metrics=True)
+
+    @pytest.mark.parametrize("store", ["plain", "replicate", "int8"])
+    @pytest.mark.parametrize("rounds", [1, 2, 3])
+    def test_rounds_equal_the_dense_path_bit_for_bit(self, rng, store,
+                                                     rounds):
+        from quiver_tpu import metrics as qm
+        hosts, per_host = self.HOSTS, 64
+        ids = rng.integers(0, self.N, hosts * per_host).astype(np.int32)
+        ids[::5] = -1
+        ids[1::9] = 5                       # a hub, replicated or not
+        # the fullest per-owner bucket of distinct ids any shard fills
+        _, probe = self._stores(store, per_host - 1)
+        probe[jnp.asarray(ids)]
+        fullest = int(qm.reduce_counters(
+            probe.last_counters)[qm.EXCH_BUCKET_MAX])
+        assert fullest >= 6
+        cap = -(-fullest // rounds)
+        assert -(-fullest // cap) == rounds
+        dense, compact = self._stores(store, cap)
+        want = np.asarray(dense[jnp.asarray(ids)])
+        got = np.asarray(compact[jnp.asarray(ids)])
+        np.testing.assert_array_equal(got, want)
+        assert not want[ids < 0].any() and want[ids >= 0].any()
+        c = qm.reduce_counters(compact.last_counters)
+        assert c[qm.EXCH_CAP] == cap and c[qm.EXCH_BUCKET_MAX] == fullest
+        # every shard says whether the lookup took a round beyond the
+        # first: the pmax'd count, the same on all of them
+        assert c[qm.EXCH_FALLBACK] == (hosts if rounds > 1 else 0)
+
+    def test_no_block_of_the_frontiers_size_in_the_compact_program(self, rng):
+        """No [H, B] or [H, B, width] value anywhere in the traced
+        compact lookup, on any path."""
+        hosts, per_host, cap = self.HOSTS, 64, 4
+        _, compact = self._stores("plain", cap)
+        fn = qv.comm.build_dist_lookup_fn(
+            compact.comm.mesh, "host", compact._rows_per_host, per_host,
+            exchange_cap=cap)
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((hosts * per_host,), jnp.int32),
+            compact.info.global2host, compact.info.global2local,
+            compact._spmd_feat)
+        from _traffic import _sub_jaxprs
+
+        def shapes(j):
+            for eqn in j.eqns:
+                for v in eqn.outvars:
+                    yield tuple(v.aval.shape)
+                for sub in _sub_jaxprs(eqn):
+                    yield from shapes(sub)
+
+        seen = set(shapes(jaxpr.jaxpr))
+        assert (hosts, cap, self.DIM) in seen
+        assert (hosts, per_host, self.DIM) not in seen
+        assert (hosts, per_host) in seen    # integer bookkeeping only
+
+
+class TestFromShards:
+    def test_same_lookups_as_from_partition(self, rng):
+        """A store built from the shards where they lie answers as the
+        one ``from_partition`` staged through the host, with the
+        replicated set and the compact exchange too."""
+        n, dim, hosts = 200, 8, 8
+        feat = rng.standard_normal((n, dim)).astype(np.float32)
+        g2h = rng.integers(0, hosts, n).astype(np.int32)
+        g2h[:hosts] = np.arange(hosts)
+        mesh = Mesh(np.array(jax.devices()), axis_names=("host",))
+        comm = qv.TpuComm(rank=0, world_size=hosts, mesh=mesh, axis="host")
+        ids = rng.integers(0, n, hosts * 16).astype(np.int32)
+        ids[::6] = -1
+        for rep in (None, np.array([2, 150], np.int32)):
+            info = qv.PartitionInfo(host=0, hosts=hosts, global2host=g2h,
+                                    replicate=rep)
+            staged = qv.DistFeature.from_partition(feat, info, comm)
+            want = np.asarray(staged[jnp.asarray(ids)])
+            for cap in (None, 5):
+                given = qv.DistFeature.from_shards(
+                    staged._spmd_feat, info, comm, exchange_cap=cap)
+                assert given._spmd_feat is staged._spmd_feat
+                assert given._rows_per_host == staged._rows_per_host
+                np.testing.assert_array_equal(
+                    np.asarray(given[jnp.asarray(ids)]), want)
+
+    def test_a_book_of_the_callers_own_and_its_checks(self, rng):
+        """``PartitionInfo(global2local=...)`` takes the layout as given:
+        rows dealt to owners in any order come back by the book."""
+        n, dim, hosts = 64, 4, 8
+        rows = n // hosts
+        table = rng.standard_normal((n, dim)).astype(np.float32)
+        slot = rng.permutation(n).astype(np.int32)   # node -> slot of store
+        g2h, g2l = slot // rows, slot % rows
+        store = np.zeros((n, dim), np.float32)
+        store[slot] = table
+        mesh = Mesh(np.array(jax.devices()), axis_names=("host",))
+        comm = qv.TpuComm(rank=0, world_size=hosts, mesh=mesh, axis="host")
+        info = qv.PartitionInfo(hosts=hosts, global2host=g2h,
+                                global2local=g2l)
+        assert info.local_sizes == [rows] * hosts
+        shards = jax.device_put(store, NamedSharding(mesh, P("host")))
+        dist = qv.DistFeature.from_shards(shards, info, comm, exchange_cap=3)
+        ids = rng.integers(0, n, hosts * 8).astype(np.int32)
+        np.testing.assert_array_equal(np.asarray(dist[jnp.asarray(ids)]),
+                                      table[ids])
+        with pytest.raises(ValueError, match="rows on its fullest host"):
+            qv.DistFeature.from_shards(shards[:hosts * (rows - 1)], info,
+                                       comm)
+        with pytest.raises(ValueError, match="replicate"):
+            qv.PartitionInfo(hosts=hosts, global2host=g2h,
+                             global2local=g2l, replicate=np.array([1]))

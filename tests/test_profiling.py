@@ -9,7 +9,10 @@ The scope contracts:
    a ``qt_sample_hop<i>`` and nowhere else; the backward's ops read
    ``transpose(jvp(qt_forward))``; every builder's layers state their
    fanout, so all of ``qt_aggregate`` lies under ``qt_aggregate_dense``
-   and the forward holds no scatter.
+   and the forward holds no scatter. The dist step has the exchange
+   where the others have ``qt_gather``: every op of its lookup lies
+   under ``qt_exchange``, each stage beneath it, and nothing under
+   ``qt_gather``.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -34,7 +37,9 @@ from quiver_tpu.profiling import hot_path
 from quiver_tpu.serving import build_serve_step
 
 N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
-TRAIN_SCOPES = set(profiling.DEVICE_SCOPES)
+TRAIN_SCOPES = set(profiling.DEVICE_SCOPES) - {profiling.QT_EXCHANGE}
+DIST_SCOPES = (TRAIN_SCOPES - {profiling.QT_GATHER}) | {
+    profiling.QT_EXCHANGE} | set(profiling.EXCHANGE_STAGES)
 SERVE_SCOPES = {profiling.QT_DRAW, profiling.QT_COMPACT, profiling.QT_GATHER,
                 profiling.QT_AGGREGATE, profiling.QT_AGGREGATE_DENSE}
 
@@ -88,6 +93,17 @@ def _lower(builder: str, w):
         return step.jitted_fns[-1].lower(
             w["state"], *graph, jnp.arange(2 * BATCH, dtype=jnp.int32),
             jnp.zeros((2 * BATCH,), jnp.int32), w["key"])
+    if builder == "dist":
+        from quiver_tpu.parallel.dist import build_dist_train_step
+        mesh = Mesh(np.array(jax.devices()[:2]), ("host",))
+        step = build_dist_train_step(w["model"], w["tx"], SIZES, BATCH, mesh,
+                                     rows_per_host=N // 2, exchange_cap=24,
+                                     donate=False)
+        book = jnp.arange(N, dtype=jnp.int32)
+        return step.jitted_fns[-1].lower(
+            w["state"], w["feat"], book % 2, book // 2, w["indptr"],
+            w["indices"], jnp.arange(2 * BATCH, dtype=jnp.int32),
+            jnp.zeros((2 * BATCH,), jnp.int32), w["key"])
     fn = build_serve_step(w["model"], SIZES, BATCH)
     return fn.lower(w["state"].params, w["key"], *graph,
                     jnp.arange(BATCH, dtype=jnp.int32))
@@ -98,7 +114,8 @@ def _op_names(lowered):
 
 
 @pytest.mark.parametrize("builder,scopes", [
-    ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES)])
+    ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES),
+    ("dist", DIST_SCOPES)])
 def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
     names = _op_names(_lower(builder, world))
     for scope in scopes:
@@ -125,7 +142,31 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         assert any(re.search(r"(^|/)qt_optimizer/", n) for n in names)
 
 
-@pytest.mark.parametrize("builder", ["train", "serve"])
+def test_the_exchange_scopes_cover_the_lookup(world):
+    """Every collective and every [.., DIM] row block of the dist step's
+    lookup carries ``qt_exchange`` and one stage beneath it; the stages
+    lie nowhere else, and the step has no ``qt_gather``."""
+    text = _lower("dist", world).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert not any(profiling.QT_GATHER + "/" in n for n in names)
+    for n in names:
+        for stage in profiling.EXCHANGE_STAGES:
+            if stage in n:
+                assert re.search(
+                    r"qt_exchange\)?/(while/body/)?(closed_call/)?"
+                    + stage + r"\)?/", n), n
+    # the two all_to_alls a round, and what they carry
+    for line in text.splitlines():
+        if re.search(r" all-to-all(-start)?\(", line):
+            assert re.search(
+                r"qt_exchange\)?/.*qt_exchange_(requests|responses)/", line), line
+    # what the lookup hands the model is written under the parent scope
+    rows = [n for n in names if "qt_exchange_expand" in n]
+    assert rows and all(len(re.findall(r"qt_exchange\)?/", n)) == 1
+                        for n in rows)
+
+
+@pytest.mark.parametrize("builder", ["train", "serve", "dist"])
 def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     named = _lower(builder, world).as_text()
     monkeypatch.setattr(profiling, "scope",
@@ -138,5 +179,6 @@ def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     # and the patch did reach the builders: the names are gone from the
     # locations too (read before any compile cache has a say)
     located = lowered.as_text(debug_info=True)
-    assert not any(s in located for s in profiling.DEVICE_SCOPES)
+    assert not any(s in located for s in profiling.DEVICE_SCOPES
+                   + profiling.EXCHANGE_STAGES)
     assert "qt_sample_hop0" in located
