@@ -178,7 +178,8 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
 
     ``exchange_cap`` (None = dense) switches the collectives to the
     COMPACT deduplicated layout: the frontier's valid ids dedup once
-    (``ops.dedup.unique_within_budget``, integer work over the batch),
+    (``ops.dedup.unique_within_budget``: two sorts and a scatter over
+    the batch's slots, no search; 8 ms at 1.08 M slots on a v5e),
     the *unique* ids bucket by owner into a [H, cap] request block —
     the same shape ``build_exchange_fn`` uses — and the wire carries
     [H, cap] requests + [H, cap, width] responses instead of [H, B] /

@@ -7,7 +7,9 @@ bytes than one that reads one row per unique *node*. These helpers make
 that dedup jittable with static shapes: ``unique_within_budget`` ranks
 the distinct values of an id array into a fixed-size table (the
 hub-budget/compaction pattern of ``sample_layer_exact_wide``) plus an
-inverse map back to the original positions. Consumers gather each
+inverse map back to the original positions; both come out of one sort
+of the ids with their positions (a second sort sends the ranks back
+along its permutation: no search over the table). Consumers gather each
 unique row once and expand — with a ``lax.cond`` full-gather fallback
 when the unique count overflows the budget, so exactness never depends
 on the budget (FastSample's dedup/compaction lever, arxiv 2311.17847,
@@ -50,17 +52,23 @@ def unique_within_budget(ids: jax.Array, budget: int, valid=None,
     budget overflowed — with pure jnp ops on values this function
     already computes (no host sync, no effect on the returned arrays).
 
-    Cost note: sorting the VALUES alone and recovering ``inv`` with a
-    ``searchsorted`` over the (sorted) unique table measures ~2.3x
-    faster on the CPU backend than the (key, position)-pair sort +
-    inverse scatter it replaces — the sort is the dedup path's largest
-    non-gather cost, so this is what keeps dedup profitable even where
-    all memory tiers run at one speed. No data-dependent shapes.
+    Cost note: ``inv`` rides back along the sort's own permutation.
+    The keys are sorted WITH their positions and the ranks of the
+    sorted keys are sorted back by position: two sorts and the
+    compaction scatter, no search. On a TPU v5e at 1,081,344 slots
+    (the row-sharded papers100M step's frontier) that is 7.9 ms;
+    scattering the ranks back instead (``unique_indices``) 11.4 ms;
+    the ``searchsorted`` over ``uniq`` that stood here until PR 29 (a
+    binary search of ~log2(budget) rounds, each a gather of ``n``
+    elements, chosen on a CPU-backend timing) 168.8 ms, 45 % of that
+    step (PERF.md section 6, PR 29).
+    No data-dependent shapes.
     """
     ids = ids.astype(jnp.int32)
     n = ids.shape[0]
     key = ids if valid is None else jnp.where(valid, ids, _I32_MAX)
-    skey = jax.lax.sort(key, is_stable=False)
+    skey, perm = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
+                              num_keys=1, is_stable=False)
     first = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
     new = (first & (skey != _I32_MAX)) if valid is not None else first
     n_uniq = jnp.sum(new).astype(jnp.int32)
@@ -68,8 +76,10 @@ def unique_within_budget(ids: jax.Array, budget: int, valid=None,
     tgt = jnp.where(new & (urank < budget), urank, budget)  # budget = drop
     uniq = jnp.full((budget,), _I32_MAX, jnp.int32).at[tgt].set(
         skey, mode="drop")
-    inv = jnp.clip(jnp.searchsorted(uniq, key), 0,
-                   budget - 1).astype(jnp.int32)
+    # inv[perm[j]] = urank[j]; perm is a permutation, so sorting by it
+    # is exact (urank is -1 where nothing is counted: clipped in range)
+    _, inv = jax.lax.sort((perm, jnp.clip(urank, 0, budget - 1)),
+                          num_keys=1, is_stable=False)
     if collector is not None:
         from ..metrics import (DEDUP_CALLS, DEDUP_OVERFLOW, DEDUP_TOTAL,
                                DEDUP_UNIQUE)
