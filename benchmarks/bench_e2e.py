@@ -335,6 +335,7 @@ def main():
     from quiver_tpu.models import GraphSAGE
     from quiver_tpu.ops import (sample_multihop, reshuffle_csr, edge_row_ids,
                                 as_index_rows, as_index_rows_overlapping)
+    from quiver_tpu.parallel.frontier import SAMPLING_KNOBS, Walk
     from quiver_tpu.parallel.train import (
         TrainState, _fused_loss, cross_entropy_logits, layers_to_adjs,
         masked_feature_gather)
@@ -381,6 +382,8 @@ def main():
     method = args.method
     windowed = method in ("rotation", "window")
     stride = 128 if args.layout == "overlap" else None
+    walk = Walk.of("bench_e2e", SAMPLING_KNOBS, sizes,
+                   {"method": method, "indices_stride": stride})
     # exact: the wide-fetch path's layout view, built ONCE outside the
     # epoch (training amortizes it the same way) and passed as an
     # argument — matches bench.py's exact arm
@@ -412,9 +415,8 @@ def main():
             kb = jax.random.fold_in(key, 100 + i)
             loss, grads = jax.value_and_grad(
                 lambda prm: _fused_loss(
-                    model, cross_entropy_logits, sizes, bs, prm, feat, None,
-                    indptr, permuted, seeds, labels, kb, method, rows,
-                    stride)
+                    model, cross_entropy_logits, walk, bs, prm, feat, None,
+                    indptr, permuted, seeds, labels, kb, rows)
             )(state.params)
             updates, opt_state = tx.update(grads, state.opt_state,
                                            state.params)

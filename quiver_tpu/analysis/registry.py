@@ -6,7 +6,10 @@ builder: a small-CPU-shape instantiation of the REAL builder (same code
 path production takes — ``build_train_step``, ``build_e2e_train_step``,
 ``build_dist_train_step``, ``build_dist_lookup_fn`` /
 ``dist_lookup_local``, ``build_serve_step`` via ``ServeEngine``,
-``Feature.lookup_tiered``) plus the invariants it promises: sync-free,
+``Feature.lookup_tiered``; the step builders take the walk's knobs as the
+``**walk`` that ``parallel.frontier.Walk.of`` validates, and every one of
+them draws and gathers in ``parallel.frontier.walk_frontier``) plus the
+invariants it promises: sync-free,
 donation-honored, shard-uniform branching, traffic budgets, and the
 executable-census lattice. Shapes are tiny (tracing only — nothing
 compiles), so the full registry runs in seconds on CPU.

@@ -1,12 +1,11 @@
 from .mesh import make_mesh, replicated, row_sharded
+from .frontier import dedup_feature_gather, masked_feature_gather
 from .train import (
     TrainState,
     build_train_step,
     build_e2e_train_step,
     build_split_train_step,
     cross_entropy_logits,
-    dedup_feature_gather,
-    masked_feature_gather,
 )
 from .gspmd import build_gspmd_train_step, shard_state, state_sharding
 from .dist import build_dist_train_step

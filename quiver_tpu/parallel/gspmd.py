@@ -28,8 +28,13 @@ from typing import Callable, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .train import (TrainState, _apply_update, _check_rows, _fused_loss,
+from .frontier import Walk, documented, walk_doc
+from .train import (TrainState, _apply_update, _fused_loss,
                     cross_entropy_logits)
+
+# GSPMD cannot partition the fused Pallas kernels, and the step never took
+# a gather of its own
+_KNOBS = ("method", "indices_stride")
 
 
 def _leaf_spec(leaf, model_axis: str) -> P:
@@ -60,31 +65,28 @@ def shard_state(state: TrainState, mesh: Mesh,
     return jax.device_put(state, state_sharding(state, mesh, model_axis))
 
 
+@documented(walk_doc(_KNOBS))
 def build_gspmd_train_step(model, tx, sizes: Sequence[int], mesh: Mesh,
                            data_axis: str = "data",
                            model_axis: str = "model",
                            loss_fn: Callable = cross_entropy_logits,
-                           method: str = "exact",
-                           indices_stride: int | None = None):
+                           **walk):
     """fn(state, feat, forder, indptr, indices, seeds, labels, key[,
     indices_rows]) -> (state, loss), with ``state`` placed by
     ``shard_state`` and seeds/labels of global batch length (any
     multiple of the ``data`` axis size) sharded over ``data_axis``;
-    topology/features (and, for ``method="rotation"|"window"``, the
-    per-epoch ``indices_rows`` view) replicated. One jitted program;
-    XLA partitions the sampler over the batch shards and the matmuls
-    over the model shards."""
-    sizes = list(sizes)
+    topology/features (and the ``indices_rows`` view) replicated. One
+    jitted program; XLA partitions the sampler over the batch shards and
+    the matmuls over the model shards."""
+    walk = Walk.of("build_gspmd_train_step", _KNOBS, sizes, walk)
     cache = {}
 
     def step(state: TrainState, feat, forder, indptr, indices, seeds,
              labels, key, *rows):
         loss, grads = jax.value_and_grad(
-            lambda p: _fused_loss(model, loss_fn, sizes, seeds.shape[0],
-                                  p, feat, forder, indptr, indices, seeds,
-                                  labels, key, method,
-                                  rows[0] if rows else None,
-                                  indices_stride)
+            lambda p: _fused_loss(model, loss_fn, walk, seeds.shape[0], p,
+                                  feat, forder, indptr, indices, seeds,
+                                  labels, key, rows[0] if rows else None)
         )(state.params)
         return _apply_update(state, tx, grads), loss
 
@@ -93,7 +95,7 @@ def build_gspmd_train_step(model, tx, sizes: Sequence[int], mesh: Mesh,
 
     def sharded_step(state, feat, forder, indptr, indices, seeds, labels,
                      key, indices_rows=None):
-        _check_rows(method, indices_rows, "gspmd")
+        walk.check_rows(indices_rows)
         has_rows = indices_rows is not None   # windowed always; exact may
         fn = cache.get(has_rows)
         if fn is None:

@@ -25,9 +25,12 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import profiling
-from ..ops.sample_multihop import sample_multihop
 from ..profiling import hot_path
-from ..pyg.sage_sampler import Adj, layer_shapes
+# the gathers and ``layers_to_adjs`` live with the walk; this module is
+# where their callers have always found them
+from .frontier import (ALL_KNOBS, SAMPLING_KNOBS, Walk,  # noqa: F401
+                       dedup_feature_gather, documented, layers_to_adjs,
+                       masked_feature_gather, walk_doc, walk_frontier)
 
 
 class TrainState(NamedTuple):
@@ -41,209 +44,26 @@ def cross_entropy_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:
         logits, labels).mean()
 
 
-def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
-    """LayerSamples (sampling order) -> Adj list (outermost hop first).
-
-    Precondition: every layer is a ``compact_layer`` output over dense
-    seeds (``seeds_dense=True``'s promise: the hop-0 batch is valid-first
-    with -1 at the tail only; hops >= 1 always are). A valid seed's local
-    id is then its position, so edge slot ``e`` targets ``e // fanout``
-    or nothing, which is what each ``Adj.fanout`` set here states. A
-    caller that cannot promise it builds its ``Adj``s without a fanout."""
-    shapes = layer_shapes(batch_size, sizes)
-    adjs = []
-    for layer, shape in zip(layers, shapes):
-        adjs.append(Adj(edge_index=jnp.stack([layer.col, layer.row]),
-                        e_id=layer.e_id,
-                        size=(shape.n_id_cap, shape.num_seeds),
-                        mask=layer.col >= 0, fanout=shape.fanout))
-    return adjs[::-1]
-
-
 @hot_path
-def masked_feature_gather(feat, n_id: jax.Array,
-                          feature_order=None,
-                          collector=None) -> jax.Array:
-    """Feature rows for a -1-padded frontier, through the optional
-    hot-order indirection (reference feature.py:296-301); padded rows
-    come back zeroed so aggregation stays exact. ``feat`` may be a
-    plain array or a quantized store (``ops.quant`` — e.g.
-    ``quant.quantize(feat, "int8")``): dequantization fuses into the
-    gather, so the step reads narrow rows + sidecars and the model
-    consumes float activations unchanged. ``collector`` is accepted for
-    gather-protocol uniformity (single-tier: nothing tiered to count)."""
-    from ..ops import quant
-    with profiling.scope(profiling.QT_GATHER):
-        ids = n_id
-        if feature_order is not None:
-            ids = feature_order[jnp.clip(n_id, 0)]
-        safe = jnp.clip(ids, 0, quant.tier_rows(feat) - 1)
-        x = quant.gather_rows(feat, safe)
-        return x * (n_id >= 0).astype(x.dtype)[:, None]
-
-
-@hot_path
-def dedup_feature_gather(feat, n_id: jax.Array,
-                         feature_order=None,
-                         budget: int | None = None,
-                         collector=None) -> jax.Array:
-    """``masked_feature_gather`` reading each distinct valid id ONCE:
-    the frontier's -1 padding (the bulk of a static multi-hop cap) and
-    any repeated ids collapse into a static-``budget`` unique table,
-    the feature read is one [budget, dim] gather, and positions expand
-    from it. Falls back to the plain full gather via ``lax.cond`` when
-    the unique count overflows — identical output in every case.
-    Default budget: ``max(len(n_id)//4, 256)``."""
-    from ..ops.dedup import unique_within_budget
-    from ..ops.quant import default_cold_budget
-    n = n_id.shape[0]
-    if budget is None:
-        budget = default_cold_budget(n)
-    if budget >= n:
-        return masked_feature_gather(feat, n_id, feature_order)
-    valid = n_id >= 0
-
-    def narrow(_):
-        # uniq's int32-max fill clips to the LAST feature row — those
-        # slots hold real (unused) data, NOT zeros: inv never points a
-        # valid position at them, and invalid positions carry in-range-
-        # garbage inv that the re-mask below zeroes
-        rows_u = masked_feature_gather(feat, uniq, feature_order)
-        x = jnp.take(rows_u, inv, axis=0)
-        return x * valid.astype(x.dtype)[:, None]
-
-    # one scope over the unique table, both reads and the expansion
-    with profiling.scope(profiling.QT_GATHER):
-        uniq, inv, n_uniq = unique_within_budget(n_id, budget, valid=valid,
-                                                 collector=collector)
-        return jax.lax.cond(n_uniq > budget,
-                            lambda _: masked_feature_gather(feat, n_id,
-                                                            feature_order),
-                            narrow, None)
-
-
-def _fused_multihop_x(feat, forder, indptr, indices, seeds, sizes, key,
-                      row_cap=2048, rng=None, interpret=None,
-                      hot_rows=None, collector=None):
-    """The fused frontier walk (``ops.pallas.fused.fused_multihop``):
-    interior hops run the sampling-only fused kernel (in-kernel indptr
-    resolution), the leaf hop samples AND gathers in one kernel, and
-    the gather-free compaction chains them — frontier ids live only in
-    VMEM/SMEM at every hop, so the step's modeled
-    ``gather_index_bytes`` is zero across the whole ladder. The layer
-    COOs and the ``[cap, dim]`` frontier block come back bit-identical
-    to ``masked_feature_gather(feat, n_id, forder)`` over the same
-    picks (valid slots).
-
-    The sampling PRNG is the KERNEL's stream (hop ``i`` seeded from
-    ``fold_in(key, i)``), not ``jax.random`` — losses are
-    bit-comparable with the split Pallas oracle
-    (``ops.pallas.fused.fused_multihop_reference``), not with the
-    ``sample_multihop`` path. A 1-hop ``sizes`` reduces exactly to the
-    qt-fuse single-hop behavior. ``hot_rows`` zeroes rows whose
-    (``forder``-translated) storage row falls outside the hot tier;
-    callers with a cold tier overlay exactly those slots afterwards
-    (the serve step's tiered fixup)."""
-    from ..ops.pallas.fused import fused_multihop, pad_indices
-    n_id, layers, x = fused_multihop(
-        indptr, pad_indices(indices, row_cap), seeds, feat, list(sizes),
-        key, row_cap=row_cap, rng=rng, interpret=interpret,
-        feature_order=forder, hot_rows=hot_rows)
-    if collector is not None:
-        from ..metrics import FRONTIER_CAP, FRONTIER_VALID
-        collector.add(FRONTIER_VALID, jnp.sum(n_id >= 0))
-        collector.add(FRONTIER_CAP, int(n_id.shape[0]))
-    return x, layers
-
-
-def _fused_knobs(enabled, row_cap, rng, interpret, sizes, method,
-                 dedup_gather=None, indices_stride=None, hub_frac=None):
-    """Validate + pack the ``fused_hot_hop`` builder knobs (shared by
-    the train and serve builders). The fused walk covers any
-    exact-method fanout ladder (qt-fuse-deep) and does its own
-    in-kernel gather, so the knob composes with nothing that reshapes
-    sampling or the gather."""
-    if not enabled:
-        return None
-    if not sizes:
-        raise ValueError("fused_hot_hop needs at least one hop in sizes")
-    if method != "exact":
-        raise ValueError(
-            f"fused_hot_hop requires method='exact', got {method!r}")
-    if dedup_gather is not None:
-        raise ValueError(
-            "fused_hot_hop gathers in-kernel (one DMA per frontier "
-            "slot); dedup_gather does not compose with it")
-    if indices_stride is not None or hub_frac is not None:
-        raise ValueError(
-            "fused_hot_hop takes neither indices_stride nor hub_frac "
-            "(no wide-exact/rotation layout views in the fused kernel)")
-    return {"row_cap": int(row_cap), "rng": rng, "interpret": interpret}
-
-
-@hot_path
-def _fused_loss(model, loss_fn, sizes, batch_size, params, feat, forder,
-                indptr, indices, seeds, labels, key, method="exact",
-                indices_rows=None, indices_stride=None, gather=None,
-                hub_frac=None, collector=None, fused=None):
-    """``gather(feat, n_id, forder, collector=None)`` defaults to the
-    local ``masked_feature_gather``; the multi-host fused step
-    substitutes the partitioned all_to_all lookup. Everything else
-    (sampling keys, the dropout fold constant, the logits slice) is THE
-    shared definition — dist/DP loss parity depends on there being
-    exactly one copy. ``collector`` (a ``metrics.Collector``) opts into
-    device-counter telemetry: sampling and the gather record counts
-    they already compute; the loss itself is untouched (bit-identical
-    with collection on or off).
-
-    Batch contract: ``seeds`` must be distinct valid ids with -1 padding
-    at the TAIL only. That was always required here — ``labels`` are
-    indexed by batch position while interior holes would shift seeds to
-    rank-based output rows, silently misaligning the loss — so hop 0
-    also takes the cheaper dense-seed compaction path.
-
-    ``fused`` (the packed ``fused_hot_hop`` builder knobs, see
-    ``_fused_knobs``) swaps the sample->gather pair for the fused
-    Pallas walk (``_fused_multihop_x``) — frontier ids stay on chip at
-    EVERY hop; everything from the frontier block on is unchanged."""
-    if fused is not None:
-        if indices_rows is not None:
-            raise TypeError(
-                "fused_hot_hop does not take indices_rows (the fused "
-                "walk does its own in-kernel CSR reads every hop)")
-        x, layers = _fused_multihop_x(feat, forder, indptr, indices,
-                                      seeds, sizes, key,
-                                      collector=collector, **fused)
-    else:
-        n_id, layers = sample_multihop(
-            indptr, indices, seeds, sizes, key, method=method,
-            indices_rows=indices_rows, indices_stride=indices_stride,
-            seeds_dense=True, hub_frac=hub_frac, collector=collector)
-        x = (gather or masked_feature_gather)(feat, n_id, forder,
-                                              collector=collector)
-    adjs = layers_to_adjs(layers, batch_size, sizes)
+def _fused_loss(model, loss_fn, walk: Walk, batch_size, params, feat, forder,
+                indptr, indices, seeds, labels, key, indices_rows=None,
+                collector=None):
+    """The loss of one batch: the walk (``walk_frontier``: sample, then
+    the walk's gather, or the fused kernel), the model's forward under
+    ``qt_forward``, ``loss_fn`` under ``qt_loss``. The dropout fold
+    constant and the logits slice are THE shared definition — dist/DP
+    loss parity depends on there being exactly one copy. ``collector``
+    (a ``metrics.Collector``) opts into device-counter telemetry:
+    sampling and the gather record counts they already compute; the loss
+    itself is untouched (bit-identical with collection on or off)."""
+    _, x, layers = walk_frontier(walk, feat, forder, indptr, indices, seeds,
+                                 key, indices_rows, collector)
+    adjs = layers_to_adjs(layers, batch_size, walk.sizes)
     with profiling.scope(profiling.QT_FORWARD):
         logits = model.apply(params, x, adjs, train=True,
                              rngs={"dropout": jax.random.fold_in(key, 1000)})
     with profiling.scope(profiling.QT_LOSS):
         return loss_fn(logits[:batch_size], labels)
-
-
-def _check_rows(method: str, indices_rows, kind: str) -> bool:
-    """Shared indices_rows contract for the step builders: rotation and
-    window REQUIRE the per-epoch shuffled view (as_index_rows /
-    as_index_rows_overlapping; refresh via permute_csr). exact
-    OPTIONALLY takes a layout view of the UN-shuffled indices — that
-    switches the scattered draw to the wide-fetch exact path
-    (``sample_layer_exact_wide``; same i.i.d. statistics, fewer
-    scattered loads). Returns whether the method is windowed."""
-    windowed = method in ("rotation", "window")
-    if windowed and indices_rows is None:
-        raise TypeError(
-            f"{method} {kind} step requires indices_rows (the shuffled "
-            "as_index_rows/as_index_rows_overlapping view; refresh per "
-            "epoch via permute_csr)")
-    return windowed
 
 
 def _apply_update(state, tx, grads) -> TrainState:
@@ -313,16 +133,6 @@ _DONATED_DOC = """
     per-step copy)."""
 
 
-def _dedup_gather_fn(dedup_gather):
-    """``dedup_gather`` knob -> the gather callable ``_fused_loss``
-    takes (None keeps the plain masked gather)."""
-    if dedup_gather is None:
-        return None
-    budget = None if dedup_gather is True else int(dedup_gather)
-    return lambda feat, n_id, forder, collector=None: dedup_feature_gather(
-        feat, n_id, forder, budget, collector=collector)
-
-
 def _metered_loss_fn(collect: bool, loss_with_collector):
     """Shared value_and_grad plumbing for the ``collect_metrics`` knob:
     ``loss_with_collector(params, collector_or_None)`` is the loss;
@@ -367,74 +177,11 @@ _COLLECT_DOC = """
     merge on or off."""
 
 
-def build_train_step(model, tx, sizes: Sequence[int], batch_size: int,
-                     loss_fn: Callable = cross_entropy_logits,
-                     method: str = "exact",
-                     indices_stride: int | None = None,
-                     hub_frac: float | None = None,
-                     donate: bool = True,
-                     dedup_gather=None,
-                     collect_metrics: bool = False,
-                     fused_hot_hop: bool = False,
-                     fused_row_cap: int = 2048,
-                     fused_rng: str | None = None,
-                     fused_interpret: bool | None = None):
-    """Single-chip fused step:
-    fn(state, feat, forder, indptr, indices, seeds, labels, key[,
-    indices_rows]). With ``method="rotation"`` pass the shuffled
-    ``as_index_rows`` view as ``indices_rows`` (refresh per epoch with
-    ``reshuffle_csr`` — exact sort or cheap butterfly) — or, with
-    ``indices_stride=128``, the
-    ``as_index_rows_overlapping`` view (one row gather per seed, 2x
-    index memory). With ``method="exact"`` + an un-shuffled layout view
-    as ``indices_rows``, pass ``hub_frac`` (the cached
-    ``CSRTopo.exact_bucket_meta().frac``) so the wide-exact hub budget
-    is sized from the graph's degree-bucket split. ``dedup_gather``
-    (True or an int unique budget) swaps the frontier feature gather
-    for ``dedup_feature_gather`` — one read per distinct node instead
-    of per frontier slot. ``feat`` may be a quantized store
-    (``ops.quant.quantize(feat, "int8"|"bf16")``): dequant fuses into
-    the gather and the model consumes float activations unchanged.
 
-    ``fused_hot_hop=True`` (any ``sizes`` ladder, ``method="exact"``
-    only) swaps the sample->gather pair for the fused Pallas walk
-    (``ops.pallas.fused.fused_multihop``): interior hops run the
-    sampling-only fused kernel, the leaf hop fuses reservoir sampling
-    with the per-pick feature-row DMA (int8 dequant applied
-    in-register), and frontier ids never materialize in HBM at ANY hop
-    — the step's modeled ``gather_index_bytes`` is zero across the
-    whole ladder. ``fused_row_cap`` bounds the in-VMEM CSR window per
-    seed (degrees beyond it are truncated — the sample kernel's
-    contract); ``fused_rng``/``fused_interpret`` default to the
-    backend-appropriate choices ("tpu" PRNG on TPU, portable "hash" +
-    interpret mode elsewhere). The fused step's sampling stream is the
-    kernel PRNG (hop ``i`` seeded from ``fold_in(key, i)``), so losses
-    are not bit-comparable with the split step — only with the split
-    Pallas oracle (``ops.pallas.fused.fused_multihop_reference``)."""
-    sizes = list(sizes)
-    gather = _dedup_gather_fn(dedup_gather)
-    fused = _fused_knobs(fused_hot_hop, fused_row_cap, fused_rng,
-                         fused_interpret, sizes, method,
-                         dedup_gather=dedup_gather,
-                         indices_stride=indices_stride,
-                         hub_frac=hub_frac)
 
-    def step(state: TrainState, feat, forder, indptr, indices, seeds,
-             labels, key, indices_rows=None):
-        loss_of, unpack = _metered_loss_fn(
-            collect_metrics,
-            lambda p, col: _fused_loss(model, loss_fn, sizes, batch_size,
-                                       p, feat, forder, indptr, indices,
-                                       seeds, labels, key, method,
-                                       indices_rows, indices_stride,
-                                       gather=gather, hub_frac=hub_frac,
-                                       collector=col, fused=fused))
-        loss, counters, grads = unpack(loss_of(state.params))
-        new_state = _apply_update(state, tx, grads)
-        if collect_metrics:
-            return new_state, loss, counters
-        return new_state, loss
-
+def _jit_guarded(who: str, step, donate: bool):
+    """``step`` jitted, its state donated behind the donation guard
+    (``_check_donatable`` on the first call); carries ``.jitted_fns``."""
     jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
     jitted.jitted_fns = (jitted,)
     if not donate:
@@ -442,132 +189,151 @@ def build_train_step(model, tx, sizes: Sequence[int], batch_size: int,
     checked = set()
 
     def guarded(state, *args, **kwargs):
-        _check_donatable("build_train_step", jitted, checked, state,
-                         *args, **kwargs)
+        _check_donatable(who, jitted, checked, state, *args, **kwargs)
         return jitted(state, *args, **kwargs)
 
     guarded.jitted_fns = (jitted,)
     return guarded
 
 
-def build_e2e_train_step(model, tx, sizes: Sequence[int],
-                         per_device_batch: int, mesh: Mesh,
-                         axis: str = "data",
-                         loss_fn: Callable = cross_entropy_logits,
-                         method: str = "exact",
-                         indices_stride: int | None = None,
-                         hub_frac: float | None = None,
-                         donate: bool = True,
-                         dedup_gather=None,
-                         collect_metrics: bool = False,
-                         merge_counters: bool = False,
-                         fused_hot_hop: bool = False,
-                         fused_row_cap: int = 2048,
-                         fused_rng: str | None = None,
-                         fused_interpret: bool | None = None):
-    """Data-parallel fused step over ``mesh[axis]``:
-    fn(state, feat, forder, indptr, indices, seeds, labels, key[,
-    indices_rows]) with seeds/labels [n_dev * per_device_batch] sharded
-    over ``axis``; state/feat/topology (and the shuffled rows view when
-    ``method="rotation"``) replicated; grads pmean over ``axis``.
-    ``indices_stride=128`` switches ``indices_rows`` to the
-    ``as_index_rows_overlapping`` layout (one row gather per seed).
-    ``hub_frac`` (cached ``CSRTopo.exact_bucket_meta().frac``) sizes the
-    wide-exact hub budget when exact mode gets an ``indices_rows``.
-    ``dedup_gather`` (True or an int unique budget) swaps each shard's
-    frontier feature gather for ``dedup_feature_gather``. ``feat`` may
-    be a quantized store (``ops.quant``) — the P() spec broadcasts
-    over its leaves as a pytree prefix.
+def _sharded_step(who: str, walk: Walk, loss, tx, mesh: Mesh, axis: str,
+                  in_specs: Sequence, tail_specs: Sequence, donate: bool,
+                  collect_metrics: bool, merge_counters: bool):
+    """The ``shard_map`` shell of the data-parallel and the row-sharded
+    train step. The jitted program's operands are ``(state, *operands,
+    key[, indices_rows], *tail)``: ``in_specs`` places ``operands``,
+    ``tail_specs`` the optional ``tail``; state, key and the rows view are
+    replicated. Each shard folds its index into ``key``, takes
+    ``loss(params, collector, key, indices_rows, *operands, *tail)``
+    through ``_metered_loss_fn``, and gradients and loss are ``pmean``ed
+    over ``axis``. Counters leave per shard (``[1, N]`` here, ``[shards,
+    N]`` outside) or, with ``merge_counters``, folded on device into ONE
+    global ``[N]`` vector (``metrics.pmerge_counters``: psum/pmax slot
+    semantics), which every host can read.
 
-    ``fused_hot_hop=True`` swaps each shard's sample->gather pair for
-    the fused Pallas walk (``ops.pallas.fused.fused_multihop``) with
-    the same contract as ``build_train_step``: exact method, any
-    ``sizes`` ladder, zero modeled ``gather_index_bytes`` per shard;
-    the per-shard key fold keeps shards on distinct kernel streams."""
-    sizes = list(sizes)
-    gather = _dedup_gather_fn(dedup_gather)
-    fused = _fused_knobs(fused_hot_hop, fused_row_cap, fused_rng,
-                         fused_interpret, sizes, method,
-                         dedup_gather=dedup_gather,
-                         indices_stride=indices_stride,
-                         hub_frac=hub_frac)
+    Returns ``run(state, operands, indices_rows, tail=())``, with
+    ``.jitted_fns = (with rows, without)``: a shard_map's arity is fixed
+    when it is built and ``"exact"`` may or may not bring a rows view, so
+    both are built; jit compiles lazily, the unused one costs nothing."""
     if merge_counters and not collect_metrics:
         raise ValueError("merge_counters=True requires "
                          "collect_metrics=True")
+    n = len(in_specs)
 
-    def per_shard(state: TrainState, feat, forder, indptr, indices, seeds,
-                  labels, key, indices_rows=None):
-        key = jax.random.fold_in(key, jax.lax.axis_index(axis))
-        loss_of, unpack = _metered_loss_fn(
-            collect_metrics,
-            lambda p, col: _fused_loss(model, loss_fn, sizes,
-                                       per_device_batch, p, feat, forder,
-                                       indptr, indices, seeds, labels, key,
-                                       method, indices_rows, indices_stride,
-                                       gather=gather, hub_frac=hub_frac,
-                                       collector=col, fused=fused))
-        loss, counters, grads = unpack(loss_of(state.params))
-        new_state, loss = _pmean_update(state, tx, grads, loss, axis)
-        if collect_metrics:
+    def per_shard_of(has_rows):
+        def per_shard(state: TrainState, *ops):
+            key = jax.random.fold_in(ops[n], jax.lax.axis_index(axis))
+            rows = ops[n + 1] if has_rows else None
+            rest = ops[:n] + ops[n + 1 + has_rows:]
+            loss_of, unpack = _metered_loss_fn(
+                collect_metrics,
+                lambda p, col: loss(p, col, key, rows, *rest))
+            value, counters, grads = unpack(loss_of(state.params))
+            new_state, value = _pmean_update(state, tx, grads, value, axis)
+            if not collect_metrics:
+                return new_state, value
             if merge_counters:
-                # device-side cross-shard fold (psum/pmax slot
-                # semantics): the step emits ONE global [N] vector
                 from ..metrics import pmerge_counters
-                return new_state, loss, pmerge_counters(counters, axis)
-            # per-shard counters, [1, N] here -> [n_dev, N] outside
-            return new_state, loss, counters[None]
-        return new_state, loss
+                return new_state, value, pmerge_counters(counters, axis)
+            return new_state, value, counters[None]
+        return per_shard
 
-    specs = [P(), P(), P(), P(), P(), P(axis), P(axis), P()]
     if collect_metrics:
         outs = (P(), P(), P() if merge_counters else P(axis))
     else:
         outs = (P(), P())
-    # shard_map arity is fixed at build time, but exact may or may not
-    # bring the (optional) wide-path rows view — build both arities; jit
-    # compiles lazily so the unused one costs nothing
-    with_rows = shard_map(
-        per_shard, mesh=mesh,
-        in_specs=tuple(specs + [P()]),   # indices_rows, replicated
-        out_specs=outs,
-        check_vma=False)
-    without_rows = shard_map(
-        per_shard, mesh=mesh,
-        in_specs=tuple(specs),
-        out_specs=outs,
-        check_vma=False)
-    dn = (0,) if donate else ()
-    jitted_rows = jax.jit(with_rows, donate_argnums=dn)
-    jitted = jax.jit(without_rows, donate_argnums=dn)
+    jitted = {
+        has_rows: jax.jit(shard_map(
+            per_shard_of(has_rows), mesh=mesh,
+            in_specs=(P(), *in_specs, *[P()] * (1 + has_rows), *tail_specs),
+            out_specs=outs, check_vma=False),
+            donate_argnums=(0,) if donate else ())
+        for has_rows in (True, False)}
     checked = set()
 
-    # validate the optional arg up front so a mismatch is a clear
-    # TypeError, not an opaque shard_map/jit arity failure
+    def run(state, operands, indices_rows, tail=()):
+        # asked up front, so that a mismatch is a clear TypeError and
+        # not an opaque shard_map/jit arity failure
+        walk.check_rows(indices_rows)
+        fn = jitted[indices_rows is not None]
+        if indices_rows is not None:
+            operands += (indices_rows,)
+        if donate:
+            _check_donatable(who, fn, checked, state, *operands, *tail)
+        return fn(state, *operands, *tail)
+
+    run.jitted_fns = tuple(jitted.values())
+    return run
+
+
+@documented(walk_doc(ALL_KNOBS), _DONATED_DOC, _COLLECT_DOC)
+def build_train_step(model, tx, sizes: Sequence[int], batch_size: int,
+                     loss_fn: Callable = cross_entropy_logits,
+                     donate: bool = True, collect_metrics: bool = False,
+                     **walk):
+    """Single-chip fused step:
+    fn(state, feat, forder, indptr, indices, seeds, labels, key[,
+    indices_rows]) -> (state, loss). ``feat`` may be a quantized store
+    (``ops.quant.quantize(feat, "int8"|"bf16")``): dequant fuses into
+    the gather and the model consumes float activations unchanged."""
+    walk = Walk.of("build_train_step", ALL_KNOBS, sizes, walk)
+
+    def step(state: TrainState, feat, forder, indptr, indices, seeds,
+             labels, key, indices_rows=None):
+        loss_of, unpack = _metered_loss_fn(
+            collect_metrics,
+            lambda p, col: _fused_loss(model, loss_fn, walk, batch_size, p,
+                                       feat, forder, indptr, indices, seeds,
+                                       labels, key, indices_rows, col))
+        loss, counters, grads = unpack(loss_of(state.params))
+        new_state = _apply_update(state, tx, grads)
+        if collect_metrics:
+            return new_state, loss, counters
+        return new_state, loss
+
+    return _jit_guarded("build_train_step", step, donate)
+
+
+@documented(walk_doc(ALL_KNOBS), _DONATED_DOC, _COLLECT_DOC)
+def build_e2e_train_step(model, tx, sizes: Sequence[int],
+                         per_device_batch: int, mesh: Mesh,
+                         axis: str = "data",
+                         loss_fn: Callable = cross_entropy_logits,
+                         donate: bool = True, collect_metrics: bool = False,
+                         merge_counters: bool = False, **walk):
+    """Data-parallel fused step over ``mesh[axis]``:
+    fn(state, feat, forder, indptr, indices, seeds, labels, key[,
+    indices_rows]) with seeds/labels [n_dev * per_device_batch] sharded
+    over ``axis``; state/feat/topology (and the rows view) replicated;
+    grads pmean over ``axis``; the per-shard key fold keeps shards on
+    distinct streams. ``feat`` may be a quantized store (``ops.quant``) —
+    the P() spec broadcasts over its leaves as a pytree prefix."""
+    who = "build_e2e_train_step"
+    walk = Walk.of(who, ALL_KNOBS, sizes, walk)
+
+    def loss(p, col, key, rows, feat, forder, indptr, indices, seeds,
+             labels):
+        return _fused_loss(model, loss_fn, walk, per_device_batch, p, feat,
+                           forder, indptr, indices, seeds, labels, key,
+                           rows, col)
+
+    run = _sharded_step(who, walk, loss, tx, mesh, axis,
+                        (P(), P(), P(), P(), P(axis), P(axis)), (), donate,
+                        collect_metrics, merge_counters)
+
     def step(state, feat, forder, indptr, indices, seeds, labels, key,
              indices_rows=None):
-        _check_rows(method, indices_rows, "e2e")
-        if indices_rows is not None:
-            args = (feat, forder, indptr, indices, seeds, labels, key,
-                    indices_rows)
-            fn = jitted_rows
-        else:
-            args = (feat, forder, indptr, indices, seeds, labels, key)
-            fn = jitted
-        if donate:
-            _check_donatable("build_e2e_train_step", fn, checked, state,
-                             *args)
-        return fn(state, *args)
+        return run(state, (feat, forder, indptr, indices, seeds, labels,
+                           key), indices_rows)
 
-    step.jitted_fns = (jitted_rows, jitted)
+    step.jitted_fns = run.jitted_fns
     return step
 
 
+@documented(walk_doc(SAMPLING_KNOBS), _DONATED_DOC)
 def build_split_train_step(model, tx, sizes: Sequence[int], batch_size: int,
                            loss_fn: Callable = cross_entropy_logits,
-                           method: str = "exact",
-                           indices_stride: int | None = None,
-                           hub_frac: float | None = None,
-                           donate: bool = True):
+                           donate: bool = True, **walk):
     """Two-phase step for tiered feature stores (the reference's own
     architecture: sampling and feature collection run as separate stages
     around the model, examples/pyg/reddit_quiver.py:116-122):
@@ -581,20 +347,18 @@ def build_split_train_step(model, tx, sizes: Sequence[int], batch_size: int,
     node; pair with ``Feature.prefetch`` / ``quiver_tpu.pipeline`` so
     batch i+1's staging overlaps step i), then run the fused
     forward/backward/update. ``sample_fn``'s inputs (topology, seeds)
-    are reused across steps, so nothing there is donatable.
-    """
-    sizes = list(sizes)
+    are reused across steps, so nothing there is donatable. No
+    ``collect_metrics``: its stages are driven from the host, where
+    ``StepStats`` times them directly."""
+    # the rows are the caller's to fetch: the walk gathers nothing
+    walk = Walk.of("build_split_train_step", SAMPLING_KNOBS, sizes, walk,
+                   gather=lambda feat, n_id, forder, collector=None: None)
 
     @jax.jit
     def sample_fn(indptr, indices, seeds, key, indices_rows=None):
-        # same batch contract as _fused_loss: distinct valid ids,
-        # -1 padding at the tail only (labels are position-indexed)
-        n_id, layers = sample_multihop(
-            indptr, indices, seeds, sizes, key, method=method,
-            indices_rows=indices_rows,
-            indices_stride=indices_stride if indices_rows is not None
-            else None, seeds_dense=True, hub_frac=hub_frac)
-        return n_id, layers_to_adjs(layers, batch_size, sizes)
+        n_id, _, layers = walk_frontier(walk, None, None, indptr, indices,
+                                        seeds, key, indices_rows)
+        return n_id, layers_to_adjs(layers, batch_size, walk.sizes)
 
     def step_fn_raw(state: TrainState, x, adjs, labels, key):
         def loss_of(p):
@@ -607,33 +371,10 @@ def build_split_train_step(model, tx, sizes: Sequence[int], batch_size: int,
         loss, grads = jax.value_and_grad(loss_of)(state.params)
         return _apply_update(state, tx, grads), loss
 
-    jitted = jax.jit(step_fn_raw, donate_argnums=(0,) if donate else ())
-    if not donate:
-        return sample_fn, jitted
-    checked = set()
-
-    def step_fn(state, *args, **kwargs):
-        _check_donatable("build_split_train_step", jitted, checked, state,
-                         *args, **kwargs)
-        return jitted(state, *args, **kwargs)
-
-    return sample_fn, step_fn
+    return sample_fn, _jit_guarded("build_split_train_step", step_fn_raw,
+                                   donate)
 
 
 def init_state(model, tx, example_x, example_adjs, key) -> TrainState:
     params = model.init(key, example_x, example_adjs)
     return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
-
-
-# the donation contract is identical across the step builders — stamp
-# it onto each docstring once instead of drifting three copies
-# (guarded: under python -OO docstrings are None)
-for _b in (build_train_step, build_e2e_train_step, build_split_train_step):
-    if _b.__doc__:
-        _b.__doc__ += _DONATED_DOC
-# likewise for the collect_metrics contract (split step: no knob — its
-# stages are driven from the host, where StepStats times them directly)
-for _b in (build_train_step, build_e2e_train_step):
-    if _b.__doc__:
-        _b.__doc__ += _COLLECT_DOC
-del _b

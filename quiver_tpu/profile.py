@@ -245,7 +245,7 @@ class StageProfiler:
         hops)."""
         from .analysis.registry import _fixture, build_entry_specs
         from .ops.sample_multihop import sample_multihop
-        from .parallel.train import masked_feature_gather
+        from .parallel.frontier import masked_feature_gather
         fx = _fixture()
         sizes = fx.sizes
 

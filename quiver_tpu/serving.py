@@ -103,9 +103,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import faults, profiling, tracing
-from .parallel.train import (_fused_knobs, _fused_multihop_x,
-                             dedup_feature_gather, layers_to_adjs,
-                             masked_feature_gather)
+from .parallel.frontier import (Walk, documented, layers_to_adjs, walk_doc,
+                                walk_frontier)
 from .profiling import hot_path
 # the typed request-failure vocabulary is shared with the RPC plane
 # (quiver_tpu.rpc defines it so the jax-free client can import it):
@@ -239,16 +238,16 @@ class _TenantState:
 # -- the jitted serve step ---------------------------------------------------
 
 
+# the serve steps have no ``indices_rows`` operand, so none of the knobs
+# that read one; the sharded step's gather is the exchange
+_SERVE_KNOBS = ("method", "dedup_gather", "fused_hot_hop", "fused_row_cap")
+_SHARDED_KNOBS = ("method", "fused_hot_hop", "fused_row_cap")
+
+
+@documented(walk_doc(_SERVE_KNOBS))
 def build_serve_step(model, sizes: Sequence[int], batch_cap: int,
-                     method: str = "exact",
-                     dedup_gather=None,
                      gather: Optional[Callable] = None,
-                     collect_metrics: bool = False,
-                     fused_hot_hop: bool = False,
-                     fused_row_cap: int = 2048,
-                     fused_rng: Optional[str] = None,
-                     fused_interpret: Optional[bool] = None,
-                     fused_hot_rows: Optional[int] = None):
+                     collect_metrics: bool = False, **walk):
     """Pre-compiled point-inference step for one fanout config.
 
     Returns ``step(params, key, feat, forder, indptr, indices, seeds)``
@@ -263,73 +262,24 @@ def build_serve_step(model, sizes: Sequence[int], batch_cap: int,
 
     ``feat``/``forder``/topology are arguments, not closures (nothing
     large bakes into the executable); ``feat`` may be a quantized store.
-    ``dedup_gather`` (True or an int unique budget) swaps the frontier
-    gather for ``dedup_feature_gather``; ``gather`` overrides the whole
-    gather callable (``gather(feat, n_id, forder, collector=None)`` —
-    the ``ServeEngine`` uses this to splice a ``Feature`` store's fused
-    tiered lookup into the program). The returned step exposes
-    ``.jitted_fns`` (for ``StepStats.watch_compiles``) and ``.raw``
-    (the traceable body, for jaxpr pins like ``host_sync_eqns``).
-
-    ``fused_hot_hop=True`` (any ``sizes`` ladder, ``method="exact"``)
-    swaps the sample+gather pair for the fused Pallas walk
-    (``ops.pallas.fused.fused_multihop``): every hop samples in-kernel
-    (interior hops run the sampling-only kernel, the leaf hop also
-    gathers the dequantized hot-tier rows), frontier ids never touch
-    HBM. ``fused_hot_rows`` scopes the in-kernel gather to the hot tier;
-    when a ``gather`` override is also given (the ``ServeEngine``'s
-    tiered ``Feature`` splice, where ``feat`` is the ``(device_part,
-    host)`` pytree and the kernel reads ``feat[0]``), the slots the
-    kernel masked as cold are overlaid from the store's unchanged
-    tiered lookup afterwards — the fused kernel handles the hot tier
-    only. ``fused_row_cap``/``fused_rng``/``fused_interpret`` are the
-    kernel's knobs (see ``parallel.train.build_train_step``)."""
-    sizes = list(sizes)
-    if gather is None and dedup_gather is not None:
-        budget = None if dedup_gather is True else int(dedup_gather)
-        gather = (lambda feat, n_id, forder, collector=None:
-                  dedup_feature_gather(feat, n_id, forder, budget,
-                                       collector=collector))
-    fused = _fused_knobs(fused_hot_hop, fused_row_cap, fused_rng,
-                         fused_interpret, sizes, method,
-                         dedup_gather=dedup_gather)
-    if fused is not None and gather is not None and fused_hot_rows is None:
-        raise ValueError(
-            "fused_hot_hop over a spliced tiered gather needs "
-            "fused_hot_rows (the hot-tier row count) to route cold "
-            "picks back through the tiered lookup")
+    ``gather`` overrides the whole gather callable (the protocol of
+    ``parallel.frontier``; it wins over ``dedup_gather``) — the
+    ``ServeEngine`` uses this to splice a ``Feature`` store's fused
+    tiered lookup into the program, with ``feat`` the ``(device_part,
+    host)`` pair; the fused walk over it needs ``gather.hot_rows``. The
+    returned step exposes ``.jitted_fns`` (for
+    ``StepStats.watch_compiles``) and ``.raw`` (the traceable body, for
+    jaxpr pins like ``host_sync_eqns``)."""
+    walk = Walk.of("build_serve_step", _SERVE_KNOBS, sizes, walk,
+                   gather=gather)
 
     @hot_path
     def forward(params, key, feat, forder, indptr, indices, seeds,
                 collector=None):
         key, sub = jax.random.split(key)
-        if fused is not None:
-            hot = feat[0] if gather is not None else feat
-            x, layers = _fused_multihop_x(
-                hot, forder, indptr, indices, seeds, sizes, sub,
-                hot_rows=fused_hot_rows, collector=collector, **fused)
-            if gather is not None:
-                # cold fixup: the kernel zeroed every frontier slot
-                # whose translated row falls outside the hot tier;
-                # those slots — and ONLY those — come from the store's
-                # unchanged tiered lookup (hot slots masked to -1 so
-                # the store reads nothing for them). The FINAL layer's
-                # n_id is the whole walk's frontier.
-                n_id = layers[-1].n_id
-                t = forder[jnp.clip(n_id, 0)] if forder is not None \
-                    else jnp.clip(n_id, 0)
-                is_cold = (n_id >= 0) & (t >= fused_hot_rows)
-                with profiling.scope(profiling.QT_GATHER):
-                    x_cold = gather(feat, jnp.where(is_cold, n_id, -1),
-                                    forder, collector=collector)
-                x = jnp.where(is_cold[:, None], x_cold, x)
-        else:
-            n_id, layers = sample_multihop_serving(
-                indptr, indices, seeds, sizes, sub, method=method,
-                collector=collector)
-            x = (gather or masked_feature_gather)(feat, n_id, forder,
-                                                  collector=collector)
-        adjs = layers_to_adjs(layers, batch_cap, sizes)
+        _, x, layers = walk_frontier(walk, feat, forder, indptr, indices,
+                                     seeds, sub, collector=collector)
+        adjs = layers_to_adjs(layers, batch_cap, walk.sizes)
         with jax.named_scope("qt_serve_forward"):
             logits = model.apply(params, x, adjs, train=False)
         return key, logits[:batch_cap]
@@ -351,18 +301,6 @@ def build_serve_step(model, sizes: Sequence[int], batch_cap: int,
     jitted.jitted_fns = (jitted,)
     jitted.raw = raw
     return jitted
-
-
-def sample_multihop_serving(indptr, indices, seeds, sizes, key,
-                            method="exact", collector=None):
-    """The serve step's sampling stage — ``ops.sample_multihop`` under
-    the coalescer's batch contract (distinct valid seeds first, -1 tail
-    fill => ``seeds_dense``). Split out so jaxpr pins can trace the
-    sampling half alone."""
-    from .ops.sample_multihop import sample_multihop
-    return sample_multihop(indptr, indices, seeds, sizes, key,
-                           method=method, seeds_dense=True,
-                           collector=collector)
 
 
 # -- the engine: params + tiers + pre-compiled variants ----------------------
@@ -399,12 +337,10 @@ class ServeEngine:
     ``collect_metrics=True`` makes every ``run`` also emit the device
     counter vector (stashed on ``last_counters``; read it lazily).
 
-    ``fused_hot_hop=True`` (exact method; any hop count — the ladder
-    variants share one census bound) builds each variant on the fused
-    Pallas walk: every hop samples in-kernel, the leaf hop gathers the
-    hot-tier rows in the same kernel, and only cold frontier slots
-    (when the store is tiered) take the split lookup. See
-    ``build_serve_step``'s knob of the same name.
+    ``**walk`` goes to every variant's ``build_serve_step`` unopened (the
+    walk's knobs, listed there). With ``fused_hot_hop=True`` the ladder
+    variants share one census bound, and only cold frontier slots (when
+    the store is tiered) take the split lookup.
 
     ``run(seeds, variant=0)`` is NOT thread-safe (the donated key chain
     is serialized state) — the server funnels all dispatches through
@@ -415,12 +351,8 @@ class ServeEngine:
                  sizes_variants: Sequence[Sequence[int]],
                  batch_cap: int,
                  forder=None,
-                 method: str = "exact",
-                 dedup_gather=None,
                  collect_metrics: bool = False,
-                 fused_hot_hop: bool = False,
-                 fused_row_cap: int = 2048,
-                 seed: int = 0):
+                 seed: int = 0, **walk):
         if not sizes_variants:
             raise ValueError("need at least one fanout variant")
         hops = {len(s) for s in sizes_variants}
@@ -432,7 +364,6 @@ class ServeEngine:
         self.params = params
         self.variants: List[List[int]] = [list(s) for s in sizes_variants]
         self.batch_cap = int(batch_cap)
-        self.method = method
         self.collect_metrics = bool(collect_metrics)
         self.last_counters = None
         # host seconds of the last run's two stages (seed block onto the
@@ -452,23 +383,9 @@ class ServeEngine:
         self._feat = feat
         self._forder = None if forder is None else \
             jnp.asarray(forder, jnp.int32)
-        fused_kw = {}
-        if fused_hot_hop:
-            hot_rows = None
-            if gather is not None:
-                # tiered store: the kernel reads the (device_part, host)
-                # pytree's hot part; cold picks route back through the
-                # store's own lookup (the serve step's cold fixup)
-                from .ops import quant
-                hot_rows = quant.tier_rows(self._feat[0])
-            fused_kw = dict(fused_hot_hop=True,
-                            fused_row_cap=fused_row_cap,
-                            fused_hot_rows=hot_rows)
         self._steps = [
-            build_serve_step(model, sizes, self.batch_cap, method=method,
-                             dedup_gather=dedup_gather, gather=gather,
-                             collect_metrics=self.collect_metrics,
-                             **fused_kw)
+            build_serve_step(model, sizes, self.batch_cap, gather=gather,
+                             collect_metrics=self.collect_metrics, **walk)
             for sizes in self.variants]
         self._key = jax.random.key(seed)
 
@@ -593,22 +510,21 @@ def _feature_gather(feature):
         rows, vec = raw(dev, host_t, n_id, forder, True, True)
         collector.absorb(vec)
         return rows
+    # for the fused walk: its kernel reads ``feat_args[0]``, the hot tier,
+    # and routes the picks beyond these rows back through ``gather``
+    gather.hot_rows = quant.tier_rows(feature.device_part)
     return (feature.device_part, host), feature.feature_order, gather
 
 
 # -- sharded serving: one partitioned store under the whole fleet ------------
 
 
+@documented(walk_doc(_SHARDED_KNOBS))
 def build_sharded_serve_step(model, sizes: Sequence[int], batch_cap: int,
                              mesh, axis: str, rows_per_host: int,
-                             method: str = "exact",
                              exchange_cap=None,
                              home: Optional[int] = None,
-                             collect_metrics: bool = False,
-                             fused_hot_hop: bool = False,
-                             fused_row_cap: int = 2048,
-                             fused_rng: Optional[str] = None,
-                             fused_interpret: Optional[bool] = None):
+                             collect_metrics: bool = False, **walk):
     """The serve step over a ``DistFeature``-partitioned store: ONE
     jitted ``shard_map`` program per fanout config whose gather stage is
     the PR 4 compact deduplicated exchange (``comm.dist_lookup_local``)
@@ -624,13 +540,14 @@ def build_sharded_serve_step(model, sizes: Sequence[int], batch_cap: int,
     sampling runs REPLICATED (no per-shard key fold), so the frontier,
     the adjacency structure and therefore the logits are bit-identical
     to the single-store ``build_serve_step`` over the same unpartitioned
-    array (pinned in tests/test_serving.py): only WHERE the rows live
+    array (pinned in tests/test_serving.py; with ``fused_hot_hop`` on
+    both, to the fused single-store step): only WHERE the rows live
     changes, never which rows are read.
 
     ``exchange_cap`` (``True | int | None``): the compact [H, cap]
-    request block; overflow falls back to the dense [H, F] exchange via
-    the shard-uniform ``lax.pmax``'d ``lax.cond`` inside
-    ``dist_lookup_local`` — row-identical either way, and the whole
+    request block; a per-owner bucket that overflows it takes further
+    rounds of the same [H, cap] exchange (a shard-uniform loop inside
+    ``dist_lookup_local``) — row-identical either way, and the whole
     program still performs zero host syncs (qt-verify's
     ``no_host_sync`` / ``collective_divergence`` rules cover the traced
     body; per-variant ``executable_census`` bounds the program count).
@@ -642,32 +559,22 @@ def build_sharded_serve_step(model, sizes: Sequence[int], batch_cap: int,
     multiply it by the shard count): owned by ``home`` ->
     ``locality_hit_rows``, owned elsewhere -> ``locality_miss_rows`` —
     the router-as-cache-policy payoff counters (miss rows are exactly
-    the rows the exchange must ship in from other partitions).
-
-    ``fused_hot_hop=True`` (exact method) swaps the replicated sampling
-    stage for the gather-free fused Pallas walk
-    (``ops.pallas.fused.fused_sample_multihop``): every hop's degrees
-    and CSR windows resolve in-kernel, so the sampling half contributes
-    zero ``gather_index_bytes`` — the hot-tier leg of the sharded step.
-    The feature rows still arrive through the unchanged partitioned
-    exchange (``dist_lookup_local``); picks come from the kernel PRNG
-    stream, so logits are bit-comparable with a fused single-store
-    ``build_serve_step`` over the same rows, not with the split sharded
-    step."""
-    from .comm import default_exchange_cap, dist_lookup_local
+    the rows the exchange must ship in from other partitions)."""
+    from .comm import dist_lookup_local
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    sizes = list(sizes)
-    fused = _fused_knobs(fused_hot_hop, fused_row_cap, fused_rng,
-                         fused_interpret, sizes, method)
     h_count = mesh.shape[axis]
-    if exchange_cap is True:
-        from .pyg.sage_sampler import layer_shapes
-        frontier = layer_shapes(batch_cap, sizes)[-1].n_id_cap
-        exchange_cap = default_exchange_cap(frontier, h_count)
-    elif exchange_cap is not None:
-        exchange_cap = int(exchange_cap)
+
+    def exchange(store, n_id, _forder, collector=None, exchange_cap=None):
+        feat, g2h, g2l = store
+        return dist_lookup_local(n_id, g2h, g2l, feat, axis, h_count,
+                                 rows_per_host, exchange_cap=exchange_cap,
+                                 collector=collector)
+
+    walk = Walk.of("build_sharded_serve_step", _SHARDED_KNOBS, sizes, walk,
+                   gather=exchange,
+                   exchange=(h_count, exchange_cap, batch_cap))
 
     @hot_path
     def per_shard(params, key, feat, g2h, g2l, indptr, indices, seeds):
@@ -681,24 +588,10 @@ def build_sharded_serve_step(model, sizes: Sequence[int], batch_cap: int,
         # psum to the true mesh-wide totals
         rep_col = Collector() if collect_metrics else None
         key, sub = jax.random.split(key)
-        if fused is not None:
-            from .ops.pallas.fused import (fused_sample_multihop,
-                                           pad_indices)
-            n_id, layers = fused_sample_multihop(
-                indptr, pad_indices(indices, fused["row_cap"]), seeds,
-                sizes, sub, **fused)
-            if rep_col is not None:
-                from .metrics import FRONTIER_CAP, FRONTIER_VALID
-                rep_col.add(FRONTIER_VALID, jnp.sum(n_id >= 0))
-                rep_col.add(FRONTIER_CAP, int(n_id.shape[0]))
-        else:
-            n_id, layers = sample_multihop_serving(
-                indptr, indices, seeds, sizes, sub, method=method,
-                collector=rep_col)
-        x = dist_lookup_local(n_id, g2h, g2l, feat, axis, h_count,
-                              rows_per_host, exchange_cap=exchange_cap,
-                              collector=col)
-        adjs = layers_to_adjs(layers, batch_cap, sizes)
+        n_id, x, layers = walk_frontier(
+            walk, (feat, g2h, g2l), None, indptr, indices, seeds, sub,
+            collector=rep_col, rows_collector=col)
+        adjs = layers_to_adjs(layers, batch_cap, walk.sizes)
         with jax.named_scope("qt_serve_forward"):
             logits = model.apply(params, x, adjs, train=False)
         if not collect_metrics:
@@ -750,17 +643,15 @@ class ShardedServeEngine:
     ``ServeEngine`` over the unpartitioned array (with
     ``fused_hot_hop=True`` on both — the fused sampling leg of
     ``build_sharded_serve_step`` — the match is against the fused
-    single-store engine's kernel-PRNG stream)."""
+    single-store engine's kernel-PRNG stream). ``**walk`` goes to every
+    variant's ``build_sharded_serve_step`` unopened."""
 
     def __init__(self, model, params, topo, dist,
                  sizes_variants: Sequence[Sequence[int]],
                  batch_cap: int,
-                 method: str = "exact",
                  home: Optional[int] = None,
                  collect_metrics: bool = False,
-                 fused_hot_hop: bool = False,
-                 fused_row_cap: int = 2048,
-                 seed: int = 0):
+                 seed: int = 0, **walk):
         if not sizes_variants:
             raise ValueError("need at least one fanout variant")
         hops = {len(s) for s in sizes_variants}
@@ -781,7 +672,6 @@ class ShardedServeEngine:
         self.dist = dist
         self.variants: List[List[int]] = [list(s) for s in sizes_variants]
         self.batch_cap = int(batch_cap)
-        self.method = method
         self.home = int(dist.info.host if home is None else home)
         self.partitions = int(dist.info.hosts)
         self.collect_metrics = bool(collect_metrics)
@@ -798,11 +688,9 @@ class ShardedServeEngine:
         self._steps = [
             build_sharded_serve_step(
                 model, sizes, self.batch_cap, dist.comm.mesh,
-                dist.comm.axis, dist._rows_per_host, method=method,
+                dist.comm.axis, dist._rows_per_host,
                 exchange_cap=dist.exchange_cap, home=self.home,
-                collect_metrics=self.collect_metrics,
-                fused_hot_hop=fused_hot_hop,
-                fused_row_cap=fused_row_cap)
+                collect_metrics=self.collect_metrics, **walk)
             for sizes in self.variants]
         self._key = jax.random.key(seed)
 
