@@ -12,9 +12,10 @@ The model composes three evidence sources the stack already produces:
   gives the device service time; the coalescer's per-request host cost
   (``overhead_per_req_ms`` — queue hop, slot bookkeeping, future
   delivery; calibrated from a serial round-trip) runs CONCURRENTLY
-  with dispatch when ``pipeline_depth >= 2``, so the batch cycle time
-  is ``s = max(dispatch, fill · overhead)`` — whichever side of the
-  pipeline is the bottleneck;
+  with dispatch when ``pipeline_depth >= 2`` (one closed batch runs,
+  one waits behind it, the next is open and coalescing), so the batch
+  cycle time is ``s = max(dispatch, fill · overhead)`` — whichever
+  side of the pipeline is the bottleneck;
 - the serving layer's queueing discipline (coalesce up to
   ``max_wait``, dispatch, p99 budget) bounds how hot the pipeline may
   run: with latency headroom ``w = budget_p99 - s - max_wait``, the

@@ -44,7 +44,14 @@ SAME batch share one seed slot — the dedup convention applied at the
 request layer; batches already dispatched are not revisited), a max-wait
 deadline bounds how long a lone request can sit waiting for company,
 and a ``pipeline.Pipeline`` executes batches so batch i+1 coalesces
-while batch i runs. Results scatter back to each request's future.
+while batch i runs. A batch is CLOSED (its seed block built, no more
+requests taken) when it is full, or when its deadline has passed AND
+the pipeline has room for it: ``pipeline_depth`` closed batches may
+exist at once, the running one included, so under load a batch stays
+open, and goes on taking the requests that arrive, until the worker
+takes the one ahead of it — a closed batch would only wait behind the
+ones in flight, and whoever arrived meanwhile would wait a batch more.
+Results scatter back to each request's future.
 Latency SLOs are first-class: per-REQUEST admission->result latency
 lands in ``metrics.StepStats`` (``record_request``) and in a
 ``metrics.SloBudget`` (target p99 + availability, multi-window
@@ -739,7 +746,9 @@ class ServeConfig:
       of a batch may wait for company before the batch dispatches
       anyway. The lone-request worst case adds exactly this much.
     - ``queue_depth``: admission bound; a full queue sheds load
-      (``submit`` raises :class:`OverloadError`).
+      (``submit`` raises :class:`OverloadError`). A batch that waits
+      open for room holds at most this many requests before it closes
+      like a full one, so a stalled device still fills the queue.
     - ``slo_p99_ms``: per-request latency target. Setting it arms a
       ``metrics.SloBudget`` (target p99 at ``slo_availability`` over
       sliding windows); the server sheds QUALITY — dispatches escalate
@@ -758,8 +767,14 @@ class ServeConfig:
     - ``shed_queue_frac``: queue fullness (0..1) that also triggers a
       quality-shed step — backlog is tomorrow's latency, so the server
       reacts before the SLO is already blown.
-    - ``pipeline_depth``: in-flight batch bound (coalesce i+1 while i
-      runs; more depth adds queueing latency, not throughput, past 2).
+    - ``pipeline_depth``: how many CLOSED batches may exist at once,
+      the one that runs included (at 2: one runs, one waits behind it,
+      and the next stays open, taking requests, until the worker takes
+      the waiting one). A batch past ``max_wait_ms`` closes when there
+      is room; a FULL batch closes at once (it can take no more) and
+      waits for its turn in ``serve.pipe_submit``. More depth adds
+      queueing latency, not throughput, past 2; at 1 a batch closes
+      only once the worker is idle, so the close is in the cycle.
     """
 
     def __init__(self, max_wait_ms: float = 2.0, queue_depth: int = 256,
@@ -898,9 +913,18 @@ class MicroBatchServer:
                 self._tenant_states[n] = _TenantState(c, share)
         self._q: "queue.Queue[_Request]" = queue.Queue(
             maxsize=self.config.queue_depth)
-        self._pipe = Pipeline(depth=self.config.pipeline_depth,
+        # pipeline_depth bounds the CLOSED batches in flight, the one
+        # inside _execute included: the worker holds one, so the
+        # pipeline queues one fewer (its own minimum is one slot, so at
+        # depth 1 a FULL batch may still queue behind the running one)
+        self._pipe = Pipeline(depth=max(1, self.config.pipeline_depth - 1),
                               name="quiver-serving-exec")
         self.stats.watch_pipeline(self._pipe)
+        # closed batches handed to the pipeline and not yet through it.
+        # Guarded by the admission queue's mutex and announced on its
+        # not_empty condition, so the coalescer has ONE wake-up: a
+        # request arrived, or room came
+        self._in_flight = 0
         self._closed = False
         # broken = the coalescer thread died UNEXPECTEDLY (not close):
         # nothing will ever drain the queue again, so submissions must
@@ -923,6 +947,9 @@ class MicroBatchServer:
             "requests": 0, "rejected": 0, "completed": 0, "failed": 0,
             "deadline_expired": 0, "displaced": 0,
             "batches": 0, "coalesced": 0,
+            # batches past their deadline and not full, closed only
+            # because the pipeline got room (see _coalesce_loop)
+            "held_open": 0,
             "variant_batches": [0] * len(engine.variants),
             # host seconds of each stage, summed over batches (and
             # queue_wait_s over requests): see docs/observability.md
@@ -955,7 +982,8 @@ class MicroBatchServer:
 
     def close(self):
         """Reject new submissions, fail queued (never-dispatched)
-        requests with ``RuntimeError``, drain the in-flight batches,
+        requests with ``RuntimeError`` — those of a batch still open,
+        waiting for room, among them — drain the in-flight batches,
         stop the coalescer and the pipeline. Idempotent."""
         from .metrics import unregister_report_section
         unregister_report_section(self._report_name)
@@ -1272,13 +1300,40 @@ class MicroBatchServer:
             with self._counts_lock:
                 self._tenant_states[req.tenant].queued -= 1
 
-    def _pop_next(self, timeout: float):
+    def _has_room(self) -> bool:
+        """May another batch close? ``pipeline_depth`` closed batches
+        may exist at once, the one inside ``_execute`` included."""
+        return self._in_flight < self.config.pipeline_depth
+
+    def _left_pipeline(self, batch, pf) -> None:
+        """Done-callback of a handed-over batch (it ran, failed, or the
+        pipeline cancelled it while queued): its place is free, and the
+        coalescer holding a batch open for room is told so."""
+        with self._q.not_empty:
+            self._in_flight -= 1
+            self._q.not_empty.notify()
+        # a batch the pipeline cancels while queued (close() drains it)
+        # never reaches _execute — fail its futures, don't strand them
+        if pf.cancelled():
+            self._fail_batch(batch)
+
+    def _pop_next(self, timeout: float, held: bool = True,
+                  or_room: bool = False):
         """Next request for the coalescer: deferred (held) requests
         first — oldest first, so class-pure deferral never starves a
-        class — then the admission queue. Raises ``queue.Empty`` on
-        timeout."""
-        if self._held:
+        class; ``held=False`` skips them — then the admission queue.
+        With ``or_room`` the wait also ends when the pipeline gets room
+        for a batch. Raises ``queue.Empty`` when the wait ends with no
+        request."""
+        if held and self._held:
             return self._held.pop(0)
+        if or_room:
+            # the queue's own condition: put() notifies it for a
+            # request, _left_pipeline for room
+            with self._q.not_empty:
+                if not self._q.queue and not self._has_room():
+                    self._q.not_empty.wait(timeout)
+            timeout = 0.0
         req = self._q.get(timeout=timeout)
         self._note_popped(req)
         return req
@@ -1354,25 +1409,44 @@ class MicroBatchServer:
                             keep.append(r)
                     self._held = keep
                 deadline = t_first + max_wait
-                # drain until the seed block is full or the first
-                # request's wait budget is spent — a lone request ships
-                # at deadline, a burst splits into back-to-back full
-                # batches
+                # drain until the seed block is full, or the first
+                # request's wait budget is spent AND the pipeline has
+                # room: the deadline says when the batch MAY close, room
+                # says when closing it gets it to the device any sooner.
+                # Until then requests keep joining (a closed batch takes
+                # none, and would only wait behind the ones in flight).
+                # A lone request on an idle server ships at deadline, a
+                # burst splits into back-to-back full batches
+                waited_for_room = held_open = False
+                late = 0                 # joined while it waited for room
                 while len(slots) < cap:
                     remaining = deadline - time.perf_counter()
                     if remaining <= 0:
-                        break
+                        if self._has_room():
+                            held_open = waited_for_room
+                            break
+                        if self._closed:
+                            self._fail_batch(batch)
+                            return
+                        if len(batch) + len(self._held) >= \
+                                self.config.queue_depth:
+                            # bounded everywhere: duplicates share a
+                            # slot, so a stalled device would let an
+                            # open batch swallow requests without end.
+                            # One that has popped a queue's worth closes
+                            # like a full one, and its blocked submit
+                            # lets the queue fill and shed at admission
+                            break
+                        waited_for_room = True
+                        remaining = 0.02       # wake to see a close()
                     try:
-                        if bcls is None:
-                            req = self._pop_next(remaining)
-                        else:
-                            # class-pure: pull from the queue only (held
-                            # was filtered above and now holds only other
-                            # classes — re-popping it here would spin)
-                            req = self._q.get(timeout=remaining)
-                            self._note_popped(req)
+                        # class-pure: pull from the queue only (held
+                        # was filtered above and now holds only other
+                        # classes — re-popping it here would spin)
+                        req = self._pop_next(remaining, held=bcls is None,
+                                             or_room=waited_for_room)
                     except queue.Empty:
-                        break
+                        continue
                     if self._shed_expired(req):
                         continue
                     if bcls is not None and \
@@ -1381,6 +1455,7 @@ class MicroBatchServer:
                         continue
                     batch.append(req)
                     slots.setdefault(req.node_id, len(slots))
+                    late += waited_for_room
                     if traced:
                         t_pop = time.perf_counter()
                         pops.append((req, t_pop))
@@ -1393,7 +1468,7 @@ class MicroBatchServer:
                 seeds = np.full((self.engine.batch_cap,), -1, np.int32)
                 for nid, s in slots.items():
                     seeds[s] = nid
-                variant = self._select_variant()
+                variant = self._select_variant(late)
                 if bcls is not None:
                     # per-class quality-shed order: this class ignores
                     # shed_grace ladder steps of the local shed level;
@@ -1404,9 +1479,14 @@ class MicroBatchServer:
                     variant = max(graced, min(self._shed_floor, top))
                 coalesce.args = {"requests": len(batch),
                                  "fill": len(slots), "variant": variant}
-            # the pipeline submit blocks at depth: device-side
-            # backpressure propagates here, the queue absorbs it, and a
-            # full queue sheds at admission — bounded everywhere
+            # only a batch that could take no more (full, or a queue's
+            # worth) finds the pipeline without room; any other stayed
+            # open until there was some. Its submit blocks at depth,
+            # device-side backpressure propagates here, the queue
+            # absorbs it, and a full queue sheds at admission — bounded
+            # everywhere
+            with self._q.mutex:
+                self._in_flight += 1
             try:
                 with tracing.stage("serve.pipe_submit", bid) as handoff:
                     pf = self._pipe.submit(self._execute, batch, slots,
@@ -1419,24 +1499,23 @@ class MicroBatchServer:
             with self._counts_lock:
                 self._counts["coalesce_s"] += coalesce.dur
                 self._counts["pipe_submit_s"] += handoff.dur
+                self._counts["held_open"] += held_open
             if traced:
                 t_sub = handoff.t0 + handoff.dur
                 for req, t_pop in pops:
                     tracing.record("serve.coalesce_wait", t_pop,
                                    t_sub - t_pop, req.trace_id,
                                    {"batch": bid})
-            # a batch the pipeline cancels while queued (close() drains
-            # it) never reaches _execute — fail its futures, don't
-            # strand them
             pf.add_done_callback(
-                lambda f, b=batch:
-                    self._fail_batch(b) if f.cancelled() else None)
+                lambda f, b=batch: self._left_pipeline(b, f))
 
     # -- shedding policy ----------------------------------------------------
-    def _select_variant(self) -> int:
+    def _select_variant(self, late: int = 0) -> int:
         """Quality-shed decision for the NEXT batch (coalescer thread
-        only). Escalates one fanout step down the ladder when queue
-        backlog crosses its threshold or the SLO error budget is
+        only; ``late`` = the requests that joined that batch while it
+        stayed open for room, backlog like the queue they would
+        otherwise sit in). Escalates one fanout step down the ladder
+        when queue backlog crosses its threshold or the SLO error budget is
         burning unsustainably (``SloBudget.should_shed`` — the
         multi-window burn-rate signal that replaced the raw recent-p99
         trigger; it reacts to the RATE the budget is being spent, and
@@ -1454,8 +1533,9 @@ class MicroBatchServer:
         cfg = self.config
         shed_at = max(1, int(cfg.queue_depth * cfg.shed_queue_frac))
         # held (class-deferred) requests are backlog too — they are
-        # admitted work the coalescer has not dispatched yet
-        pressed = self._q.qsize() + len(self._held) >= shed_at
+        # admitted work the coalescer has not dispatched yet, as are
+        # the late joiners of the batch about to close
+        pressed = self._q.qsize() + len(self._held) + late >= shed_at
         if not pressed and self.slo is not None:
             pressed = self.slo.should_shed()
         if pressed:

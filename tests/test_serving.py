@@ -199,6 +199,47 @@ class TestCoalescing:
         assert srv.snapshot()["serving"]["batches"] == 1
         srv.close()
 
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_rows_are_engine_runs_of_the_closed_seed_blocks(
+            self, world, depth, monkeypatch):
+        # the coalescer decides WHICH seed blocks exist and nothing
+        # else: a request is served, bit for bit, engine.run's row of
+        # its batch's block, the blocks run one at a time in the order
+        # they closed (what every pipeline_depth, and the server before
+        # batches stayed open for room, serves for these blocks). The
+        # SHED fanout is under most degrees, so a row depends on its
+        # block's place in the key chain.
+        model, params, ij, xj, feat = world
+        eng = qv.ServeEngine(model, params, (ij, xj), feat,
+                             sizes_variants=[SHED], batch_cap=CAP, seed=17)
+        real_run, blocks = eng.run, []
+
+        def recording_run(seeds, variant=0):
+            blocks.append((np.array(seeds), variant))
+            return real_run(seeds, variant)
+
+        monkeypatch.setattr(eng, "run", recording_run)
+        srv = qv.MicroBatchServer(
+            eng, qv.ServeConfig(max_wait_ms=1.0, queue_depth=64,
+                                pipeline_depth=depth, shed_queue_frac=1.0),
+            start=False)
+        ids = list(range(2 * CAP + 3))
+        futs = [srv.submit(i) for i in ids]
+        srv.start()
+        served = [f.result(timeout=20) for f in futs]
+        srv.close()
+        monkeypatch.undo()
+        assert [list(b[:3]) for b, _ in blocks] == [[0, 1, 2], [8, 9, 10],
+                                                    [16, 17, 18]]
+        eng._key = jax.random.key(17)        # rewind the donated chain
+        rows = np.concatenate([np.asarray(jax.device_get(eng.run(b, v)))
+                               for b, v in blocks])
+        for i, row in zip(ids, served):
+            assert row.tobytes() == rows[i].tobytes(), i
+        # the chain matters: the same block one place later reads another
+        again = np.asarray(jax.device_get(eng.run(*blocks[-1])))
+        assert not np.array_equal(again[:3], rows[2 * CAP:2 * CAP + 3])
+
     def test_scatter_under_interleaved_arrivals(self, engine, reference):
         srv = qv.MicroBatchServer(
             engine, qv.ServeConfig(max_wait_ms=2.0, queue_depth=512,

@@ -13,13 +13,20 @@ The contracts:
    children of ``serve.dispatch`` sum to no more than it;
    ``serve.batch_coalesce`` ends where the batch closes and
    ``serve.pipe_submit`` holds the backpressure.
-3. **Names** — ``tracing.STAGES`` is every name a ``tracing.stage(``
+3. **Room** — ``pipeline_depth`` closed batches may exist, the running
+   one included: a batch past its deadline stays open, and takes the
+   requests that arrive, until the pipeline has room (``held_open``
+   counts those); a full batch closes at once and waits in
+   ``serve.pipe_submit``; a lone request on an idle server ships at
+   ``max_wait_ms``.
+4. **Names** — ``tracing.STAGES`` is every name a ``tracing.stage(``
    call site uses, and no stage is also hand-recorded.
 """
 
 import glob
 import os
 import re
+import sys
 import threading
 import time
 
@@ -65,8 +72,10 @@ class _StubEngine:
         self.gate = gate
         self.last_stage_s = (0.0, 0.0)
         self._stage_s = stage_s
+        self.calls = []              # the seed block of every run, at entry
 
     def run(self, seeds, variant=0):
+        self.calls.append([int(s) for s in seeds])
         if self.gate is not None:
             assert self.gate.wait(timeout=10)
         time.sleep(sum(self._stage_s))
@@ -74,6 +83,29 @@ class _StubEngine:
         out = np.zeros((self.batch_cap, 2), np.float32)
         out[:, 0] = np.asarray(seeds, np.float32)
         return out
+
+
+class _Turnstile:
+    """The gate a ``_StubEngine`` waits on, one ``run`` through a
+    ``let()``."""
+
+    def __init__(self):
+        self._sem = threading.Semaphore(0)
+
+    def wait(self, timeout=None):
+        return self._sem.acquire(timeout=timeout)
+
+    def let(self, n=1):
+        for _ in range(n):
+            self._sem.release()
+
+
+def _until(what, timeout=5.0):
+    """Poll ``what()`` true within ``timeout``; its last value."""
+    end = time.perf_counter() + timeout
+    while not what() and time.perf_counter() < end:
+        time.sleep(0.001)
+    return what()
 
 
 def _serve(engine, n, **cfg):
@@ -218,15 +250,18 @@ class TestCounters:
         assert 0 < launch_s <= snap["launch_s"]
         assert snap["put_s"] + snap["launch_s"] < snap["execute_s"]
 
-    def test_backpressure_lands_in_pipe_submit_not_in_coalesce(self):
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_backpressure_lands_in_pipe_submit_not_in_coalesce(self, depth):
         gate = threading.Event()
         srv = qv.MicroBatchServer(
             _StubEngine(batch_cap=1, gate=gate),
             qv.ServeConfig(max_wait_ms=1.0, queue_depth=16,
-                           pipeline_depth=1, shed_queue_frac=1.0))
-        # batch 1 sits in run(), batch 2 fills the pipeline's one slot,
-        # batch 3's submit blocks until the gate opens
-        futs = [srv.submit(i) for i in range(3)]
+                           pipeline_depth=depth, shed_queue_frac=1.0))
+        # every batch is full at its first request, so it closes at
+        # once: batch 1 sits in run(), the next fill the pipeline's
+        # slots (one at depth 1 and 2, two at depth 3), and the submit
+        # of the one after blocks until the gate opens
+        futs = [srv.submit(i) for i in range(depth + 2)]
         time.sleep(0.15)
         gate.set()
         for f in futs:
@@ -234,6 +269,7 @@ class TestCounters:
         snap = _closed_snapshot(srv)
         assert snap["pipe_submit_s"] >= 0.1
         assert snap["coalesce_s"] <= 0.08
+        assert snap["held_open"] == 0
         # the pipeline's clock starts when the batch is handed over, so
         # the blocked submit is inside its wait
         assert snap["pipeline_wait_s"] >= snap["pipe_submit_s"] * 0.9
@@ -287,6 +323,187 @@ class TestCounters:
                    for r in recs if r[0] == "serve.request")
         assert snap["queue_wait_s"] == pytest.approx(want, rel=1e-6)
         assert snap["queue_wait_s"] > 0
+
+
+def _in_pipeline(srv):
+    """Closed batches in existence: handed over and not yet through."""
+    q = srv.snapshot()["queue"]
+    return q["submitted"] - q["completed"] - q["failed"] - q["cancelled"]
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+class TestRoom:
+    """``pipeline_depth`` closed batches may exist, the running one
+    included; until there is room a batch past its deadline stays open."""
+
+    def _fill(self, depth, cap=4):
+        """A server whose pipeline is full behind a shut gate: ``depth``
+        batches of one request, each closed at its deadline."""
+        gate = _Turnstile()
+        eng = _StubEngine(batch_cap=cap, stage_s=(0.0, 0.0), gate=gate)
+        srv = qv.MicroBatchServer(
+            eng, qv.ServeConfig(max_wait_ms=1.0, queue_depth=16,
+                                pipeline_depth=depth, shed_queue_frac=1.0))
+        futs = []
+        for i in range(depth):
+            futs.append(srv.submit(i))
+            assert _until(lambda: _in_pipeline(srv) == i + 1)
+        assert _until(lambda: len(eng.calls) == 1)
+        return gate, eng, srv, futs
+
+    def test_a_request_that_finds_the_pipeline_full_rides_the_next_batch(
+            self, depth):
+        gate, eng, srv, futs = self._fill(depth)
+        # twenty deadlines each: the parent closed a batch for either
+        a = srv.submit(100)
+        time.sleep(0.02)
+        assert _in_pipeline(srv) == depth and len(eng.calls) == 1
+        b = srv.submit(101)
+        time.sleep(0.02)
+        assert _in_pipeline(srv) == depth and len(eng.calls) == 1
+        assert not a.done() and not b.done()
+        gate.let(1)                  # batch 1 leaves: room for ONE more
+        assert futs[0].result(timeout=10)[0] == 0
+        assert _until(lambda: srv.snapshot()["serving"]["held_open"] == 1)
+        assert _in_pipeline(srv) == depth
+        gate.let(depth)
+        assert a.result(timeout=10)[0] == 100
+        assert b.result(timeout=10)[0] == 101
+        for i, f in enumerate(futs):
+            assert f.result(timeout=10)[0] == i
+        # both rode the batch that closed next, the (depth+1)-th
+        assert eng.calls == [[i, -1, -1, -1] for i in range(depth)] \
+            + [[100, 101, -1, -1]]
+        snap = _closed_snapshot(srv)
+        assert snap["batches"] == depth + 1 and snap["completed"] == depth + 2
+        assert srv.snapshot()["queue"]["max_depth"] <= max(1, depth - 1)
+
+    def test_a_lone_request_ships_at_max_wait_and_is_not_held(self, depth):
+        max_wait = 0.02
+        srv = qv.MicroBatchServer(
+            _StubEngine(stage_s=(0.0, 0.0)),
+            qv.ServeConfig(max_wait_ms=1e3 * max_wait, queue_depth=16,
+                           pipeline_depth=depth, shed_queue_frac=1.0))
+        for i in range(3):
+            t0 = time.perf_counter()
+            assert srv.submit(i).result(timeout=10)[0] == i
+            # the slack is the machine's: a timed wait may overshoot
+            assert max_wait * 0.9 <= time.perf_counter() - t0 \
+                <= max_wait + 0.1
+        snap = _closed_snapshot(srv)
+        assert snap["batches"] == 3 and snap["held_open"] == 0
+        assert 3 * max_wait * 0.9 <= snap["coalesce_s"] \
+            <= 3 * (max_wait + 0.02)
+
+    def test_held_open_counts_the_batches_that_closed_on_room(self, depth):
+        gate, eng, srv, futs = self._fill(depth)
+        # held past its deadline, closed when room came: counted
+        futs += [srv.submit(100), srv.submit(101)]
+        assert _until(lambda: srv.snapshot()["serving"]["queue_depth"] == 0)
+        time.sleep(0.05)
+        gate.let(1)
+        assert _until(lambda: _in_pipeline(srv) == depth
+                      and srv.snapshot()["queue"]["completed"] == 1)
+        # held past its deadline, then FULL: it closes on the cap and
+        # waits in serve.pipe_submit like any full batch: not counted
+        futs.append(srv.submit(200))
+        time.sleep(0.01)
+        futs += [srv.submit(i) for i in (201, 202, 203)]
+        time.sleep(0.01)
+        gate.let(depth + 1)
+        for f in futs:
+            f.result(timeout=10)
+        # alone on an idle server, closed at its deadline: not counted
+        gate.let(1)
+        assert srv.submit(300).result(timeout=10)[0] == 300
+        snap = _closed_snapshot(srv)
+        assert eng.calls[depth:] == [[100, 101, -1, -1],
+                                     [200, 201, 202, 203],
+                                     [300, -1, -1, -1]]
+        assert snap["batches"] == depth + 3 and snap["held_open"] == 1
+
+    def test_an_open_batch_takes_a_queues_worth_then_admission_sheds(
+            self, depth):
+        # duplicates share one slot, so the cap never closes this batch;
+        # behind a stalled engine it must not swallow requests for ever
+        gate, eng, srv, futs = self._fill(depth)
+        depth_q = srv.config.queue_depth
+        admitted = 0
+        with pytest.raises(qv.OverloadError):
+            for _ in range(8 * depth_q):
+                futs.append(srv.submit(100))
+                admitted += 1
+                time.sleep(0.0005)
+        # at most: an open batch's worth in the blocked submit (and one
+        # before it where the pipeline's one slot was free), the queue
+        assert depth_q <= admitted <= 3 * depth_q + 4
+        gate.let(depth + 8)
+        for f in futs:
+            assert f.result(timeout=10)[0] in (*range(depth), 100)
+        snap = _closed_snapshot(srv)
+        assert snap["completed"] == depth + admitted
+        assert snap["rejected"] == 1 and snap["held_open"] == 0
+
+    def test_the_count_of_batches_in_flight_survives_a_stampede(self, depth):
+        # the coalescer adds to it and the worker takes from it: more
+        # callers than cores and a short switch interval, a lost update
+        # would leave the count off zero or let too many batches close
+        eng = _StubEngine(stage_s=(0.0, 0.0002))
+        srv = qv.MicroBatchServer(
+            eng, qv.ServeConfig(max_wait_ms=0.2, queue_depth=256,
+                                pipeline_depth=depth, shed_queue_frac=1.0))
+        wrong, most = [], [0]
+
+        def caller(k):
+            for i in range(150):
+                nid = 1000 * k + i
+                if srv.submit(nid).result(timeout=20)[0] != nid:
+                    wrong.append(nid)
+                most[0] = max(most[0], _in_pipeline(srv))
+
+        before = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=caller, args=(k,))
+                       for k in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(before)
+        assert not any(t.is_alive() for t in threads) and not wrong
+        snap = _closed_snapshot(srv)
+        assert snap["completed"] == 16 * 150 and srv._in_flight == 0
+        # the one more is a full batch in its blocked submit (at depth 1
+        # the pipeline's one slot may also hold a full one)
+        assert most[0] <= max(depth, 2) + 1
+
+    def test_close_fails_a_batch_held_open_and_strands_no_future(self,
+                                                                 depth):
+        gate, eng, srv, futs = self._fill(depth)
+        held = [srv.submit(100), srv.submit(101)]
+        time.sleep(0.01)
+        closer = threading.Thread(target=srv.close)
+        closer.start()               # blocks on the batch inside run()
+        for f in held:               # failed while the gate is still shut
+            assert isinstance(f.exception(timeout=5), qv.ServerClosed)
+        with pytest.raises(qv.ServerClosed):
+            srv.submit(102)
+        # the pipeline's queue is swept while batch 1 still sits in run()
+        assert _until(
+            lambda: srv.snapshot()["queue"]["cancelled"] == depth - 1)
+        gate.let(1)
+        closer.join(timeout=10)
+        assert not closer.is_alive()
+        # the running batch drained, the queued ones were cancelled
+        assert futs[0].result(timeout=5)[0] == 0
+        for f in futs[1:]:
+            assert isinstance(f.exception(timeout=5), qv.ServerClosed)
+        assert len(eng.calls) == 1
+        snap = srv.snapshot()["serving"]
+        assert snap["completed"] == 1 and snap["failed"] == depth + 1
+        assert snap["held_open"] == 0
 
 
 class TestPipelineStages:
