@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import profiling
 from .ops import quant
 from .profiling import hot_path
 from .utils import CSRTopo, parse_size, reindex_feature
@@ -233,6 +234,47 @@ class Feature:
         self._log_cache_stats()
         return self
 
+    def from_tiers(self, device_part, host_part, feature_order=None):
+        """Construct from tiers that EXIST (as ``DistFeature.from_shards``
+        does from shards): ``device_part`` the hot rows, storage rows
+        ``[0, hot)``, already on the device (or None); ``host_part`` the
+        cold rows, storage rows ``[hot, total)``; ``feature_order`` the
+        node id -> storage row map (None: ids are storage rows; absent
+        an argument, ``csr_topo.feature_order`` where one is set). No
+        copy of the table is made: this is the constructor for a table
+        too large to pass through one host array (``from_cpu_tensor``'s
+        argument), each tier written where it lives by the loader. A
+        ``host_part`` that is a jax array in pinned host memory is
+        taken as the offload tier as it is; any other goes the way
+        ``from_cpu_tensor``'s cold part goes (numpy, pinned under
+        ``host_placement="offload"``, refused loudly with
+        ``allow_fallback=False`` where pinning is unusable). The tiers
+        are stored as given: narrow storage is the caller's
+        (``quant.quantize``), so a ``dtype_policy`` is refused."""
+        if any(self.dtype_policy.values()):
+            raise ValueError(
+                "from_tiers stores the tiers as given; hand it quantized "
+                "tiers (ops.quant.quantize) rather than a dtype_policy")
+        if feature_order is None and self.csr_topo is not None:
+            feature_order = self.csr_topo.feature_order
+        self.feature_order = None if feature_order is None else \
+            jnp.asarray(feature_order, dtype=jnp.int32)
+        self.device_part = device_part
+        self.cache_rows = 0 if device_part is None \
+            else int(quant.tier_rows(device_part))
+        leaves = jax.tree_util.tree_leaves(host_part)
+        pinned = bool(leaves) and all(
+            isinstance(l, jax.Array)
+            and l.sharding.memory_kind == "pinned_host" for l in leaves)
+        if pinned:
+            self._host_offload, self.host_part = host_part, None
+        else:
+            self.host_part = None if host_part is None else \
+                quant.tree_map_tier(np.asarray, host_part)
+            self._maybe_offload_host()
+        self._build_gather()
+        return self
+
     def _log_hot_plan(self, tensor, budget: int):
         """Log what the dtype policy buys: hot rows held by the budget
         and (with a csr_topo) the expected degree-mass hit-rate gain
@@ -422,6 +464,15 @@ class Feature:
         @hot_path
         def lookup_tiered_body(dev_part, host_part, ids, order,
                                masked=False, collector=None):
+            # ALL of the lookup is the frontier's row gather: the two
+            # tiers' reads carry their own scopes beneath this one, the
+            # translation, the compaction and the merge only this one
+            with profiling.scope(profiling.QT_GATHER):
+                return lookup_tiered_rows(dev_part, host_part, ids, order,
+                                          masked, collector)
+
+        def lookup_tiered_rows(dev_part, host_part, ids, order, masked,
+                               collector):
             # one dispatch for the WHOLE tiered lookup: hot rows from
             # the HBM cache, cold rows gathered by XLA directly from
             # the (pinned host) cold tier — no Python round trip, no
@@ -454,12 +505,12 @@ class Feature:
             def take_host(hids):
                 # named scope: XProf attributes cold-tier (pinned host)
                 # gather time to this stage, not one opaque jit blob
-                with jax.named_scope("qt_lookup_cold"):
+                with profiling.scope(profiling.QT_LOOKUP_COLD):
                     return quant.gather_rows(host_part,
                                              hids).astype(out_dt)
 
             def take_hot(hids):
-                with jax.named_scope("qt_lookup_hot"):
+                with profiling.scope(profiling.QT_LOOKUP_HOT):
                     return gather_cached(dev_part, hids).astype(out_dt)
 
             def finish(rows):
@@ -494,6 +545,17 @@ class Feature:
             cold_total = quant.tier_rows(host_part)
             cold_idx = jnp.clip(t - cache_rows, 0, max(cold_total - 1, 0))
             budget = _resolve_cold_budget(dedup_budget, cold_budget, n)
+
+            def count_overflow(also=True):
+                # the compaction's own test (``n_cold > budget`` below;
+                # padding counts as hot), taken OUTSIDE its lax.cond: the
+                # lookup that trips it reads all n slots from the host,
+                # not ``budget`` of them
+                if collector is not None:
+                    from .metrics import COLD_OVERFLOW
+                    collector.add(COLD_OVERFLOW,
+                                  (jnp.sum(~hot) > budget) & also)
+
             if dev_part is None:
                 if dedup and budget < n:
                     # no HBM cache: every slot is cold — dedup still
@@ -563,6 +625,7 @@ class Feature:
                 valid_pos = (ids_raw >= 0) if masked else None
                 uniq, inv, n_uniq = unique_within_budget(
                     t, budget, valid=valid_pos, collector=collector)
+                count_overflow(n_uniq > budget)
                 safe_u = jnp.clip(uniq, 0, total - 1)
                 hot_u = safe_u < cache_rows
                 hot_rows_u = take_hot(jnp.where(hot_u, safe_u, 0))
@@ -589,6 +652,7 @@ class Feature:
                     n_uniq > budget, lambda _: compacted_lookup(),
                     narrow_fn, None))
 
+            count_overflow()
             return finish(compacted_lookup())
 
         def lookup_tiered(dev_part, host_part, ids, order, masked=False,

@@ -103,8 +103,10 @@ FAULTS_INJECTED = 21  # faults the armed FaultPlan fired (process-wide)
 STAGING_RESTARTS = 22  # staging workers auto-replaced / shards retried
 LOCALITY_HIT_ROWS = 23   # frontier rows owned by the serving home partition
 LOCALITY_MISS_ROWS = 24  # frontier rows owned elsewhere (exchange-remote)
+COLD_OVERFLOW = 25    # tiered lookups whose cold count passed cold_budget
+#                       (filed with COLD_ROWS: the full host gather ran)
 
-NUM_COUNTERS = 25
+NUM_COUNTERS = 26
 
 #: slots merged with ``max`` across steps/shards; all others add
 MAX_SLOTS = (EXCH_BUCKET_MAX, EXCH_CAP, IO_DEPTH_PEAK)
@@ -129,6 +131,7 @@ SLOT_NAMES = {
     STAGING_RESTARTS: "staging_worker_restarts",
     LOCALITY_HIT_ROWS: "locality_hit_rows",
     LOCALITY_MISS_ROWS: "locality_miss_rows",
+    COLD_OVERFLOW: "cold_overflow",
 }
 
 _MAX_MASK_NP = np.zeros((NUM_COUNTERS,), bool)

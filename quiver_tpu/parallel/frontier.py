@@ -114,6 +114,59 @@ def dedup_feature_gather(feat, n_id: jax.Array,
                             narrow, None)
 
 
+def feature_splice(feature):
+    """Splice a ``Feature`` store's fused tiered lookup into a step
+    program (``build_train_step(gather=)``, ``build_serve_step(gather=)``;
+    ``ServeEngine`` calls it for a store it is given): returns
+    ``(feat_args, forder, gather)`` where ``feat_args`` is the
+    ``(device_part, host_tier)`` pytree the step passes through and
+    ``gather`` runs the store's own traceable lookup body (masked,
+    dedup_cold, quantized tiers — all its conventions) on it. A pure-HBM
+    store needs none: ``gather`` is None, ``feat_args`` its device part."""
+    from ..ops import quant
+    if feature.mmap_array is not None:
+        raise ValueError(
+            "a step program cannot fuse a disk/mmap-tier Feature store "
+            "(its cold reads are host-driven); splice a store whose "
+            "tiers are HBM/host arrays")
+    host = feature._host_offload
+    if host is None and feature.host_part is not None:
+        # numpy cold tier: commit once so the lookup fuses — a step
+        # cannot afford a per-batch host round trip. Commit to
+        # PINNED HOST memory (the store's own offload placement), not
+        # device HBM: the cold tier is cold precisely because it does
+        # not fit there. Loud jnp fallback only where host-offload is
+        # unusable (CPU: host and device memory are the same arena).
+        from ..utils.placement import pinned_put
+        devs = jax.devices()
+        dev = devs[feature.rank if feature.rank < len(devs) else 0]
+        leaves, tree = jax.tree_util.tree_flatten(feature.host_part)
+        got = pinned_put(leaves, dev, True, "the spliced cold tier",
+                         mesh=feature.mesh, usage="gather")
+        if got is not None:
+            host = jax.tree_util.tree_unflatten(tree, got)
+        else:
+            host = quant.tree_map_tier(jnp.asarray, feature.host_part)
+    if host is None:
+        # pure-HBM store: the default masked gather over the cache part
+        # IS the store's lookup (same translate + clip + mask semantics)
+        return feature.device_part, feature.feature_order, None
+    raw = feature._lookup_tiered_raw
+
+    def gather(feat_args, n_id, forder, collector=None):
+        dev, host_t = feat_args
+        if collector is None:
+            return raw(dev, host_t, n_id, forder, True)
+        rows, vec = raw(dev, host_t, n_id, forder, True, True)
+        collector.absorb(vec)
+        return rows
+    # for the fused walk: its kernel reads ``feat_args[0]``, the hot tier,
+    # and routes the picks beyond these rows back through ``gather``
+    gather.hot_rows = 0 if feature.device_part is None \
+        else quant.tier_rows(feature.device_part)
+    return (feature.device_part, host), feature.feature_order, gather
+
+
 # The walk's knobs: declared, defaulted and documented here and nowhere
 # else. A builder takes them as a ``**walk`` it does not open and names
 # (``takes``) the ones its step can honour.

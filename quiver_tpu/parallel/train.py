@@ -270,13 +270,21 @@ def _sharded_step(who: str, walk: Walk, loss, tx, mesh: Mesh, axis: str,
 def build_train_step(model, tx, sizes: Sequence[int], batch_size: int,
                      loss_fn: Callable = cross_entropy_logits,
                      donate: bool = True, collect_metrics: bool = False,
-                     **walk):
+                     gather: Callable | None = None, **walk):
     """Single-chip fused step:
     fn(state, feat, forder, indptr, indices, seeds, labels, key[,
     indices_rows]) -> (state, loss). ``feat`` may be a quantized store
     (``ops.quant.quantize(feat, "int8"|"bf16")``): dequant fuses into
-    the gather and the model consumes float activations unchanged."""
-    walk = Walk.of("build_train_step", ALL_KNOBS, sizes, walk)
+    the gather and the model consumes float activations unchanged.
+    ``gather`` overrides the whole gather callable, exactly as
+    ``build_serve_step``'s does (the protocol of ``parallel.frontier``;
+    it wins over ``dedup_gather``): ``frontier.feature_splice(store)``
+    gives ``(feat, forder, gather)`` of a ``Feature`` store, so a table
+    larger than the chip's memory trains through this step with its
+    cold rows read from pinned host memory inside the one program;
+    ``feat`` is then the ``(device_part, host_tier)`` pair."""
+    walk = Walk.of("build_train_step", ALL_KNOBS, sizes, walk,
+                   gather=gather)
 
     def step(state: TrainState, feat, forder, indptr, indices, seeds,
              labels, key, indices_rows=None):

@@ -26,11 +26,18 @@ scope = jax.named_scope
 # ``transpose(jvp(<scope>))``. ``qt_aggregate_dense`` sits inside
 # ``qt_aggregate`` and is there exactly when the mean ran as a reduce
 # over the fanout axis (models/sage.py): a choice made at trace time
-# leaves its record in the program's names.
+# leaves its record in the program's names. ``qt_lookup_hot`` /
+# ``qt_lookup_cold`` are the two tiers of a ``Feature`` store's fused
+# lookup (feature.py ``lookup_tiered_body``): the HBM cache's gather, and
+# the pinned-host tier's (the device's loop of row fetches out of host
+# memory); they sit beneath the ``qt_gather`` of a step over a spliced
+# tiered store, which covers all of the lookup.
 (QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
- QT_AGGREGATE, QT_AGGREGATE_DENSE, QT_EXCHANGE) = DEVICE_SCOPES = (
+ QT_AGGREGATE, QT_AGGREGATE_DENSE, QT_EXCHANGE, QT_LOOKUP_HOT,
+ QT_LOOKUP_COLD) = DEVICE_SCOPES = (
     "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
-    "qt_optimizer", "qt_aggregate", "qt_aggregate_dense", "qt_exchange")
+    "qt_optimizer", "qt_aggregate", "qt_aggregate_dense", "qt_exchange",
+    "qt_lookup_hot", "qt_lookup_cold")
 
 # the row-sharded store's lookup (comm.dist_lookup_local) stands where a
 # one-chip step has ``qt_gather``: ALL of it sits under ``qt_exchange``,

@@ -110,8 +110,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import faults, profiling, tracing
-from .parallel.frontier import (Walk, documented, layers_to_adjs, walk_doc,
-                                walk_frontier)
+from .parallel.frontier import (Walk, documented, feature_splice,
+                                layers_to_adjs, walk_doc, walk_frontier)
 from .profiling import hot_path
 # the typed request-failure vocabulary is shared with the RPC plane
 # (quiver_tpu.rpc defines it so the jax-free client can import it):
@@ -384,7 +384,7 @@ class ServeEngine:
         self._store = None
         if hasattr(feat, "lookup_tiered"):        # a Feature store
             self._store = feat
-            feat, forder, gather = _feature_gather(feat)
+            feat, forder, gather = feature_splice(feat)
         elif isinstance(feat, np.ndarray):
             feat = jnp.asarray(feat)
         self._feat = feat
@@ -458,7 +458,7 @@ class ServeEngine:
             raise ValueError(
                 "refresh_feature needs an engine built over a Feature "
                 "store (this one was built over a plain array)")
-        feat, forder, _ = _feature_gather(self._store)
+        feat, forder, _ = feature_splice(self._store)
 
         def sig(t):
             return [(tuple(l.shape), str(l.dtype))
@@ -472,55 +472,6 @@ class ServeEngine:
         self._forder = None if forder is None else \
             jnp.asarray(forder, jnp.int32)
         return self
-
-
-def _feature_gather(feature):
-    """Splice a ``Feature`` store's fused tiered lookup into the serve
-    program: returns ``(feat_args, forder, gather)`` where ``feat_args``
-    is the ``(device_part, host_tier)`` pytree the step passes through
-    and ``gather`` runs the store's own traceable lookup body (masked,
-    dedup_cold, quantized tiers — all its conventions) on it."""
-    from .ops import quant
-    if feature.mmap_array is not None:
-        raise ValueError(
-            "ServeEngine cannot fuse a disk/mmap-tier Feature store "
-            "(its cold reads are host-driven); serve from a store whose "
-            "tiers are HBM/host arrays")
-    host = feature._host_offload
-    if host is None and feature.host_part is not None:
-        # numpy cold tier: commit once so the lookup fuses — the serve
-        # path cannot afford a per-batch host round trip. Commit to
-        # PINNED HOST memory (the store's own offload placement), not
-        # device HBM: the cold tier is cold precisely because it does
-        # not fit there. Loud jnp fallback only where host-offload is
-        # unusable (CPU: host and device memory are the same arena).
-        from .utils.placement import pinned_put
-        devs = jax.devices()
-        dev = devs[feature.rank if feature.rank < len(devs) else 0]
-        leaves, tree = jax.tree_util.tree_flatten(feature.host_part)
-        got = pinned_put(leaves, dev, True, "the serving cold tier",
-                         mesh=feature.mesh, usage="gather")
-        if got is not None:
-            host = jax.tree_util.tree_unflatten(tree, got)
-        else:
-            host = quant.tree_map_tier(jnp.asarray, feature.host_part)
-    if host is None:
-        # pure-HBM store: the default masked gather over the cache part
-        # IS the store's lookup (same translate + clip + mask semantics)
-        return feature.device_part, feature.feature_order, None
-    raw = feature._lookup_tiered_raw
-
-    def gather(feat_args, n_id, forder, collector=None):
-        dev, host_t = feat_args
-        if collector is None:
-            return raw(dev, host_t, n_id, forder, True)
-        rows, vec = raw(dev, host_t, n_id, forder, True, True)
-        collector.absorb(vec)
-        return rows
-    # for the fused walk: its kernel reads ``feat_args[0]``, the hot tier,
-    # and routes the picks beyond these rows back through ``gather``
-    gather.hot_rows = quant.tier_rows(feature.device_part)
-    return (feature.device_part, host), feature.feature_order, gather
 
 
 # -- sharded serving: one partitioned store under the whole fleet ------------

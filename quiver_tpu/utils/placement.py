@@ -9,7 +9,8 @@ logger (allow_fallback=True) or raise.
 jax types the memory space of every value: one op takes operands of ONE
 space, so a device-space index vector cannot gather from a host-space
 table ("memory_space of all inputs passed to `gather` must be the
-same"). ``take_rows`` is the gather that respects that, and what the
+same"). ``take_rows`` is the read that respects that (the device fetches
+the rows itself, one slice of the host buffer a row), and what the
 usability probe runs.
 """
 
@@ -17,24 +18,52 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.compute_on import compute_on
 
 from ..debug import log as _log
+
+
+# rows of a pinned-host table in flight at once (the loop's unroll)
+_ROWS_IN_FLIGHT = 8
+_LANES, _SUBLANES = 128, 8
 
 
 def take_rows(table, ids):
     """``jnp.take(table, ids, axis=0)`` for a table in either memory
     space; ``ids`` must be in range. Rows of a pinned-host table are
-    gathered ON the host (ids moved there, the gather run as host
-    compute) and only the gathered rows cross to the device — the form
-    the v5e accepts; the same gather left to the device aborts XLA's
-    host offloader."""
+    fetched BY THE DEVICE, one DMA a row (a ``dynamic_slice`` of the host
+    buffer, the form XLA's host offloader moves itself), ``ids.shape[0]``
+    of them in a loop: time and host traffic go with the rows asked for.
+    The same gather run as host compute (``compute_on("device_host")``,
+    what this was until PR 32) converts the WHOLE table for the host's
+    program on every call: 2.3-2.7 s and ~2.9 GB of host memory a call
+    out of a 7.1 GB tier, against 0.2 s here, and a step loop dies of it
+    (PERF.md section 6, PR 32); left to the device as one ``gather`` it
+    aborts the offloader. The chip's compiler refuses a one-row piece of
+    a table whose rows are not a whole number of 128-lane vectors, so
+    such a table gives each DMA the aligned group of 8 rows that holds
+    the row asked for, and the row is picked out of it on the device."""
     if jax.typeof(table).memory_space != jax.memory.Space.Host:
         return jnp.take(table, ids, axis=0)
-    ids = jax.device_put(ids, jax.memory.Space.Host)
-    with compute_on("device_host"):
-        rows = table.at[ids].get(mode="promise_in_bounds")
-    return jax.device_put(rows, jax.memory.Space.Device)
+    n, tail = table.shape[0], table.shape[1:]
+    zeros = (0,) * len(tail)
+    k = ids.shape[0]
+    ids = ids.astype(jnp.int32)
+    group = 1 if tail and tail[-1] % _LANES == 0 else min(_SUBLANES, n)
+    first = jnp.minimum(ids // group * group, n - group)
+
+    def fetch(i, out):
+        at = jax.lax.dynamic_index_in_dim(first, i, keepdims=False)
+        piece = jax.device_put(
+            jax.lax.dynamic_slice(table, (at,) + zeros, (group,) + tail),
+            jax.memory.Space.Device)
+        return jax.lax.dynamic_update_slice(out, piece, (i * group,) + zeros)
+
+    rows = jax.lax.fori_loop(
+        0, k, fetch, jnp.zeros((k * group,) + tail, table.dtype),
+        unroll=_ROWS_IN_FLIGHT)
+    if group == 1:
+        return rows
+    return rows.reshape((k, group) + tail)[jnp.arange(k), ids - first]
 
 # (usage, platform, mesh?) -> refusal; a capability PROBE, not a
 # platform allowlist: whether a backend that ACCEPTS the pinned_host

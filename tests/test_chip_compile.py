@@ -263,3 +263,28 @@ def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
     text = compiled.as_text()
     assert f"f32[{chips},{frontier},{dim}]" not in text
     assert f"f32[{chips},{cell['exchange_cap']},{dim}]" in text
+
+
+@pytest.mark.parametrize("rows,dim,dtype", [
+    (13_882_494, 128, jnp.float32),      # the benchmark's 7.1 GB cold tier
+    (1_959_224, 100, jnp.float32),       # products' width: not 128 lanes
+    (1_000_000, 128, jnp.int8)])         # a quantized tier's codes
+def test_take_rows_out_of_pinned_host_memory(topo, shape_on_chip, rows, dim,
+                                             dtype):
+    """``placement.take_rows`` over a table in the host's pinned memory is
+    a loop of device-initiated DMAs out of host memory space ``S(5)``, no
+    host compute, and the chip's compiler takes it at these widths
+    (``shape_on_chip`` is asked for because it turns the cache off)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from quiver_tpu.utils.placement import take_rows
+    # a NamedSharding carries its memory kind into the traced type
+    mesh = Mesh(np.array([topo.devices[0]]), ("chip",))
+    table = jax.ShapeDtypeStruct(
+        (rows, dim), dtype,
+        sharding=NamedSharding(mesh, P(), memory_kind="pinned_host"))
+    ids = jax.ShapeDtypeStruct((131_072,), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    text = jax.jit(take_rows).lower(table, ids).compile().as_text()
+    assert "S(5)" in text and "dynamic-slice-start" in text
+    assert "HostExecute" not in text

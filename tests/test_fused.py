@@ -311,7 +311,8 @@ class TestFusedServeStep:
         # lookup — logits match a step that runs the WHOLE frontier
         # through the tiered lookup
         from quiver_tpu.feature import Feature
-        from quiver_tpu.serving import ServeEngine, _feature_gather
+        from quiver_tpu.parallel.frontier import feature_splice
+        from quiver_tpu.serving import ServeEngine
         from quiver_tpu.utils import CSRTopo
         indptr, indices, n = graph
         cap = 8
@@ -330,7 +331,7 @@ class TestFusedServeStep:
         _, logits = eng._steps[0](state.params, jax.random.key(0),
                                   eng._feat, eng._forder, eng._indptr,
                                   eng._indices, jnp.asarray(seeds))
-        _, _, store_gather = _feature_gather(store)
+        _, _, store_gather = feature_splice(store)
         hot = eng._feat[0]
 
         def oracle(params, key, feat_args, forder, seeds):
@@ -569,7 +570,8 @@ class TestFusedMultihop:
         # multi-hop ladder over a hot+cold Feature store: the FINAL
         # frontier's cold slots come from the store's tiered lookup
         from quiver_tpu.feature import Feature
-        from quiver_tpu.serving import ServeEngine, _feature_gather
+        from quiver_tpu.parallel.frontier import feature_splice
+        from quiver_tpu.serving import ServeEngine
         from quiver_tpu.utils import CSRTopo
         indptr, indices, n = graph
         cap, sizes = 8, [3, 2]
@@ -589,7 +591,7 @@ class TestFusedMultihop:
         _, logits = eng._steps[0](state.params, jax.random.key(0),
                                   eng._feat, eng._forder, eng._indptr,
                                   eng._indices, jnp.asarray(seeds))
-        _, _, store_gather = _feature_gather(store)
+        _, _, store_gather = feature_splice(store)
         hot = eng._feat[0]
 
         def oracle(params, key, feat_args, forder, seeds):

@@ -12,7 +12,8 @@ The scope contracts:
    and the forward holds no scatter. The dist step has the exchange
    where the others have ``qt_gather``: every op of its lookup lies
    under ``qt_exchange``, each stage beneath it, and nothing under
-   ``qt_gather``.
+   ``qt_gather``. A step over a spliced tiered ``Feature`` store has
+   ``qt_lookup_hot`` / ``qt_lookup_cold`` beneath its ``qt_gather``.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -37,7 +38,12 @@ from quiver_tpu.profiling import hot_path
 from quiver_tpu.serving import build_serve_step
 
 N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
-TRAIN_SCOPES = set(profiling.DEVICE_SCOPES) - {profiling.QT_EXCHANGE}
+LOOKUP_SCOPES = {profiling.QT_LOOKUP_HOT, profiling.QT_LOOKUP_COLD}
+TRAIN_SCOPES = set(profiling.DEVICE_SCOPES) - {profiling.QT_EXCHANGE} \
+    - LOOKUP_SCOPES
+# a step over a spliced tiered store has the two tiers' reads beneath its
+# ``qt_gather``
+TIERED_SCOPES = TRAIN_SCOPES | LOOKUP_SCOPES
 DIST_SCOPES = (TRAIN_SCOPES - {profiling.QT_GATHER}) | {
     profiling.QT_EXCHANGE} | set(profiling.EXCHANGE_STAGES)
 SERVE_SCOPES = {profiling.QT_DRAW, profiling.QT_COMPACT, profiling.QT_GATHER,
@@ -86,6 +92,21 @@ def _lower(builder: str, w):
                               donate=False)
         return fn.lower(w["state"], *graph, jnp.arange(BATCH, dtype=jnp.int32),
                         jnp.zeros((BATCH,), jnp.int32), w["key"])
+    if builder == "tiered":
+        import quiver_tpu as qv
+        from quiver_tpu.parallel.frontier import feature_splice
+        rows = np.asarray(w["feat"])
+        store = qv.Feature(host_placement="offload", allow_fallback=False,
+                           cold_budget=16).from_tiers(
+            jnp.asarray(rows[:N // 2]), jax.device_put(
+                rows[N // 2:], jax.sharding.SingleDeviceSharding(
+                    jax.devices()[0], memory_kind="pinned_host")))
+        feat, forder, gather = feature_splice(store)
+        fn = build_train_step(w["model"], w["tx"], SIZES, BATCH,
+                              gather=gather, donate=False)
+        return fn.lower(w["state"], feat, forder, w["indptr"], w["indices"],
+                        jnp.arange(BATCH, dtype=jnp.int32),
+                        jnp.zeros((BATCH,), jnp.int32), w["key"])
     if builder == "e2e":
         mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
         step = build_e2e_train_step(w["model"], w["tx"], SIZES, BATCH, mesh,
@@ -115,7 +136,7 @@ def _op_names(lowered):
 
 @pytest.mark.parametrize("builder,scopes", [
     ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES),
-    ("dist", DIST_SCOPES)])
+    ("dist", DIST_SCOPES), ("tiered", TIERED_SCOPES)])
 def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
     names = _op_names(_lower(builder, world))
     for scope in scopes:
@@ -127,6 +148,11 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         # the aggregation ran as the dense reduce, all of it
         if profiling.QT_AGGREGATE in n:
             assert "qt_aggregate/qt_aggregate_dense/" in n, n
+    for n in names:
+        # the tiers' reads lie beneath the store's gather and nowhere else
+        if any(s in n for s in LOOKUP_SCOPES):
+            assert builder == "tiered"
+            assert re.search(r"qt_gather\)?/.*qt_lookup_(hot|cold)\)?/", n), n
     hops = {m.group(0) for n in names
             for m in [re.search(r"qt_sample_hop\d", n)] if m}
     assert hops == {f"qt_sample_hop{i}" for i in range(len(SIZES))}
@@ -166,7 +192,7 @@ def test_the_exchange_scopes_cover_the_lookup(world):
                         for n in rows)
 
 
-@pytest.mark.parametrize("builder", ["train", "serve", "dist"])
+@pytest.mark.parametrize("builder", ["train", "serve", "dist", "tiered"])
 def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     named = _lower(builder, world).as_text()
     monkeypatch.setattr(profiling, "scope",

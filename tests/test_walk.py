@@ -3,6 +3,8 @@ for all seven step builders, and the stage called by hand, with a
 builder's own key fold, returns the sample and the rows that builder's
 step used."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -313,3 +315,62 @@ def test_the_stage_by_hand_returns_what_the_step_used(w, which, method):
         assert len({_bits(v) for v in per_shard}) == HOSTS
         want = sum(per_shard) / np.float32(HOSTS)
         assert _bits(loss) == _bits(want)
+
+
+def test_serve_and_train_reach_a_feature_store_through_one_splice(
+        w, monkeypatch):
+    """``parallel.frontier.feature_splice`` is the ONE function that turns
+    a ``Feature`` store into ``(feat_args, forder, gather)``: the engine
+    calls it from there, and the same gather under ``build_train_step``
+    and ``build_serve_step`` hands the model the rows the stage reads by
+    hand through it, which are the table's."""
+    from quiver_tpu import serving
+    from quiver_tpu.parallel import frontier
+    from quiver_tpu.parallel.train import TrainState
+    assert serving.feature_splice is frontier.feature_splice
+    assert not hasattr(serving, "_feature_gather")
+    rng = np.random.default_rng(9)
+    order = rng.permutation(N).astype(np.int32)
+    storage = np.empty_like(w.feat_np)
+    storage[order] = w.feat_np
+    dev = jax.devices()[0]
+    store = qv.Feature(host_placement="offload", allow_fallback=False,
+                       cold_budget=32).from_tiers(
+        jnp.asarray(storage[:N // 2]),
+        jax.device_put(storage[N // 2:], jax.sharding.SingleDeviceSharding(
+            dev, memory_kind="pinned_host")), order)
+    calls = []
+    real = frontier.feature_splice
+    monkeypatch.setattr(serving, "feature_splice",
+                        lambda f: calls.append(f) or real(f))
+    serving.ServeEngine(w.model, w.state.params, (w.indptr, w.indices),
+                        store, [SIZES], BATCH)
+    assert calls == [store]
+    feat, forder, gather = real(store)
+    tx = optax.sgd(0.1)
+    params = {"w": jnp.float32(1.0)}
+    state = TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+    key, one = jax.random.key(17), w.seeds[:BATCH]
+    walk = Walk.of("by-hand", ALL_KNOBS, SIZES, {}, gather=gather)
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def by_hand(seeds, key, train):
+        n_id, x, layers = walk_frontier(walk, feat, forder, w.indptr,
+                                        w.indices, seeds, key)
+        adjs = layers_to_adjs(layers, BATCH, SIZES)
+        rngs = {"dropout": jax.random.fold_in(key, 1000)} if train else None
+        return n_id, x, Probe.apply(params, x, adjs, rngs=rngs)[0, 0]
+
+    train = build_train_step(Probe, tx, SIZES, BATCH, gather=gather,
+                             loss_fn=_probe_loss, donate=False)
+    _, loss = train(state, feat, forder, w.indptr, w.indices, one,
+                    w.labels[one], key)
+    n_id, x, want = by_hand(one, key, True)
+    np.testing.assert_array_equal(
+        np.asarray(x), np.asarray(masked_feature_gather(w.feat, n_id)))
+    assert _bits(loss) == _bits(want)
+    serve = build_serve_step(Probe, SIZES, BATCH, gather=gather)
+    _, sub = jax.random.split(key)
+    _, logits = serve(params, jnp.copy(key), feat, forder, w.indptr,
+                      w.indices, one)
+    assert _bits(logits[0, 0]) == _bits(by_hand(one, sub, False)[2])
