@@ -484,9 +484,11 @@ class Feature:
             # count (hub repeats in a multi-hop frontier collapse to
             # one host read each): cold positions are compacted (rank +
             # sort, the sample_layer_exact_wide hub-budget pattern) and
-            # only a static ``budget`` of host rows is gathered — the
-            # reference's UVA kernel likewise touches only the rows it
-            # needs (shard_tensor.cu.hpp:49-58). A batch whose cold
+            # at most a static ``budget`` of host rows is gathered: the
+            # budget sizes the block, the cold count is what a step
+            # fetches out of a pinned tier (``take_rows``' ``count``) —
+            # the reference's UVA kernel likewise touches only the rows
+            # it needs (shard_tensor.cu.hpp:49-58). A batch whose cold
             # count exceeds the budget falls back via ``lax.cond`` to
             # the full-batch host gather — correct in every case, only
             # the traffic bound degrades.
@@ -502,12 +504,12 @@ class Feature:
                 quant.tier_dtype(p) for p in (dev_part, host_part)
                 if p is not None])
 
-            def take_host(hids):
+            def take_host(hids, count=None):
                 # named scope: XProf attributes cold-tier (pinned host)
                 # gather time to this stage, not one opaque jit blob
                 with profiling.scope(profiling.QT_LOOKUP_COLD):
-                    return quant.gather_rows(host_part,
-                                             hids).astype(out_dt)
+                    return quant.gather_rows(host_part, hids,
+                                             count).astype(out_dt)
 
             def take_hot(hids):
                 with profiling.scope(profiling.QT_LOOKUP_HOT):
@@ -600,9 +602,10 @@ class Feature:
                                  jnp.iinfo(jnp.int32).max)
                 _, cpos = jax.lax.sort((okey, iota), num_keys=1)
                 cpos = cpos[:budget]    # cold positions (garbage past n_cold)
-                c_valid = (jnp.arange(budget, dtype=jnp.int32)
-                           < jnp.minimum(n_cold, budget))
-                rows = take_host(cold_idx[cpos])            # [budget, dim]
+                n_fetch = jnp.minimum(n_cold, budget)
+                c_valid = jnp.arange(budget, dtype=jnp.int32) < n_fetch
+                # [budget, dim]; a host tier fetches the first n_fetch
+                rows = take_host(cold_idx[cpos], n_fetch)
                 tgt = jnp.where(c_valid, cpos, n)           # n = drop slot
                 narrow = hot_rows.at[tgt].set(rows, mode="drop")
                 return jax.lax.cond(n_cold > budget, _full,

@@ -202,18 +202,20 @@ def tier_key(t):
     return (tuple(t.shape), str(t.dtype))
 
 
-def gather_rows(t, ids):
+def gather_rows(t, ids, count=None):
     """``jnp.take(t, ids, axis=0)`` with dequantization FUSED: a
     quantized tier reads ``[k, d]`` int8 + two ``[k, 1]`` sidecars and
     converts only the gathered rows — the whole-table width never moves.
     ``ids`` must already be clipped in-range (callers own masking).
-    A tier in pinned host memory is gathered there and its narrow rows
-    moved to the device before the decode (``placement.take_rows``)."""
+    A tier in pinned host memory has its narrow rows fetched by the
+    device before the decode (``placement.take_rows``, which says what
+    ``count`` is: how many of ``ids`` a host tier's loops fetch; rows at
+    or past it must not be read)."""
     if not is_quantized(t):
-        return take_rows(t, ids)
-    code = take_rows(t.data, ids)
-    scale = take_rows(t.scale, ids)
-    zero = take_rows(t.zero, ids)
+        return take_rows(t, ids, count)
+    code = take_rows(t.data, ids, count)
+    scale = take_rows(t.scale, ids, count)
+    zero = take_rows(t.zero, ids, count)
     return code.astype(scale.dtype) * scale + zero
 
 
@@ -253,10 +255,12 @@ def default_cold_budget(n: int) -> int:
 def dedup_rows_read(ids, budget: int | None = None,
                     cold_count: int | None = None) -> int:
     """Analytic mirror of the fused dedup tiered lookup's host-row
-    count for one batch (``lookup_tiered``'s branch structure):
-    ``budget`` rows on the narrow path; on unique-overflow the lookup
-    falls back to the COLD-COMPACTION path, which still reads only
-    ``budget`` rows unless the batch's raw cold-slot count
+    count for one batch (``lookup_tiered``'s branch structure), as an
+    UPPER bound: ``budget`` rows on the narrow path; on unique-overflow
+    the lookup falls back to the COLD-COMPACTION path, which reads at
+    most ``budget`` rows (over a pinned-host tier it fetches the cold
+    count, rounded up to a turn of ``placement.take_rows``' loop, and
+    not the budget) unless the batch's raw cold-slot count
     (``cold_count``; translated ids >= cache_rows) overflows too — only
     then does the full batch move. ``cold_count=None`` assumes every
     slot may be cold (the conservative upper bound). The benches'

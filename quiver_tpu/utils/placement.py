@@ -22,48 +22,75 @@ import jax.numpy as jnp
 from ..debug import log as _log
 
 
-# rows of a pinned-host table in flight at once (the loop's unroll)
-_ROWS_IN_FLIGHT = 8
+# rows of a pinned-host table a turn of the loop fetches (all in flight
+# at once, their ids read and their block written together)
+_ROWS_IN_FLIGHT = 32
 _LANES, _SUBLANES = 128, 8
 
 
-def take_rows(table, ids):
+def take_rows(table, ids, count=None):
     """``jnp.take(table, ids, axis=0)`` for a table in either memory
     space; ``ids`` must be in range. Rows of a pinned-host table are
     fetched BY THE DEVICE, one DMA a row (a ``dynamic_slice`` of the host
-    buffer, the form XLA's host offloader moves itself), ``ids.shape[0]``
-    of them in a loop: time and host traffic go with the rows asked for.
+    buffer, the form XLA's host offloader moves itself), in a loop
+    (``_fetch_rows``): time and host traffic go with the rows fetched.
+    ``count`` (a traced int32 scalar, ``0 <= count <= ids.shape[0]``)
+    says how many of ``ids`` matter: the loop then stops after the turn
+    that holds row ``count - 1``, rows past that turn are zeros, and the
+    caller must not read rows at or past ``count``. Without it every id
+    is fetched; a table in device memory is one ``jnp.take`` whatever
+    ``count`` is.
     The same gather run as host compute (``compute_on("device_host")``,
     what this was until PR 32) converts the WHOLE table for the host's
     program on every call: 2.3-2.7 s and ~2.9 GB of host memory a call
-    out of a 7.1 GB tier, against 0.2 s here, and a step loop dies of it
-    (PERF.md section 6, PR 32); left to the device as one ``gather`` it
+    out of a 7.1 GB tier, against 0.065 s here, and a step loop dies of
+    it (PERF.md section 6, PR 32); left to the device as one ``gather`` it
     aborts the offloader. The chip's compiler refuses a one-row piece of
     a table whose rows are not a whole number of 128-lane vectors, so
     such a table gives each DMA the aligned group of 8 rows that holds
     the row asked for, and the row is picked out of it on the device."""
     if jax.typeof(table).memory_space != jax.memory.Space.Host:
         return jnp.take(table, ids, axis=0)
+    return _fetch_rows(table, ids, count)
+
+
+def _fetch_rows(table, ids, count=None):
+    """The loop of ``take_rows`` (which says what ``count`` is), over a
+    table of any memory space. A turn handles ``_ROWS_IN_FLIGHT`` rows:
+    ONE read of their ids, a fetch each, ONE write of their block (for a
+    128-lane table whole ``[8, 128]`` tiles). With ``count`` it runs
+    ``ceil(count / _ROWS_IN_FLIGHT)`` turns, else all of them; ids and
+    block are padded to a whole number of turns. No index is wrapped
+    (``allow_negative_indices=False``: every one is in range by
+    contract, and the three scalar ops of the wrap cost a row more than
+    its fetch does; PERF.md section 6, PR 33)."""
     n, tail = table.shape[0], table.shape[1:]
     zeros = (0,) * len(tail)
-    k = ids.shape[0]
+    k, per = ids.shape[0], _ROWS_IN_FLIGHT
+    turns = -(-k // per)
     ids = ids.astype(jnp.int32)
     group = 1 if tail and tail[-1] % _LANES == 0 else min(_SUBLANES, n)
     first = jnp.minimum(ids // group * group, n - group)
+    padded = jnp.pad(first, (0, turns * per - k))
 
-    def fetch(i, out):
-        at = jax.lax.dynamic_index_in_dim(first, i, keepdims=False)
-        piece = jax.device_put(
-            jax.lax.dynamic_slice(table, (at,) + zeros, (group,) + tail),
-            jax.memory.Space.Device)
-        return jax.lax.dynamic_update_slice(out, piece, (i * group,) + zeros)
+    def turn(t, out):
+        at = jax.lax.dynamic_slice(padded, (t * per,), (per,),
+                                   allow_negative_indices=False)
+        pieces = [jax.device_put(jax.lax.dynamic_slice(
+            table, (at[j],) + zeros, (group,) + tail,
+            allow_negative_indices=False), jax.memory.Space.Device)
+            for j in range(per)]
+        return jax.lax.dynamic_update_slice(
+            out, jnp.concatenate(pieces), (t * per * group,) + zeros,
+            allow_negative_indices=False)
 
     rows = jax.lax.fori_loop(
-        0, k, fetch, jnp.zeros((k * group,) + tail, table.dtype),
-        unroll=_ROWS_IN_FLIGHT)
+        0, turns if count is None else (count + per - 1) // per, turn,
+        jnp.zeros((turns * per * group,) + tail, table.dtype))
     if group == 1:
-        return rows
-    return rows.reshape((k, group) + tail)[jnp.arange(k), ids - first]
+        return rows[:k]
+    return rows.reshape((turns * per, group) + tail)[jnp.arange(k),
+                                                     ids - first]
 
 # (usage, platform, mesh?) -> refusal; a capability PROBE, not a
 # platform allowlist: whether a backend that ACCEPTS the pinned_host

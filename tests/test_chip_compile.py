@@ -177,6 +177,16 @@ def test_gather_rows(graph, shape_on_chip, dim):
             feat, seeds) == 1
 
 
+def _sage_state(dims, tx):
+    """GraphSAGE's train state, by the shapes of its parameter tree."""
+    from quiver_tpu.parallel.train import TrainState
+    params = {"params": {f"conv{i}": {
+        "lin_root": {"kernel": jnp.zeros((a, b)), "bias": jnp.zeros((b,))},
+        "lin_nbr": {"kernel": jnp.zeros((a, b))}}
+        for i, (a, b) in enumerate(zip(dims[:-1], dims[1:]))}}
+    return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+
 def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
     """The row-sharded papers100M step (``build_dist_train_step`` at the
     shapes of the cell ``papers100m-sage-train-dist4``: 55.5 M nodes, 808 M
@@ -194,7 +204,6 @@ def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
 
     from quiver_tpu.models import GraphSAGE
     from quiver_tpu.parallel.dist import build_dist_train_step
-    from quiver_tpu.parallel.train import TrainState
     from quiver_tpu.pyg.sage_sampler import layer_shapes
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
@@ -222,18 +231,9 @@ def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
 
     dims = [dim] + [cfg["hidden_dim"]] * (cfg["num_layers"] - 1) \
         + [cfg["num_classes"]]
-
-    def make_state():
-        # GraphSAGE's parameter tree, by its shapes
-        params = {"params": {f"conv{i}": {
-            "lin_root": {"kernel": jnp.zeros((a, b)), "bias": jnp.zeros((b,))},
-            "lin_nbr": {"kernel": jnp.zeros((a, b))}}
-            for i, (a, b) in enumerate(zip(dims[:-1], dims[1:]))}}
-        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
-
     key = jax.eval_shape(lambda: jax.random.key(0))
     state = jax.tree.map(lambda a: arr(a.shape, a.dtype, P()),
-                         jax.eval_shape(make_state))
+                         jax.eval_shape(lambda: _sage_state(dims, tx)))
     step = build_dist_train_step(model, tx, sizes, batch, mesh,
                                  rows_per_host=rows,
                                  exchange_cap=cell["exchange_cap"],
@@ -265,26 +265,108 @@ def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
     assert f"f32[{chips},{cell['exchange_cap']},{dim}]" in text
 
 
+def _loop_bounds(text):
+    """What each ``while`` of a compiled program compares its counter
+    with: the operands of its condition's ROOT."""
+    import re
+    bounds = []
+    for cond in re.findall(r" while\(.*?condition=(%[\w.]+)", text):
+        body = text[text.index(f"\n{cond} "):]
+        root = re.search(r"ROOT [^\n]*compare\(([^)]*)\)",
+                         body[:body.index("\n}")])
+        bounds.append(root.group(1))
+    return bounds
+
+
+@pytest.mark.parametrize("counted", [False, True], ids=["all-ids", "count"])
 @pytest.mark.parametrize("rows,dim,dtype", [
     (13_882_494, 128, jnp.float32),      # the benchmark's 7.1 GB cold tier
     (1_959_224, 100, jnp.float32),       # products' width: not 128 lanes
     (1_000_000, 128, jnp.int8)])         # a quantized tier's codes
 def test_take_rows_out_of_pinned_host_memory(topo, shape_on_chip, rows, dim,
-                                             dtype):
+                                             dtype, counted):
     """``placement.take_rows`` over a table in the host's pinned memory is
     a loop of device-initiated DMAs out of host memory space ``S(5)``, no
     host compute, and the chip's compiler takes it at these widths
-    (``shape_on_chip`` is asked for because it turns the cache off)."""
+    (``shape_on_chip`` is asked for because it turns the cache off). With
+    a traced ``count`` the loop's bound is a value the program computes;
+    over all of ``ids`` its ``while`` compares with a constant."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from quiver_tpu.utils.placement import take_rows
+    from quiver_tpu.utils.placement import _ROWS_IN_FLIGHT, take_rows
     # a NamedSharding carries its memory kind into the traced type
     mesh = Mesh(np.array([topo.devices[0]]), ("chip",))
+    on_chip = NamedSharding(mesh, P())
     table = jax.ShapeDtypeStruct(
         (rows, dim), dtype,
         sharding=NamedSharding(mesh, P(), memory_kind="pinned_host"))
-    ids = jax.ShapeDtypeStruct((131_072,), jnp.int32,
-                               sharding=NamedSharding(mesh, P()))
-    text = jax.jit(take_rows).lower(table, ids).compile().as_text()
-    assert "S(5)" in text and "dynamic-slice-start" in text
+    args = (table, jax.ShapeDtypeStruct((131_072,), jnp.int32,
+                                        sharding=on_chip))
+    if counted:
+        args += (jax.ShapeDtypeStruct((), jnp.int32, sharding=on_chip),)
+    text = jax.jit(take_rows).lower(*args).compile().as_text()
+    assert "S(5)" in text and "HostExecute" not in text
+    # a turn's fetches, all started before the first is waited for
+    assert text.count("dynamic-slice-start(") == _ROWS_IN_FLIGHT
+    bounds = _loop_bounds(text)
+    assert bounds and counted != any("constant" in b for b in bounds), bounds
+
+
+def test_the_tiered_step_fetches_its_cold_rows_under_qt_lookup_cold(
+        topo, shape_on_chip):
+    """A train step over a spliced tiered store, compiled for the described
+    chip (a small world: names only; ``shape_on_chip`` is asked for because
+    it turns the cache off): the cold tier's row fetches are
+    ``dynamic-slice-start`` / ``-done`` pairs out of host memory in scope
+    ``qt_lookup_cold`` beneath ``qt_gather``, which is where the
+    benchmark's reducers look for them, and no host compute."""
+    import re
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import quiver_tpu as qv
+    from quiver_tpu import profiling
+    from quiver_tpu.models import GraphSAGE
+    from quiver_tpu.parallel.frontier import feature_splice
+    from quiver_tpu.parallel.train import build_train_step
+    from quiver_tpu.utils.placement import _ROWS_IN_FLIGHT
+
+    nodes, hot, dim, edges, batch, sizes = 4096, 2048, 128, 20_000, 8, [3, 2]
+    # the store is built over real (CPU) tiers of these shapes: the
+    # splice closes over its sizes, the step is lowered over shapes
+    store = qv.Feature(host_placement="offload", allow_fallback=False,
+                       cold_budget=32, dedup_cold=False).from_tiers(
+        jnp.zeros((hot, dim)), jax.device_put(
+            np.zeros((nodes - hot, dim), np.float32),
+            SingleDeviceSharding(jax.devices()[0],
+                                 memory_kind="pinned_host")),
+        np.arange(nodes, dtype=np.int32))
+    _, _, gather = feature_splice(store)
+    model = GraphSAGE(hidden_dim=16, out_dim=4, num_layers=len(sizes))
+    tx = optax.adam(1e-3)
+    step = build_train_step(model, tx, sizes, batch, gather=gather,
+                            collect_metrics=True)
+    mesh = Mesh(np.array([topo.devices[0]]), ("chip",))
+    arr = lambda shape, dtype, kind=None: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(), memory_kind=kind))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = jax.tree.map(
+        lambda a: arr(a.shape, a.dtype),
+        jax.eval_shape(lambda: _sage_state([dim, 16, 4], tx)))
+    text = step.jitted_fns[-1].lower(
+        state, (arr((hot, dim), jnp.float32),
+                arr((nodes - hot, dim), jnp.float32, "pinned_host")),
+        arr((nodes,), jnp.int32), arr((nodes + 1,), jnp.int32),
+        arr((edges,), jnp.int32), arr((batch,), jnp.int32),
+        arr((batch,), jnp.int32), arr(key.shape, key.dtype)).compile().as_text()
     assert "HostExecute" not in text
+    fetches = [line for line in text.splitlines()
+               if re.search(r" dynamic-slice-(start|done)\(", line)]
+    # the narrow read's turn; the overflow branch's loop has its own
+    assert len(fetches) >= 2 * _ROWS_IN_FLIGHT
+    for line in fetches:
+        name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(profiling.QT_GATHER + r"\)?/.*"
+                         + profiling.QT_LOOKUP_COLD + r"\)?/", name), name

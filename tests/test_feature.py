@@ -995,6 +995,55 @@ class TestOffloadHostTier:
         with pytest.raises(ValueError, match="host_placement"):
             qv.Feature(host_placement="gpu")
 
+    # the loop of placement.take_rows, run over an ordinary array whose
+    # ids are two whole turns and five rows of a third
+    @pytest.mark.parametrize("count", [
+        None, 0, 1, 7, 8, 9, "turn-1", "turn", "turn+1", "k-1", "k"])
+    @pytest.mark.parametrize("dtype", [np.float32, np.int8])
+    @pytest.mark.parametrize("dim", [128, 100],
+                             ids=["a-row-a-fetch", "a-group-of-8-a-fetch"])
+    def test_the_row_loop_fetches_count_rows(self, dim, dtype, count):
+        from quiver_tpu.utils.placement import _ROWS_IN_FLIGHT, _fetch_rows
+        rng = np.random.default_rng(33)
+        table = (rng.standard_normal((157, dim)) * 50).astype(dtype)
+        k = 2 * _ROWS_IN_FLIGHT + 5
+        ids = rng.integers(0, 157, k)
+        ids[:3] = [156, 0, 155]     # the last group of eight, clamped
+        turn = _ROWS_IN_FLIGHT
+        count = {"turn-1": turn - 1, "turn": turn, "turn+1": turn + 1,
+                 "k-1": k - 1, "k": k}.get(count, count)
+        if count is None:
+            got = jax.jit(_fetch_rows)(jnp.asarray(table), jnp.asarray(ids))
+            count = k
+        else:
+            got = jax.jit(_fetch_rows)(jnp.asarray(table), jnp.asarray(ids),
+                                       jnp.int32(count))
+        got = np.asarray(got)
+        assert got.shape == (k, dim) and got.dtype == dtype
+        np.testing.assert_array_equal(got[:count], table[ids[:count]])
+        # no turn past the one that holds row count - 1 ran
+        assert not got[-(-count // turn) * turn:].any()
+
+    @pytest.mark.parametrize("count", [None, 5, 40])
+    def test_a_quantized_pinned_tier_fetches_count_rows(self, count):
+        # three loops (codes, scale, zero) under the one count, out of
+        # pinned host memory, decoded as the same tier on the device
+        from quiver_tpu.ops import quant
+        rng = np.random.default_rng(34)
+        qt = quant.quantize(
+            rng.standard_normal((40, 128)).astype(np.float32), "int8")
+        pinned = jax.sharding.SingleDeviceSharding(
+            jax.devices()[0], memory_kind="pinned_host")
+        tier = quant.tree_map_tier(lambda a: jax.device_put(a, pinned), qt)
+        ids = rng.integers(0, 40, 40)
+        got = jax.jit(quant.gather_rows)(
+            tier, jnp.asarray(ids),
+            None if count is None else jnp.int32(count))
+        want = np.asarray(jax.jit(quant.gather_rows)(
+            quant.tree_map_tier(jnp.asarray, qt), jnp.asarray(ids)))
+        count = 40 if count is None else count
+        np.testing.assert_array_equal(np.asarray(got)[:count], want[:count])
+
 
 class TestCacheStatsLog:
     def test_expected_hit_rate_logged(self, rng, small_graph, caplog):
