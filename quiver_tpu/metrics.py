@@ -105,8 +105,10 @@ LOCALITY_HIT_ROWS = 23   # frontier rows owned by the serving home partition
 LOCALITY_MISS_ROWS = 24  # frontier rows owned elsewhere (exchange-remote)
 COLD_OVERFLOW = 25    # tiered lookups whose cold count passed cold_budget
 #                       (filed with COLD_ROWS: the full host gather ran)
+EDGE_VALID = 26       # valid sampled edge slots, all hops of the walk
+EDGE_CAP = 27         # static edge-slot capacity of those hops
 
-NUM_COUNTERS = 26
+NUM_COUNTERS = 28
 
 #: slots merged with ``max`` across steps/shards; all others add
 MAX_SLOTS = (EXCH_BUCKET_MAX, EXCH_CAP, IO_DEPTH_PEAK)
@@ -132,6 +134,7 @@ SLOT_NAMES = {
     LOCALITY_HIT_ROWS: "locality_hit_rows",
     LOCALITY_MISS_ROWS: "locality_miss_rows",
     COLD_OVERFLOW: "cold_overflow",
+    EDGE_VALID: "edge_valid", EDGE_CAP: "edge_cap",
 }
 
 _MAX_MASK_NP = np.zeros((NUM_COUNTERS,), bool)

@@ -63,6 +63,9 @@ class LayerSample(NamedTuple):
     e_id:     [num_seeds*k] global edge id of each sampled edge (-1
               fill), present only when edge-id tracking was requested
               (``sample_multihop(..., eid=...)``); None otherwise
+    seed_count: [] number of valid seeds; they hold the local ids
+              ``[0, seed_count)`` (see ``compact_layer``), so a target
+              slot ``t`` of this hop is a real node iff ``t < seed_count``
     """
 
     n_id: jax.Array
@@ -71,6 +74,7 @@ class LayerSample(NamedTuple):
     col: jax.Array
     edge_count: jax.Array
     e_id: jax.Array | None = None
+    seed_count: jax.Array | None = None
 
 
 def _fisher_yates_rows(key: jax.Array, deg: jax.Array, k: int) -> jax.Array:
@@ -868,7 +872,8 @@ def compact_layer(seeds: jax.Array, nbrs: jax.Array,
     row = jnp.where(nbr_valid, seed_local, -1)
     edge_count = jnp.sum(nbr_valid).astype(jnp.int32)
     return LayerSample(n_id=n_id, n_count=n_count, row=row, col=col,
-                       edge_count=edge_count)
+                       edge_count=edge_count,
+                       seed_count=jnp.sum(seeds >= 0).astype(jnp.int32))
 
 
 def sample_prob_step(indptr: jax.Array, indices: jax.Array,

@@ -203,11 +203,22 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
               layer = layer._replace(e_id=jnp.where(flat >= 0, ids, -1))
         layers.append(layer)
         cur = layer.n_id
-    if collector is not None:
-        from ..metrics import FRONTIER_CAP, FRONTIER_VALID
-        collector.add(FRONTIER_VALID, jnp.sum(cur >= 0))
-        collector.add(FRONTIER_CAP, int(cur.shape[0]))
+    count_walk(collector, cur, layers)
     return cur, layers
+
+
+def count_walk(collector, n_id, layers) -> None:
+    """What a walk counts into a ``metrics.Collector`` (None: nothing):
+    the final frontier's valid slots against its static cap, and the
+    hops' valid edge slots against theirs: what share of a model's
+    per-row and per-edge work is padding."""
+    if collector is None:
+        return
+    from ..metrics import EDGE_CAP, EDGE_VALID, FRONTIER_CAP, FRONTIER_VALID
+    collector.add(FRONTIER_VALID, jnp.sum(n_id >= 0))
+    collector.add(FRONTIER_CAP, int(n_id.shape[0]))
+    collector.add(EDGE_VALID, sum(l.edge_count for l in layers))
+    collector.add(EDGE_CAP, sum(int(l.col.shape[0]) for l in layers))
 
 
 def sample_multihop_dedup(indptr: jax.Array, indices: jax.Array,

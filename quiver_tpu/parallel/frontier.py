@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from .. import profiling
 from ..comm import default_exchange_cap
-from ..ops.sample_multihop import sample_multihop
+from ..ops.sample_multihop import count_walk, sample_multihop
 from ..profiling import hot_path
 from ..pyg.sage_sampler import Adj, layer_shapes
 
@@ -41,14 +41,18 @@ def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
     with -1 at the tail only; hops >= 1 always are). A valid seed's local
     id is then its position, so edge slot ``e`` targets ``e // fanout``
     or nothing, which is what each ``Adj.fanout`` set here states. A
-    caller that cannot promise it builds its ``Adj``s without a fanout."""
+    caller that cannot promise it builds its ``Adj``s without a fanout.
+    Each ``Adj`` also states how many of its target slots hold a node
+    (``valid_targets``: the hop's count of valid seeds), for every step
+    builder alike."""
     shapes = layer_shapes(batch_size, sizes)
     adjs = []
     for layer, shape in zip(layers, shapes):
         adjs.append(Adj(edge_index=jnp.stack([layer.col, layer.row]),
                         e_id=layer.e_id,
                         size=(shape.n_id_cap, shape.num_seeds),
-                        mask=layer.col >= 0, fanout=shape.fanout))
+                        mask=layer.col >= 0, fanout=shape.fanout,
+                        valid_targets=layer.seed_count))
     return adjs[::-1]
 
 
@@ -62,8 +66,11 @@ def masked_feature_gather(feat, n_id: jax.Array,
     plain array or a quantized store (``ops.quant`` — e.g.
     ``quant.quantize(feat, "int8")``): dequantization fuses into the
     gather, so the step reads narrow rows + sidecars and the model
-    consumes float activations unchanged. ``collector`` is the gather
-    protocol's (one tier: nothing to count)."""
+    consumes float activations unchanged. A table stored in a float
+    narrower than float32 (float16, bfloat16) is read as stored and the
+    gathered block converted to float32 HERE, once and exactly, before the
+    mask: the store's dtype decides, a float32 table is untouched.
+    ``collector`` is the gather protocol's (one tier: nothing to count)."""
     from ..ops import quant
     with profiling.scope(profiling.QT_GATHER):
         ids = n_id
@@ -71,6 +78,8 @@ def masked_feature_gather(feat, n_id: jax.Array,
             ids = feature_order[jnp.clip(n_id, 0)]
         safe = jnp.clip(ids, 0, quant.tier_rows(feat) - 1)
         x = quant.gather_rows(feat, safe)
+        if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize < 4:
+            x = x.astype(jnp.float32)
         return x * (n_id >= 0).astype(x.dtype)[:, None]
 
 
@@ -331,13 +340,6 @@ class Walk:
                 "hop)")
 
 
-def _count_frontier(collector, n_id) -> None:
-    if collector is not None:
-        from ..metrics import FRONTIER_CAP, FRONTIER_VALID
-        collector.add(FRONTIER_VALID, jnp.sum(n_id >= 0))
-        collector.add(FRONTIER_CAP, int(n_id.shape[0]))
-
-
 @hot_path
 def walk_frontier(walk: Walk, feat, forder, indptr, indices, seeds, key,
                   indices_rows=None, collector=None, rows_collector=None):
@@ -381,7 +383,7 @@ def walk_frontier(walk: Walk, feat, forder, indptr, indices, seeds, key,
         n_id, layers = fused_sample_multihop(indptr, padded, seeds,
                                              walk.sizes, key,
                                              row_cap=walk.row_cap)
-        _count_frontier(collector, n_id)
+        count_walk(collector, n_id, layers)
         return n_id, walk.gather(feat, n_id, forder,
                                  collector=rows_collector), layers
     # the leaf hop samples AND gathers in one kernel; ``x`` is
@@ -391,7 +393,7 @@ def walk_frontier(walk: Walk, feat, forder, indptr, indices, seeds, key,
         indptr, padded, seeds, feat[0] if tiered else feat,
         list(walk.sizes), key, row_cap=walk.row_cap, feature_order=forder,
         hot_rows=walk.hot_rows)
-    _count_frontier(collector, n_id)
+    count_walk(collector, n_id, layers)
     if tiered:
         # cold fix-up: the kernel zeroed every frontier slot whose
         # translated row falls outside the hot tier; those slots — and

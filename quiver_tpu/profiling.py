@@ -31,13 +31,23 @@ scope = jax.named_scope
 # lookup (feature.py ``lookup_tiered_body``): the HBM cache's gather, and
 # the pinned-host tier's (the device's loop of row fetches out of host
 # memory); they sit beneath the ``qt_gather`` of a step over a spliced
-# tiered store, which covers all of the lookup.
+# tiered store, which covers all of the lookup. ``qt_project``,
+# ``qt_attention`` and ``qt_norm`` sit beneath ``qt_forward`` in the
+# attention model (models/gat.py, models/mag.py): every product with a
+# weight matrix (the layer's shared projection, the skip, the head); the
+# logits, the softmax over a target's edges and the weighted sum; the
+# batch norm over the valid rows. ``qt_attention_slots`` sits inside
+# ``qt_attention`` exactly when the softmax ran over the slot axis of an
+# ``Adj`` that states its ``fanout`` (as ``qt_aggregate_dense`` does for
+# the mean).
 (QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
  QT_AGGREGATE, QT_AGGREGATE_DENSE, QT_EXCHANGE, QT_LOOKUP_HOT,
- QT_LOOKUP_COLD) = DEVICE_SCOPES = (
+ QT_LOOKUP_COLD, QT_PROJECT, QT_ATTENTION, QT_ATTENTION_SLOTS,
+ QT_NORM) = DEVICE_SCOPES = (
     "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
     "qt_optimizer", "qt_aggregate", "qt_aggregate_dense", "qt_exchange",
-    "qt_lookup_hot", "qt_lookup_cold")
+    "qt_lookup_hot", "qt_lookup_cold", "qt_project", "qt_attention",
+    "qt_attention_slots", "qt_norm")
 
 # the row-sharded store's lookup (comm.dist_lookup_local) stands where a
 # one-chip step has ``qt_gather``: ALL of it sits under ``qt_exchange``,

@@ -58,18 +58,37 @@ class Adj:
                 then reduce over the fanout axis without reading row 1
                 (``models.sage.masked_mean_aggregate``). Set where the
                 producer guarantees it (``parallel.train.layers_to_adjs``).
+    valid_targets: [] int32, how many of the ``size[1]`` target slots
+                hold a node. Valid targets come first (a hop's valid
+                seeds keep the local ids ``[0, count)``), so target ``t``
+                is real iff ``t < valid_targets``; ``target_mask()`` says
+                it row by row. A real target may have no valid edge (an
+                isolated node): edge validity does not tell the two
+                apart, which is why a model that takes statistics over a
+                batch's rows, or gives a target an edge of its own, reads
+                this. ``None`` promises nothing: every target slot is
+                then taken for a node.
 
     Supports PyG-style destructuring: ``edge_index, e_id, size = adj``.
     """
 
-    __slots__ = ("edge_index", "e_id", "size", "mask", "fanout")
+    __slots__ = ("edge_index", "e_id", "size", "mask", "fanout",
+                 "valid_targets")
 
-    def __init__(self, edge_index, e_id, size, mask=None, fanout=None):
+    def __init__(self, edge_index, e_id, size, mask=None, fanout=None,
+                 valid_targets=None):
         self.edge_index = edge_index
         self.e_id = e_id
         self.size = tuple(size)
         self.mask = mask if mask is not None else edge_index[0] >= 0
         self.fanout = fanout
+        self.valid_targets = valid_targets
+
+    def target_mask(self):
+        """``[size[1]]`` bool: which target slots hold a node."""
+        if self.valid_targets is None:
+            return jnp.ones((self.size[1],), bool)
+        return jnp.arange(self.size[1], dtype=jnp.int32) < self.valid_targets
 
     def __iter__(self):
         return iter((self.edge_index, self.e_id, self.size))
@@ -78,13 +97,13 @@ class Adj:
         return self
 
     def tree_flatten(self):
-        return ((self.edge_index, self.e_id, self.mask),
+        return ((self.edge_index, self.e_id, self.mask, self.valid_targets),
                 (self.size, self.fanout))
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
         size, fanout = aux
-        return cls(leaves[0], leaves[1], size, leaves[2], fanout)
+        return cls(leaves[0], leaves[1], size, leaves[2], fanout, leaves[3])
 
 
 class _LayerShape(NamedTuple):
@@ -499,7 +518,8 @@ class GraphSageSampler:
             adjs.append(Adj(edge_index=edge_index,
                             e_id=layer.e_id,
                             size=(shape.n_id_cap, shape.num_seeds),
-                            mask=layer.col >= 0))
+                            mask=layer.col >= 0,
+                            valid_targets=layer.seed_count))
         return n_id, bs, adjs[::-1]
 
     def _sample_cpu(self, seeds, bs):

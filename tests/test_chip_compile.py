@@ -370,3 +370,42 @@ def test_the_tiered_step_fetches_its_cold_rows_under_qt_lookup_cold(
         name = re.search(r'op_name="([^"]*)"', line).group(1)
         assert re.search(profiling.QT_GATHER + r"\)?/.*"
                          + profiling.QT_LOOKUP_COLD + r"\)?/", name), name
+
+
+def test_the_attention_step_fits_beside_a_float16_table(topo, shape_on_chip):
+    """The MAG240M cell's first layer at its shapes (``chipbench/configs/
+    mag240m-gat-1of32.json``: a frontier of 425,984 rows of 768 float16
+    out of a 3.8 M-row table, 26,624 targets x 15 slots, four heads of
+    256): the chip's compiler takes a float16 table as it is, and the
+    attention's forward and backward pass fit beside it: the per-slot
+    rows (``[15, 26624, 1024]`` float32, 1.6 GB a block) are held three
+    or four times over at the most."""
+    from quiver_tpu.models.gat import gat_attention
+    from quiver_tpu.parallel.frontier import masked_feature_gather
+    from quiver_tpu.pyg.sage_sampler import Adj
+    nodes, width, hidden, heads = 3_804_740, 768, 1024, 4
+    targets, k = 26_624, 15
+    sources = targets * (k + 1)
+
+    def loss(w, a_src, a_dst, feat, n_id, src, count):
+        x = masked_feature_gather(feat, n_id)
+        assert x.dtype == jnp.float32
+        adj = Adj(jnp.stack([src, src]), None, (sources, targets),
+                  fanout=k, valid_targets=count)
+        return (gat_attention(x @ w, a_src, a_dst, adj) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape_on_chip((width, hidden), jnp.float32),
+        shape_on_chip((heads, hidden // heads), jnp.float32),
+        shape_on_chip((heads, hidden // heads), jnp.float32),
+        shape_on_chip((nodes, width), jnp.float16),
+        shape_on_chip((sources,), jnp.int32),
+        shape_on_chip((targets * k,), jnp.int32),
+        shape_on_chip((), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    block = 4 * k * targets * hidden
+    assert memory.argument_size_in_bytes > 2 * nodes * width   # the table
+    # x, H and dH (1.3 + 1.7 + 1.7 GB) and two or three per-slot blocks
+    assert memory.temp_size_in_bytes < 6 * block, memory
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        < 15.75e9

@@ -151,6 +151,26 @@ class TestMAG240MGNN:
         out = model.apply(params, x, adjs)
         assert out.shape == (adjs[-1].size[1], 5)
         assert bool(jnp.isfinite(out[:8]).all())
+        tree = params["params"]
+        if variant == "gat":
+            # the published layer: ONE projection shared by sources and
+            # targets, a bias, a skip Linear beside it
+            assert set(tree["conv0"]) == {"lin", "att_src", "att_dst",
+                                          "bias"}
+            assert tree["conv0"]["lin"]["kernel"].shape == (12, 16)
+            assert tree["conv0"]["att_src"].shape == (4, 4)
+            assert tree["skip0"]["kernel"].shape == (12, 16)
+        # batch norms over the block's valid rows (no LayerNorm stand-in):
+        # the sampler's device path states them, and what the padding
+        # holds changes no seed's output
+        assert set(tree["norm0"]) == set(tree["mlp_norm"]) == {"scale",
+                                                                 "bias"}
+        assert all(a.valid_targets is not None for a in adjs)
+        assert int(adjs[-1].valid_targets) == 8
+        x2 = jnp.where((n_id >= 0)[:, None], x, 77.0)
+        out2 = model.apply(params, x2, adjs)
+        np.testing.assert_allclose(np.asarray(out[:8]), np.asarray(out2[:8]),
+                                   rtol=1e-5, atol=1e-6)
 
 
 class TestHeteroPerfModes:

@@ -14,6 +14,13 @@ The scope contracts:
    under ``qt_exchange``, each stage beneath it, and nothing under
    ``qt_gather``. A step over a spliced tiered ``Feature`` store has
    ``qt_lookup_hot`` / ``qt_lookup_cold`` beneath its ``qt_gather``.
+   A step over the attention model (``MAG240MGNN(model="gat")``) has
+   ``qt_project``, ``qt_attention`` and ``qt_norm`` beneath its
+   ``qt_forward``, forward and backward, and no ``qt_aggregate``; every
+   builder's layers state their fanout, so all of ``qt_attention`` lies
+   under ``qt_attention_slots`` and holds no scatter in the forward
+   pass; over an ``Adj`` that states no fanout the same model leaves no
+   ``qt_attention_slots`` in its names.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -29,7 +36,7 @@ import pytest
 from jax.sharding import Mesh
 
 from quiver_tpu import profiling
-from quiver_tpu.models import GraphSAGE
+from quiver_tpu.models import MAG240MGNN, GraphSAGE
 from quiver_tpu.ops.sample_multihop import sample_multihop
 from quiver_tpu.parallel.train import (build_e2e_train_step, build_train_step,
                                        init_state, layers_to_adjs,
@@ -39,8 +46,13 @@ from quiver_tpu.serving import build_serve_step
 
 N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
 LOOKUP_SCOPES = {profiling.QT_LOOKUP_HOT, profiling.QT_LOOKUP_COLD}
+# the attention model's own, beneath ``qt_forward``
+MODEL_SCOPES = {profiling.QT_PROJECT, profiling.QT_ATTENTION,
+                profiling.QT_ATTENTION_SLOTS, profiling.QT_NORM}
 TRAIN_SCOPES = set(profiling.DEVICE_SCOPES) - {profiling.QT_EXCHANGE} \
-    - LOOKUP_SCOPES
+    - LOOKUP_SCOPES - MODEL_SCOPES
+GAT_SCOPES = (TRAIN_SCOPES - {profiling.QT_AGGREGATE,
+                              profiling.QT_AGGREGATE_DENSE}) | MODEL_SCOPES
 # a step over a spliced tiered store has the two tiers' reads beneath its
 # ``qt_gather``
 TIERED_SCOPES = TRAIN_SCOPES | LOOKUP_SCOPES
@@ -79,8 +91,13 @@ def world():
                                    seeds_dense=True)
     state = init_state(model, tx, masked_feature_gather(feat, n_id),
                        layers_to_adjs(layers, BATCH, SIZES), key)
+    gat = MAG240MGNN(model="gat", hidden_dim=8, out_dim=4,
+                     num_layers=len(SIZES), heads=2)
+    gat_state = init_state(gat, tx, masked_feature_gather(feat, n_id),
+                           layers_to_adjs(layers, BATCH, SIZES), key)
     return {"model": model, "tx": tx, "state": state, "feat": feat,
-            "indptr": indptr, "indices": indices, "key": key}
+            "indptr": indptr, "indices": indices, "key": key, "gat": gat,
+            "gat_state": gat_state, "n_id": n_id, "layers": layers}
 
 
 def _lower(builder: str, w):
@@ -91,6 +108,11 @@ def _lower(builder: str, w):
         fn = build_train_step(w["model"], w["tx"], SIZES, BATCH,
                               donate=False)
         return fn.lower(w["state"], *graph, jnp.arange(BATCH, dtype=jnp.int32),
+                        jnp.zeros((BATCH,), jnp.int32), w["key"])
+    if builder == "gat":
+        fn = build_train_step(w["gat"], w["tx"], SIZES, BATCH, donate=False)
+        return fn.lower(w["gat_state"], w["feat"].astype(jnp.float16),
+                        *graph[1:], jnp.arange(BATCH, dtype=jnp.int32),
                         jnp.zeros((BATCH,), jnp.int32), w["key"])
     if builder == "tiered":
         import quiver_tpu as qv
@@ -136,11 +158,24 @@ def _op_names(lowered):
 
 @pytest.mark.parametrize("builder,scopes", [
     ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES),
-    ("dist", DIST_SCOPES), ("tiered", TIERED_SCOPES)])
+    ("dist", DIST_SCOPES), ("tiered", TIERED_SCOPES), ("gat", GAT_SCOPES)])
 def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
     names = _op_names(_lower(builder, world))
     for scope in scopes:
         assert any(scope in n for n in names), scope
+    for n in names:
+        # the attention model's scopes lie beneath the forward pass of
+        # the step that runs it and nowhere else
+        if any(s in n for s in MODEL_SCOPES):
+            assert builder == "gat" and "qt_forward" in n, n
+        # the softmax ran over the slot axis, all of it
+        if profiling.QT_ATTENTION in n:
+            assert "qt_attention/qt_attention_slots/" in n, n
+    if builder == "gat":
+        assert not any(profiling.QT_AGGREGATE in n for n in names)
+        for scope in (profiling.QT_PROJECT, profiling.QT_ATTENTION,
+                      profiling.QT_NORM):
+            assert any(scope in n and "transpose(" in n for n in names), scope
     # draw and compact are the two halves of a hop, never on their own
     for n in names:
         if profiling.QT_DRAW in n or profiling.QT_COMPACT in n:
@@ -166,6 +201,36 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         assert back and fwd
         # the optimizer is outside value_and_grad: no jvp around it
         assert any(re.search(r"(^|/)qt_optimizer/", n) for n in names)
+
+
+def test_which_attention_path_ran_is_in_the_names(world):
+    """The attention's path is chosen at trace time from what the ``Adj``
+    states and recorded as a name: the slot form (``qt_attention_slots``,
+    no scatter in the forward pass) where it states its fanout, the
+    segment form (scatters, no such name) where it does not."""
+    from quiver_tpu.pyg.sage_sampler import Adj
+    w = world
+    adjs = layers_to_adjs(w["layers"], BATCH, SIZES)
+    loose = [Adj(a.edge_index, a.e_id, a.size, a.mask, None, a.valid_targets)
+             for a in adjs]
+    x = masked_feature_gather(w["feat"], w["n_id"])
+    text = {}
+    for name, blocks in (("slots", adjs), ("segments", loose)):
+        text[name] = jax.jit(w["gat"].apply).lower(
+            w["gat_state"].params, x, blocks).compile().as_text()
+    slots, segments = (_names_and_ops(text[k]) for k in ("slots", "segments"))
+    assert any("qt_attention/qt_attention_slots/" in n for n, _ in slots)
+    assert not any("scatter" in op for n, op in slots
+                   if profiling.QT_ATTENTION in n)
+    assert not any(profiling.QT_ATTENTION_SLOTS in n for n, _ in segments)
+    assert any("scatter" in op for n, op in segments
+               if profiling.QT_ATTENTION in n)
+
+
+def _names_and_ops(text):
+    """``(op_name, instruction text)`` of a compiled program's lines."""
+    return [(m.group(1), line) for line in text.splitlines()
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
 
 
 def test_the_exchange_scopes_cover_the_lookup(world):

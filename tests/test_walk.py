@@ -1,7 +1,8 @@
 """The neighbourhood stage (``parallel.frontier``): one constructor refuses
-for all seven step builders, and the stage called by hand, with a
+for all seven step builders, the stage called by hand, with a
 builder's own key fold, returns the sample and the rows that builder's
-step used."""
+step used, and the blocks every builder hands its model state how many
+of their target slots hold a node."""
 
 import functools
 
@@ -315,6 +316,73 @@ def test_the_stage_by_hand_returns_what_the_step_used(w, which, method):
         assert len({_bits(v) for v in per_shard}) == HOSTS
         want = sum(per_shard) / np.float32(HOSTS)
         assert _bits(loss) == _bits(want)
+
+
+class Tell:
+    """In a model's place: its "logits" spell out the ``valid_targets``
+    of the blocks it was handed, outermost hop first, 12 bits a hop."""
+
+    @staticmethod
+    def apply(params, x, adjs, train=False, rngs=None):
+        told = sum(adj.valid_targets.astype(jnp.float32) * 4096.0 ** j
+                   for j, adj in enumerate(adjs))
+        return jnp.zeros((x.shape[0], CLASSES)) + told + 0.0 * params["w"]
+
+
+@pytest.mark.parametrize("which", BUILDERS)
+def test_every_builders_blocks_state_their_valid_targets(w, which):
+    """Whatever builds the step, the model's ``Adj``s say how many target
+    slots hold a node: the batch's valid seeds for the batch's own hop (two
+    of each batch's eight slots are -1 here), the valid entries of the
+    frontier of the hop before for the others; valid targets come first,
+    so ``target_mask()`` is that many ones."""
+    from quiver_tpu.parallel.train import TrainState
+    key = jax.random.key(23)
+    serve = which in ("serve", "sharded-serve")
+    tx = optax.sgd(0.1)
+    params = {"w": jnp.float32(1.0)}
+    state = TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+    tail = jnp.asarray([BATCH - 2, BATCH - 1, 2 * BATCH - 2, 2 * BATCH - 1])
+    told = type("ToldWorld", (), dict(
+        vars(w), model=Tell, tx=tx, state=state,
+        seeds=w.seeds.at[tail].set(-1)))
+    kw = {} if serve else {"loss_fn": _probe_loss}
+    if which in ("train", "e2e", "dist", "split"):
+        kw["donate"] = False
+    step = _build(told, which, **kw)
+
+    def want(seeds, key):
+        _, layers = sample_multihop(w.indptr, w.indices, seeds, SIZES, key,
+                                    seeds_dense=True)
+        counts = [int((seeds >= 0).sum())] + [int(l.n_count)
+                                              for l in layers[:-1]]
+        assert counts[0] == BATCH - 2 and counts[1] > counts[0]
+        return counts[::-1]                      # outermost hop first
+
+    spell = lambda counts: np.float32(sum(c * 4096.0 ** j
+                                          for j, c in enumerate(counts)))
+    one = told.seeds[:BATCH]
+    if which == "split":
+        _, adjs = _call(told, which, step, key)
+        assert [int(a.valid_targets) for a in adjs] == want(one, key)
+        for a in adjs:
+            mask = np.asarray(a.target_mask())
+            assert mask.shape == (a.size[1],)
+            assert mask.sum() == int(a.valid_targets)
+            assert mask[:int(a.valid_targets)].all()
+    elif serve:
+        out = _call(told, which, step, jnp.copy(key))
+        sub = jax.random.split(key)[1]
+        assert _bits(out[1][0, 0]) == _bits(spell(want(one, sub)))
+    elif which in ("train", "gspmd"):
+        _, loss = _call(told, which, step, key)
+        assert _bits(loss) == _bits(spell(want(one, key)))
+    else:
+        _, loss = _call(told, which, step, key)
+        shards = [spell(want(told.seeds[h * BATCH:(h + 1) * BATCH],
+                             jax.random.fold_in(key, h)))
+                  for h in range(HOSTS)]
+        assert _bits(loss) == _bits(sum(shards) / np.float32(HOSTS))
 
 
 def test_serve_and_train_reach_a_feature_store_through_one_splice(
