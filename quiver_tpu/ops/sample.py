@@ -8,7 +8,11 @@ This is the TPU-native redesign of the reference's CUDA sampling stack:
   ``min(degree, k)`` distinct neighbors uniformly without replacement — but
   expressed as a vectorized partial Fisher–Yates over a fixed ``(bs, k)``
   output with a validity count, because XLA requires static shapes (the
-  reference allocates a dynamic ``tot``-sized buffer instead).
+  reference allocates a dynamic ``tot``-sized buffer instead). The draw
+  keeps a ``[k, bs]`` log of its own swaps and reads it back by compares
+  and selects over the k columns, never by a gather: on this chip a
+  gather costs by the index, so a hop's only gathers are the data's own
+  (two reads of ``indptr``, one of ``indices``).
 
 - ``compact_layer``  <- the device ordered hashtable + prefix-sum compaction
   (``reindex_single``/``FillWithDuplicates``, quiver_sample.cu:202-357,
@@ -83,22 +87,35 @@ def _fisher_yates_rows(key: jax.Array, deg: jax.Array, k: int) -> jax.Array:
     Vectorized partial Fisher–Yates: a virtual array ``a = [0..deg)`` per
     row; step i swaps ``a[i]`` with ``a[j]``, ``j ~ U[i, deg)``, and emits
     ``a[j]``. Only the <=k written entries are materialized (a tiny write
-    log), so cost is O(bs * k^2) independent of degree — the same trick the
+    log), so the work is independent of degree — the same trick the
     reference's warp reservoir achieves with atomics, minus the atomics.
+
+    The log is held ``[k, bs]``: a column (one step's writes) is a dense
+    ``[bs]`` vector with the rows along the lanes. The virtual read
+    ``a[x]`` walks the k columns with a compare and a select each, later
+    columns overwriting earlier ones (last write wins); an unwritten
+    column holds position -1 and never matches an ``x >= 0``. No gather:
+    XLA's TPU gather costs by the index, not by the byte (~7.5 ns an
+    index even out of this small log), so fetching ONE of a row's k
+    logged values that way cost half of what fetching a neighbour out of
+    ``indices`` does, 2k times a hop: 16 ms of an 89 ms papers100M
+    train step. The 4k compares and selects of a step fuse with its
+    ``randint`` into one elementwise pass over ``[bs]`` vectors (0.06 ms
+    at ``bs`` = 180,224). k is static and small (the fanout); unrolling
+    the k steps as well buys nothing at run time and costs the compiler
+    up to 40 s at k = 25, so they stay a ``scan``.
 
     Returns positions [bs, k]; entries at slot i >= min(deg, k) are
     meaningless and must be masked by the caller.
     """
     bs = deg.shape[0]
-    steps = jnp.arange(k, dtype=jnp.int32)
 
     def lookup(pos_log, val_log, x):
         # virtual read a[x]: last write wins; unwritten -> x itself
-        match = pos_log == x[:, None]                       # [bs, k]
-        last = jnp.max(jnp.where(match, steps[None, :], -1), axis=1)
-        logged = jnp.take_along_axis(
-            val_log, jnp.maximum(last, 0)[:, None], axis=1)[:, 0]
-        return jnp.where(last >= 0, logged, x)
+        res = x
+        for c in range(k):
+            res = jnp.where(pos_log[c] == x, val_log[c], res)
+        return res
 
     def body(carry, xs):
         pos_log, val_log = carry
@@ -108,13 +125,14 @@ def _fisher_yates_rows(key: jax.Array, deg: jax.Array, k: int) -> jax.Array:
         a_j = lookup(pos_log, val_log, j)
         a_i = lookup(pos_log, val_log, jnp.full((bs,), i, dtype=deg.dtype))
         pos_log = jax.lax.dynamic_update_slice_in_dim(
-            pos_log, j[:, None], i, axis=1)
+            pos_log, j[None, :], i, axis=0)
         val_log = jax.lax.dynamic_update_slice_in_dim(
-            val_log, a_i[:, None], i, axis=1)
+            val_log, a_i[None, :], i, axis=0)
         return (pos_log, val_log), a_j
 
-    pos_log = jnp.full((bs, k), -1, dtype=deg.dtype)
-    val_log = jnp.zeros((bs, k), dtype=deg.dtype)
+    pos_log = jnp.full((k, bs), -1, dtype=deg.dtype)
+    val_log = jnp.zeros((k, bs), dtype=deg.dtype)
+    steps = jnp.arange(k, dtype=jnp.int32)
     keys = jax.random.split(key, k)
     (_, _), picks = jax.lax.scan(
         body, (pos_log, val_log), (steps, keys))
@@ -487,8 +505,9 @@ def sample_layer_window(indptr: jax.Array, indices_rows: jax.Array,
     same fetch cost. Any mixing reshuffle (sort or butterfly) serves.
 
     Cost: the same one (overlap layout, ``stride=width``) or two (pair
-    layout) row gathers per seed as rotation, plus an O(bs*k^2)
-    Fisher-Yates position draw — the price of subset independence.
+    layout) row gathers per seed as rotation, plus the gather-free
+    Fisher-Yates position draw (``_fisher_yates_rows``: 4k selects a
+    step over ``[bs]`` vectors) — the price of subset independence.
     (A [bs, window] uniform-priorities + top_k draw gives the same
     distribution but costs a 256-wide sort per seed; measured 3x
     slower end-to-end on v5e, so the write-log form is the one used.)

@@ -487,12 +487,16 @@ class TestCleanPass:
         # output: the entry traces sync-free, its census enumerates
         # both storage variants, and the FUSED hop moves ZERO gather
         # indexing bytes while the split train step's frontier-id
-        # round trip prices at 1984 B — the exact traffic the kernel
+        # round trip prices at 1280 B — the exact traffic the kernel
         # deletes (+ 4 B that are not frontier ids: optax 0.2.6's
         # cross-entropy squeezes its take_along_axis result through a
         # one-element constant-index gather). It was 2080 B while the
-        # mean's backward gathered by target id: 24 slots x 4 B that
-        # the dense reduce's transpose, a broadcast, does not read
+        # mean's backward gathered by target id (24 slots x 4 B that
+        # the dense reduce's transpose, a broadcast, does not read),
+        # and 1984 B while the draw read its own write log by gather
+        # (2k reads a hop of one index a seed: 2*3*8 + 2*2*32 = 176
+        # indices x 4 B that the selects over the log's columns do not
+        # make)
         specs = registry.build_entry_specs("fused_hot_hop")
         assert len(specs) == specs[0].census.count() == 2
         from quiver_tpu.analysis.costmodel import cost_of
@@ -500,7 +504,7 @@ class TestCleanPass:
         assert fused_cost.gather_index_bytes == 0
         assert fused_cost.gather_bytes > 0       # real DMA traffic
         split_cost = cost_of(registry.build_entry("train_step"))
-        assert split_cost.gather_index_bytes == 1984 + 4
+        assert split_cost.gather_index_bytes == 1280 + 4
         findings = run_rules(specs[0], ("no_host_sync",))
         assert [str(f) for f in findings] == []
 
@@ -509,7 +513,7 @@ class TestCleanPass:
         # hops, leaf sample+gather, compaction, reassembly — still
         # models ZERO gather indexing bytes (in-kernel indptr at every
         # hop; the split train step's per-hop frontier round trips
-        # price at 1984 B), while the leaf's tier DMAs show up as real
+        # price at 1280 B), while the leaf's tier DMAs show up as real
         # gather traffic
         specs = registry.build_entry_specs("fused_multihop")
         assert len(specs) == specs[0].census.count() == 2

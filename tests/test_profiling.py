@@ -6,7 +6,10 @@ The scope contracts:
 1. every scope of ``profiling.DEVICE_SCOPES`` reaches the ``op_name``s
    of the compiled train steps (the forward-only serve step has no
    loss, backward or optimizer); ``qt_draw``/``qt_compact`` sit beneath
-   a ``qt_sample_hop<i>`` and nowhere else; the backward's ops read
+   a ``qt_sample_hop<i>`` and nowhere else, and a hop's ``qt_draw`` holds
+   three ``gather``s, the data's own (two reads of ``indptr``, one of
+   ``indices``: the draw reads its write log by selects); the backward's
+   ops read
    ``transpose(jvp(qt_forward))``; every builder's layers state their
    fanout, so all of ``qt_aggregate`` lies under ``qt_aggregate_dense``
    and the forward holds no scatter. The dist step has the exchange
@@ -25,6 +28,7 @@ The scope contracts:
    swapped for a null context the lowered program is the same text.
 """
 
+import collections
 import contextlib
 import re
 
@@ -201,6 +205,21 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         assert back and fwd
         # the optimizer is outside value_and_grad: no jvp around it
         assert any(re.search(r"(^|/)qt_optimizer/", n) for n in names)
+
+
+@pytest.mark.parametrize("builder", ["train", "serve"])
+def test_the_draw_gathers_nothing_but_the_data(world, builder):
+    """A hop's draw holds three ``gather``s: the two reads of ``indptr``
+    and the one of ``indices``. Its Fisher–Yates write log is read by
+    selects over the log's columns (a gather costs by the index on the
+    chip: reading the log that way was 16 ms of an 89 ms step)."""
+    text = _lower(builder, world).compile().as_text()
+    per_hop = collections.Counter()
+    for name, line in _names_and_ops(text):
+        hop = re.search(r"(qt_sample_hop\d)\)?/qt_draw", name)
+        if hop and re.search(r"= \S+ gather\(", line):
+            per_hop[hop.group(1)] += 1
+    assert per_hop == {f"qt_sample_hop{i}": 3 for i in range(len(SIZES))}
 
 
 def test_which_attention_path_ran_is_in_the_names(world):

@@ -5,12 +5,16 @@ plus distribution and compaction-order properties the reference never
 asserted.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from quiver_tpu.ops import sample as sample_ops
 from quiver_tpu.ops import sample_layer, compact_layer, sample_prob
+from quiver_tpu.ops.sample import _fisher_yates_rows
 
 KEY = jax.random.key(42)
 
@@ -88,6 +92,107 @@ class TestSampleLayer:
             jnp.array([0, 1], jnp.int32), 4, KEY)
         assert int(counts[0]) == 0
         assert int(counts[1]) == 2
+
+
+def _fisher_yates_rows_by_gather(key, deg, k):
+    """The draw as it stood before the log was read by selects (PR 34's
+    ``_fisher_yates_rows``, verbatim): a ``[bs, k]`` log, the last match
+    found by a max over steps, the logged value fetched by
+    ``take_along_axis``. Kept as the oracle of the gather-free form: the
+    same keys, the same ``randint``s, the same swaps, so the same picks
+    in every element, masked slots included."""
+    bs = deg.shape[0]
+    steps = jnp.arange(k, dtype=jnp.int32)
+
+    def lookup(pos_log, val_log, x):
+        # virtual read a[x]: last write wins; unwritten -> x itself
+        match = pos_log == x[:, None]                       # [bs, k]
+        last = jnp.max(jnp.where(match, steps[None, :], -1), axis=1)
+        logged = jnp.take_along_axis(
+            val_log, jnp.maximum(last, 0)[:, None], axis=1)[:, 0]
+        return jnp.where(last >= 0, logged, x)
+
+    def body(carry, xs):
+        pos_log, val_log = carry
+        i, subkey = xs
+        span = jnp.maximum(deg - i, 1)
+        j = i + jax.random.randint(subkey, (bs,), 0, span).astype(deg.dtype)
+        a_j = lookup(pos_log, val_log, j)
+        a_i = lookup(pos_log, val_log, jnp.full((bs,), i, dtype=deg.dtype))
+        pos_log = jax.lax.dynamic_update_slice_in_dim(
+            pos_log, j[:, None], i, axis=1)
+        val_log = jax.lax.dynamic_update_slice_in_dim(
+            val_log, a_i[:, None], i, axis=1)
+        return (pos_log, val_log), a_j
+
+    pos_log = jnp.full((bs, k), -1, dtype=deg.dtype)
+    val_log = jnp.zeros((bs, k), dtype=deg.dtype)
+    keys = jax.random.split(key, k)
+    (_, _), picks = jax.lax.scan(
+        body, (pos_log, val_log), (steps, keys))
+    return jnp.transpose(picks)                              # [bs, k]
+
+
+def _gather_ops(compiled_text):
+    """The ``gather`` instructions of a compiled module's text. (The
+    StableHLO text would not do: it repeats a gather once per outlined
+    copy of the function that holds it.)"""
+    return [line for line in compiled_text.splitlines()
+            if re.search(r"= \S+ gather\(", line)]
+
+
+class TestFisherYatesLog:
+    """The draw reads its own write log by selects over the log's k
+    columns. It is the draw of PR 34 in every element, and it left no
+    gather behind."""
+
+    @pytest.mark.parametrize("seed", [42, 2147483777])
+    @pytest.mark.parametrize("k", [5, 10, 15, 25])
+    def test_equals_the_gather_form_in_every_element(self, k, seed):
+        rng = np.random.default_rng(seed % 1000 + k)
+        deg = jnp.asarray(np.concatenate([
+            [0, 1, k - 1, k, k + 1, 10_000],
+            rng.integers(0, 4 * k, 3000),            # around the fanout
+            rng.integers(0, 10_000, 1000)]), jnp.int32)
+        key = jax.random.key(seed)
+        got = jax.jit(_fisher_yates_rows, static_argnums=2)(key, deg, k)
+        want = jax.jit(_fisher_yates_rows_by_gather, static_argnums=2)(
+            key, deg, k)
+        assert got.shape == want.shape == (deg.shape[0], k)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # and it is a draw: distinct positions inside the row
+        got, deg = np.asarray(got), np.asarray(deg)
+        for row, d in zip(got[:200], deg[:200]):
+            kept = row[:min(d, k)]
+            assert len(set(kept.tolist())) == len(kept)
+            assert ((kept >= 0) & (kept < max(d, 1))).all()
+
+    @pytest.mark.parametrize("with_slots", [False, True])
+    def test_a_hop_compiles_to_the_datas_three_gathers(
+            self, small_graph, with_slots, monkeypatch):
+        """Two reads of ``indptr`` and one of ``indices``, nothing else of
+        opcode ``gather``: the oracle's form holds five."""
+        indptr, indices = (jnp.asarray(a) for a in small_graph)
+        bs, k = indptr.shape[0] - 1, 5
+        seeds = jnp.arange(bs, dtype=jnp.int32)
+
+        def gathers():
+            hop = jax.jit(lambda *a: sample_layer(
+                *a[:3], k, a[3], with_slots=with_slots))
+            return _gather_ops(
+                hop.lower(indptr, indices, seeds, KEY).compile().as_text())
+
+        ours = gathers()
+        # by what each brings back: a value a seed, twice, and one a pick
+        sizes = sorted(
+            int(np.prod([int(d) for d in re.search(
+                r"= s32\[([\d,]+)\]", line).group(1).split(",")]))
+            for line in ours)
+        assert sizes == [bs, bs, bs * k], ours
+        monkeypatch.setattr(sample_ops, "_fisher_yates_rows",
+                            _fisher_yates_rows_by_gather)
+        assert len(gathers()) == 5
 
 
 class TestRotationSampler:
