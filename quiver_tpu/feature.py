@@ -465,8 +465,8 @@ class Feature:
         def lookup_tiered_body(dev_part, host_part, ids, order,
                                masked=False, collector=None):
             # ALL of the lookup is the frontier's row gather: the two
-            # tiers' reads carry their own scopes beneath this one, the
-            # translation, the compaction and the merge only this one
+            # tiers' reads, the translation, the compaction and the merge
+            # carry their own scopes beneath this one, the mask only this
             with profiling.scope(profiling.QT_GATHER):
                 return lookup_tiered_rows(dev_part, host_part, ids, order,
                                           masked, collector)
@@ -520,14 +520,17 @@ class Feature:
                     return rows
                 return rows * (ids_raw >= 0).astype(rows.dtype)[:, None]
 
-            t = translate(ids, order)
-            hot = t < cache_rows
-            if masked:
-                # padding slots classify as HOT regardless of where
-                # clip(−1)→node 0 landed in storage: they must not
-                # consume cold_budget (a padded hetero frontier could
-                # otherwise trip the full-gather fallback every batch)
-                hot = hot | (ids_raw < 0)
+            # the bookkeeping around the two tiers' reads carries its own
+            # names beside theirs (profiling.LOOKUP_STAGES)
+            with profiling.scope(profiling.QT_LOOKUP_TRANSLATE):
+                t = translate(ids, order)
+                hot = t < cache_rows
+                if masked:
+                    # padding slots classify as HOT regardless of where
+                    # clip(−1)→node 0 landed in storage: they must not
+                    # consume cold_budget (a padded hetero frontier could
+                    # otherwise trip the full-gather fallback every batch)
+                    hot = hot | (ids_raw < 0)
             n = t.shape[0]
             if collector is not None:
                 # the OBSERVED hit rate plan_hot_capacity predicted:
@@ -545,7 +548,9 @@ class Feature:
                 collector.add(HOT_ROWS, hot_valid)
                 collector.add(COLD_ROWS, n_valid - hot_valid)
             cold_total = quant.tier_rows(host_part)
-            cold_idx = jnp.clip(t - cache_rows, 0, max(cold_total - 1, 0))
+            with profiling.scope(profiling.QT_LOOKUP_TRANSLATE):
+                cold_idx = jnp.clip(t - cache_rows, 0,
+                                    max(cold_total - 1, 0))
             budget = _resolve_cold_budget(dedup_budget, cold_budget, n)
 
             def count_overflow(also=True):
@@ -571,7 +576,8 @@ class Feature:
             def naive_full():
                 hot_rows = take_hot(jnp.where(hot, t, 0))
                 cold_rows = take_host(cold_idx)
-                return jnp.where(hot[:, None], hot_rows, cold_rows)
+                with profiling.scope(profiling.QT_LOOKUP_MERGE):
+                    return jnp.where(hot[:, None], hot_rows, cold_rows)
 
             if budget >= n:
                 # budget can't beat a full gather: keep the single
@@ -589,25 +595,29 @@ class Feature:
                 batch can overflow the unique budget while its cold
                 slots still fit the compaction budget)."""
                 hot_rows = take_hot(jnp.where(hot, t, 0))
-                cold = ~hot
 
                 def _full(_):
                     cold_rows = take_host(cold_idx)
-                    return jnp.where(hot[:, None], hot_rows, cold_rows)
+                    with profiling.scope(profiling.QT_LOOKUP_MERGE):
+                        return jnp.where(hot[:, None], hot_rows, cold_rows)
 
-                n_cold = jnp.sum(cold).astype(jnp.int32)
-                iota = jnp.arange(n, dtype=jnp.int32)
-                crank = jnp.cumsum(cold).astype(jnp.int32) - 1
-                okey = jnp.where(cold & (crank < budget), crank,
-                                 jnp.iinfo(jnp.int32).max)
-                _, cpos = jax.lax.sort((okey, iota), num_keys=1)
-                cpos = cpos[:budget]    # cold positions (garbage past n_cold)
-                n_fetch = jnp.minimum(n_cold, budget)
-                c_valid = jnp.arange(budget, dtype=jnp.int32) < n_fetch
+                with profiling.scope(profiling.QT_LOOKUP_COMPACT):
+                    cold = ~hot
+                    n_cold = jnp.sum(cold).astype(jnp.int32)
+                    iota = jnp.arange(n, dtype=jnp.int32)
+                    crank = jnp.cumsum(cold).astype(jnp.int32) - 1
+                    okey = jnp.where(cold & (crank < budget), crank,
+                                     jnp.iinfo(jnp.int32).max)
+                    _, cpos = jax.lax.sort((okey, iota), num_keys=1)
+                    cpos = cpos[:budget]  # cold positions (garbage past n_cold)
+                    n_fetch = jnp.minimum(n_cold, budget)
+                    c_valid = jnp.arange(budget, dtype=jnp.int32) < n_fetch
+                    cold_ids = cold_idx[cpos]
                 # [budget, dim]; a host tier fetches the first n_fetch
-                rows = take_host(cold_idx[cpos], n_fetch)
-                tgt = jnp.where(c_valid, cpos, n)           # n = drop slot
-                narrow = hot_rows.at[tgt].set(rows, mode="drop")
+                rows = take_host(cold_ids, n_fetch)
+                with profiling.scope(profiling.QT_LOOKUP_MERGE):
+                    tgt = jnp.where(c_valid, cpos, n)       # n = drop slot
+                    narrow = hot_rows.at[tgt].set(rows, mode="drop")
                 return jax.lax.cond(n_cold > budget, _full,
                                     lambda _: narrow, None)
 

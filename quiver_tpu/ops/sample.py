@@ -41,6 +41,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
+
 
 def _scatter_friendly() -> bool:
     """True when the backend executes gather/scatter as vectorized memory
@@ -150,18 +152,24 @@ def sample_layer(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
     """
     n = indptr.shape[0] - 1
     e = indices.shape[0]
-    valid = seeds >= 0
-    safe = jnp.clip(seeds, 0, max(n - 1, 0)).astype(indptr.dtype)
-    start = indptr[safe]
-    deg = jnp.where(valid, indptr[safe + 1] - start, 0).astype(jnp.int32)
-    counts = jnp.minimum(deg, k)
-    picks = _fisher_yates_rows(key, deg, k)
-    gather = jnp.clip(start[:, None] + picks.astype(indptr.dtype), 0, e - 1)
-    nbrs = indices[gather].astype(jnp.int32)
-    mask = jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
-    nbrs = jnp.where(mask, nbrs, -1)
-    if with_slots:
-        return nbrs, counts, jnp.where(mask, gather, -1)
+    # the draw's three parts carry their own names beneath the hop's
+    # ``qt_draw`` (profiling.DRAW_STAGES)
+    with profiling.scope(profiling.QT_DRAW_ROWS):
+        valid = seeds >= 0
+        safe = jnp.clip(seeds, 0, max(n - 1, 0)).astype(indptr.dtype)
+        start = indptr[safe]
+        deg = jnp.where(valid, indptr[safe + 1] - start, 0).astype(jnp.int32)
+        counts = jnp.minimum(deg, k)
+    with profiling.scope(profiling.QT_DRAW_PICKS):
+        picks = _fisher_yates_rows(key, deg, k)
+    with profiling.scope(profiling.QT_DRAW_NEIGHBORS):
+        gather = jnp.clip(start[:, None] + picks.astype(indptr.dtype),
+                          0, e - 1)
+        nbrs = indices[gather].astype(jnp.int32)
+        mask = jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
+        nbrs = jnp.where(mask, nbrs, -1)
+        if with_slots:
+            return nbrs, counts, jnp.where(mask, gather, -1)
     return nbrs, counts
 
 
@@ -395,10 +403,11 @@ def _segment_heads(indptr: jax.Array, seeds: jax.Array):
     """Per-seed (start, deg) shared by the windowed samplers; invalid
     (-1) seeds get deg 0, which masks them downstream."""
     n = indptr.shape[0] - 1
-    valid = seeds >= 0
-    safe = jnp.clip(seeds, 0, max(n - 1, 0)).astype(indptr.dtype)
-    start = indptr[safe]
-    deg = jnp.where(valid, indptr[safe + 1] - start, 0).astype(jnp.int32)
+    with profiling.scope(profiling.QT_DRAW_ROWS):
+        valid = seeds >= 0
+        safe = jnp.clip(seeds, 0, max(n - 1, 0)).astype(indptr.dtype)
+        start = indptr[safe]
+        deg = jnp.where(valid, indptr[safe + 1] - start, 0).astype(jnp.int32)
     return start, deg
 
 

@@ -63,6 +63,38 @@ scope = jax.named_scope
         "route", "dedup", "bucket", "requests", "gather", "responses",
         "expand"))
 
+# the parts of a hop's draw, beneath ``qt_sample_hop{i}/qt_draw`` in the
+# order they run (ops/sample.py ``sample_layer``, the arm every builder's
+# default walk runs): ``_rows`` the seeds' two reads of ``indptr``, their
+# degrees and counts (``_segment_heads``, the same two reads for the
+# rotation and window arms, carries it too); ``_picks`` the partial
+# Fisher-Yates over the degrees (``_fisher_yates_rows``: the ``scan``,
+# no scope inside its body); ``_neighbors`` the one read of ``indices``
+# at ``start + picks``, its clip, the mask and the slots. The hop's key
+# arithmetic stays under ``qt_draw`` alone, and so do the other arms'
+# bodies (rotation, window, wide-exact, weighted, the Pallas kernels)
+# until a cell runs them.
+(QT_DRAW_ROWS, QT_DRAW_PICKS, QT_DRAW_NEIGHBORS) = DRAW_STAGES = tuple(
+    QT_DRAW + "_" + stage for stage in ("rows", "picks", "neighbors"))
+
+# the bookkeeping of a tiered store's lookup, beneath its ``qt_gather``
+# as siblings of ``qt_lookup_hot`` / ``qt_lookup_cold`` and never inside
+# them (feature.py ``lookup_tiered_rows``): ``_translate`` the read of
+# the order map (node id -> storage row), which tier a slot's row lies
+# in, its row within the cold tier; ``_compact`` the cold slots' ranks,
+# the sort that brings their positions to the front of a ``cold_budget``
+# block, and the block's cold rows' ids; ``_merge`` the cold block
+# written into the hot gather's rows (the scatter; in the full read of
+# an overflow or of a budget past the batch, the select between the
+# tiers' rows). The ``-1`` mask of ``finish`` stays under ``qt_gather``
+# alone, and so does the ``lax.cond`` between the narrow
+# block and the overflow's full read (a name around it would hold the
+# branch's ``qt_lookup_cold`` too): they are the remainder. The dedup
+# branch, which no cell runs, takes none of its own: its translation is
+# the shared one, its fallback is the compaction above with its names.
+(QT_LOOKUP_TRANSLATE, QT_LOOKUP_COMPACT, QT_LOOKUP_MERGE) = LOOKUP_STAGES = \
+    tuple("qt_lookup_" + stage for stage in ("translate", "compact", "merge"))
+
 
 def hot_path(fn):
     """Marker for sync-free hot-path functions — the contract

@@ -23,7 +23,14 @@ The scope contracts:
    builder's layers state their fanout, so all of ``qt_attention`` lies
    under ``qt_attention_slots`` and holds no scatter in the forward
    pass; over an ``Adj`` that states no fanout the same model leaves no
-   ``qt_attention_slots`` in its names.
+   ``qt_attention_slots`` in its names. A hop's draw names its three
+   parts beneath ``qt_draw`` (``profiling.DRAW_STAGES``): the two
+   ``indptr`` gathers under ``qt_draw_rows``, the ``scan``'s loop under
+   ``qt_draw_picks``, the ``indices`` gather under ``qt_draw_neighbors``.
+   The tiered lookup names its bookkeeping (``profiling.LOOKUP_STAGES``)
+   beside the tiers' reads, never inside them: the compaction's ``sort``
+   under ``qt_lookup_compact``, the cold block's ``scatter`` under
+   ``qt_lookup_merge``.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -207,6 +214,72 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         assert any(re.search(r"(^|/)qt_optimizer/", n) for n in names)
 
 
+@pytest.mark.parametrize("builder", ["train", "e2e", "serve", "dist", "tiered",
+                                     "gat"])
+def test_the_draws_parts_lie_beneath_the_draw(world, builder):
+    """``DRAW_STAGES`` reach every builder's names, each only as
+    ``qt_sample_hop<i>/qt_draw/qt_draw_<stage>/``."""
+    names = _op_names(_lower(builder, world))
+    for stage in profiling.DRAW_STAGES:
+        held = [n for n in names if stage in n]
+        assert held, stage
+        for n in held:
+            assert re.search(
+                r"qt_sample_hop\d\)?/qt_draw\)?/" + stage + r"\)?/", n), n
+            assert sum(n.count(s) for s in profiling.DRAW_STAGES) == 1, n
+
+
+@pytest.mark.parametrize("builder", ["train", "serve"])
+def test_a_hops_gathers_by_the_draws_part(world, builder):
+    """Of a hop's three ``gather``s, the two reads of ``indptr`` lie under
+    ``qt_draw_rows`` and the one of ``indices`` under
+    ``qt_draw_neighbors``; ``qt_draw_picks`` holds none, and holds the
+    ``scan``'s loop where the compiler keeps a ``while``."""
+    text = _lower(builder, world).compile().as_text()
+    gathers, loops = collections.Counter(), collections.Counter()
+    for name, line in _names_and_ops(text):
+        at = re.search(r"(qt_sample_hop\d)\)?/qt_draw\)?/(qt_draw_\w+?)\)?/",
+                       name)
+        if at and re.search(r"= \S+ gather\(", line):
+            gathers[at.groups()] += 1
+        # (threefry's own rounds are a loop too on the CPU backend)
+        if re.search(r"qt_sample_hop\d\)?/qt_draw", name) \
+                and " while(" in line and "threefry" not in name:
+            loops[at.group(2) if at else None] += 1
+    assert gathers == {
+        (f"qt_sample_hop{i}", stage): count for i in range(len(SIZES))
+        for stage, count in ((profiling.QT_DRAW_ROWS, 2),
+                             (profiling.QT_DRAW_NEIGHBORS, 1))}
+    assert set(loops) <= {profiling.QT_DRAW_PICKS}
+
+
+def test_the_lookups_bookkeeping_lies_beside_the_tiers(world):
+    """``LOOKUP_STAGES`` lie beneath the tiered lookup's ``qt_gather`` as
+    siblings of ``qt_lookup_hot`` / ``qt_lookup_cold``, never inside
+    them; the compaction's ``sort`` is under ``qt_lookup_compact``, the
+    cold block's ``scatter`` under ``qt_lookup_merge``."""
+    named = _names_and_ops(_lower("tiered", world).compile().as_text())
+    held = collections.defaultdict(list)
+    for name, line in named:
+        for stage in profiling.LOOKUP_STAGES:
+            if stage in name:
+                assert re.search(r"qt_gather\)?/.*" + stage + r"\)?/", name), name
+                assert not any(s in name for s in LOOKUP_SCOPES), name
+                held[stage].append(line)
+    assert set(held) == set(profiling.LOOKUP_STAGES)
+    for opcode, stage in (("sort", profiling.QT_LOOKUP_COMPACT),
+                          ("scatter", profiling.QT_LOOKUP_MERGE)):
+        ops = [line for name, line in named
+               if re.search(r"qt_gather\)?/", name) and f" {opcode}(" in line]
+        assert ops and all(line in held[stage] for line in ops), opcode
+
+
+@pytest.mark.parametrize("builder", ["train", "e2e", "serve", "dist", "gat"])
+def test_no_other_builder_names_the_lookups_bookkeeping(world, builder):
+    names = _op_names(_lower(builder, world))
+    assert not any(s in n for n in names for s in profiling.LOOKUP_STAGES)
+
+
 @pytest.mark.parametrize("builder", ["train", "serve"])
 def test_the_draw_gathers_nothing_but_the_data(world, builder):
     """A hop's draw holds three ``gather``s: the two reads of ``indptr``
@@ -276,7 +349,8 @@ def test_the_exchange_scopes_cover_the_lookup(world):
                         for n in rows)
 
 
-@pytest.mark.parametrize("builder", ["train", "serve", "dist", "tiered"])
+@pytest.mark.parametrize("builder", ["train", "serve", "dist", "tiered",
+                                     "gat"])
 def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     named = _lower(builder, world).as_text()
     monkeypatch.setattr(profiling, "scope",
@@ -290,5 +364,6 @@ def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     # locations too (read before any compile cache has a say)
     located = lowered.as_text(debug_info=True)
     assert not any(s in located for s in profiling.DEVICE_SCOPES
-                   + profiling.EXCHANGE_STAGES)
+                   + profiling.EXCHANGE_STAGES + profiling.DRAW_STAGES
+                   + profiling.LOOKUP_STAGES)
     assert "qt_sample_hop0" in located
