@@ -163,10 +163,14 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
            quantized-tier pytree (``ops.quant.QuantizedTensor``)
       -> [B, dim] feature rows (zeros at -1 fill)
 
-    Bucket ids by owner (one-hot + cumsum), scatter into a static
-    request block, one ``all_to_all`` ships requests, a local gather
-    reads rows, a second ``all_to_all`` ships responses, and a final
-    gather unbuckets them into batch order. A quantized ``feat`` ships
+    An id is resolved ONCE, to one word: the two maps fold into a book,
+    ``g2h * rows_per_host + loc`` (the node's row in the row-sharded
+    ``[H * rows_per_host, dim]`` store), and a read of the book gives
+    owner and local row by one division. Bucket ids by owner (one-hot
+    + cumsum), scatter into a static request block, one ``all_to_all``
+    ships requests, a local gather reads rows, a second ``all_to_all``
+    ships responses, and a final gather unbuckets them into batch
+    order. A quantized ``feat`` ships
     the narrow rows + per-row sidecars through the response collective
     and dequantizes only the unbucketed result — the exchange moves
     storage-width bytes, not fp32. ``rep`` optionally carries
@@ -180,7 +184,11 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
     COMPACT deduplicated layout: the frontier's valid ids dedup once
     (``ops.dedup.unique_within_budget``: two sorts and a scatter over
     the batch's slots, no search; 8 ms at 1.08 M slots on a v5e),
-    the *unique* ids bucket by owner into a [H, cap] request block —
+    only the *unique* ids read the book (the one gather out of an [N]
+    table a lookup), each batch slot reads its owner and its place in
+    the owner's bucket from its distinct id (``place * H + owner``, one
+    gather out of a [B] table; ``B * H`` must stay below 2**31), and
+    the unique ids bucket by owner into a [H, cap] request block —
     the same shape ``build_exchange_fn`` uses — and the wire carries
     [H, cap] requests + [H, cap, width] responses instead of [H, B] /
     [H, B, width]; each batch slot then reads its row out of the
@@ -212,28 +220,24 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
     Every op sits under the scope ``qt_exchange``
     (``profiling.QT_EXCHANGE``), its stages beneath it: ``_route``,
     ``_dedup``, ``_bucket``, ``_requests``, ``_gather``,
-    ``_responses``, ``_expand``.
+    ``_responses``, ``_expand``. ``_route`` runs in two places: first
+    the book, then (compact) the slots' read of their owner and place
+    after ``_bucket``, or (dense) the slots' read of the book;
+    ``_bucket`` holds the distinct ids' read of the book, their places
+    (one-hot + cumsum), the count of rounds and the request block.
     """
     batch = ids.shape[0]
     valid = ids >= 0
     n_nodes = g2h.shape[0]
 
-    def route(ids_, valid_):
-        """Global id -> (owning host, local row); -1 owner at invalid
-        slots (so they match no bucket). Clips from above too: the
-        compact path's unique table carries int32-max fill."""
-        safe = jnp.clip(ids_, 0, n_nodes - 1)
-        owner = jnp.where(valid_, g2h[safe], -1)
-        local = loc[safe]
-        if rep:
-            # replicated nodes resolve locally: owner := this host,
-            # local := this host's replica-tail base + rank in the set
-            is_rep, rep_rank, bases = rep
-            me = jax.lax.axis_index(axis).astype(owner.dtype)
-            r = is_rep[safe]
-            owner = jnp.where(valid_ & r, me, owner)
-            local = jnp.where(r, bases[me] + rep_rank[safe], local)
-        return owner, local
+    def read(book, ids_, valid_):
+        """Global id -> (owning host, local row) by ONE read of the
+        book; -1 owner at invalid slots (so they match no bucket).
+        Clips from above too: the compact path's unique table carries
+        int32-max fill."""
+        row = book[jnp.clip(ids_, 0, n_nodes - 1)]
+        owner = row // rows_per_host
+        return jnp.where(valid_, owner, -1), row - owner * rows_per_host
 
     def bucket_pos(owner):
         """Each id's place in its owner's bucket, and counts[h] = valid
@@ -280,6 +284,8 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
             lambda leaf: leaf[jnp.clip(owner, 0), slot], resp))
 
     def dense():
+        with profiling.scope(profiling.QT_EXCHANGE_ROUTE):
+            owner, local = read(book, ids, valid)
         with profiling.scope(profiling.QT_EXCHANGE_BUCKET):
             my_pos, counts = bucket_pos(owner)
             req = fill(owner, local, valid, my_pos, batch)
@@ -295,15 +301,22 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
         their rows to the batch slots that asked for them. One round
         when every bucket fits ``cap``; the count is ``pmax``-reduced
         over ``axis`` first, since the loop carries collectives and
-        every shard must run it as often."""
+        every shard must run it as often. Only the distinct ids read
+        the book; a batch slot reads its owner and its place from its
+        distinct id, packed as ``place * H + owner`` (one gather out of
+        a [B] table, not the [N] maps)."""
+        if batch * h_count >= 2 ** 31:
+            raise ValueError(
+                f"the compact exchange packs a place and an owner into "
+                f"one int32: batch {batch} x hosts {h_count} must stay "
+                f"below 2**31")
         with profiling.scope(profiling.QT_EXCHANGE_DEDUP):
             uniq, inv, _ = unique_within_budget(
                 ids, batch, valid=valid, collector=collector)
         u_valid = uniq != I32_MAX
         with profiling.scope(profiling.QT_EXCHANGE_BUCKET):
-            owner_u, local_u = route(uniq, u_valid)
+            owner_u, local_u = read(book, uniq, u_valid)
             pos_u, counts = bucket_pos(owner_u)
-            pos = pos_u[inv]             # the place each batch slot reads
             fullest = jnp.max(counts)
             rounds = jax.lax.pmax(-(-fullest // cap), axis)
         if collector is not None:
@@ -312,6 +325,12 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
             collector.add(EXCH_FALLBACK, rounds > 1)
             collector.peak(EXCH_BUCKET_MAX, fullest)
             collector.peak(EXCH_CAP, cap)
+        with profiling.scope(profiling.QT_EXCHANGE_ROUTE):
+            # invalid slots read garbage here: they are masked below
+            # and at the end
+            slot = (pos_u * h_count + owner_u)[inv]
+            owner = jnp.where(valid, slot % h_count, -1)
+            pos = slot // h_count        # the place each batch slot reads
 
         def one_round(k, out):
             base = k * cap
@@ -332,7 +351,17 @@ def dist_lookup_local(ids: jax.Array, g2h: jax.Array, loc: jax.Array,
 
     with profiling.scope(profiling.QT_EXCHANGE):
         with profiling.scope(profiling.QT_EXCHANGE_ROUTE):
-            owner, local = route(ids, valid)
+            # THE resolution of an id, one int32 a node: its row in the
+            # row-sharded store. One elementwise pass over the maps,
+            # which XLA materializes, so a read of it loads one word an
+            # id, not one from each map. Replicated nodes resolve to this
+            # host's replica tail.
+            book = g2h * rows_per_host + loc
+            if rep:
+                is_rep, rep_rank, bases = rep
+                me = jax.lax.axis_index(axis).astype(book.dtype)
+                book = jnp.where(
+                    is_rep, me * rows_per_host + bases[me] + rep_rank, book)
         if collector is not None:
             from .metrics import EXCH_CALLS
             collector.add(EXCH_CALLS, 1)

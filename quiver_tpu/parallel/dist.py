@@ -59,7 +59,9 @@ def build_dist_train_step(model, tx, sizes: Sequence[int],
     the P(axis) spec shards its leaves together and the exchange ships
     the narrow payload, dequantizing after the collective);
     ``g2h``/``g2l`` the replicated owner / local-row maps
-    (``PartitionInfo.global2host/global2local``); ``seeds``/``labels``
+    (``PartitionInfo.global2host/global2local``), which the lookup
+    folds into one book a step (``g2h * rows_per_host + g2l``) that
+    only the frontier's distinct ids read; ``seeds``/``labels``
     [H*per_host_batch] sharded over ``axis``; topology replicated.
 
     ``with_replicate=True`` adds the three replicated-node operands
@@ -85,7 +87,9 @@ def build_dist_train_step(model, tx, sizes: Sequence[int],
 
     The lookup's ops sit under the scope ``qt_exchange``
     (``profiling.QT_EXCHANGE``), where the one-chip steps have
-    ``qt_gather``."""
+    ``qt_gather``; ``qt_exchange_route`` holds the book and each
+    frontier slot's read of its owner and place, ``qt_exchange_bucket``
+    the distinct ids' read of the book (``comm.dist_lookup_local``)."""
     who = "build_dist_train_step"
     h_count = mesh.shape[axis]
 

@@ -51,10 +51,15 @@ scope = jax.named_scope
 
 # the row-sharded store's lookup (comm.dist_lookup_local) stands where a
 # one-chip step has ``qt_gather``: ALL of it sits under ``qt_exchange``,
-# its stages beneath that, in the order they run. ``_dedup`` and
-# ``_expand`` are the compact layout's ends (the sort that finds the
-# distinct ids; each batch slot reading its row out of the response
-# block, and the -1 mask), ``_requests`` and ``_responses`` hold the two
+# its stages beneath that, in the order they run. ``_route`` builds the
+# book (node -> owner * rows_per_host + local row, one word a node) and,
+# a second time after ``_bucket``, holds each batch slot's read of its
+# owner and place from its distinct id (the dense path: the slots' read
+# of the book). ``_dedup`` and ``_expand`` are the compact layout's ends
+# (the sort that finds the distinct ids; each batch slot reading its row
+# out of the response block, and the -1 mask); ``_bucket`` the distinct
+# ids' one read of the book, their places, the count of rounds and the
+# request block; ``_requests`` and ``_responses`` hold the two
 # ``all_to_all``s.
 (QT_EXCHANGE_ROUTE, QT_EXCHANGE_DEDUP, QT_EXCHANGE_BUCKET,
  QT_EXCHANGE_REQUESTS, QT_EXCHANGE_GATHER, QT_EXCHANGE_RESPONSES,

@@ -263,6 +263,42 @@ def test_dist_step_temporaries_are_bounded_by_the_exchange_cap(topo):
     text = compiled.as_text()
     assert f"f32[{chips},{frontier},{dim}]" not in text
     assert f"f32[{chips},{cell['exchange_cap']},{dim}]" in text
+    # an id is resolved once: ONE gather reads an [nodes] operand, the
+    # book a fusion computes out of the two maps; none reads a map itself
+    assert _gather_sources(text, f"s32[{nodes}]") == ["fusion"]
+
+
+def _gather_sources(text, operand):
+    """The opcode that makes each ``operand``-typed array a gather of a
+    compiled program reads, looked through the fusion that holds the
+    gather: ``parameter`` for an argument of the program."""
+    import re
+    comp, insts, calls = None, {}, {}
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?(%[\w.-]+) = (\S+) ([\w-]+)\((.*)", line)
+        if m:
+            name, typ, op, rest = m.groups()
+            insts[comp, name] = (typ, op, rest)
+            callee = re.search(r"calls=(%[\w.-]+)", rest)
+            if op == "fusion" and callee:
+                calls[callee.group(1)] = (comp, rest)
+    sources = []
+    for (comp_, _), (_, op, rest) in insts.items():
+        src = re.match(r"%[\w.-]+", rest).group(0) if op == "gather" else None
+        if src is None or not insts[comp_, src][0].startswith(operand):
+            continue
+        _, src_op, src_rest = insts[comp_, src]
+        if src_op == "parameter" and comp_ in calls:
+            caller, args = calls[comp_]
+            arg = re.findall(r"%[\w.-]+", args.split(")")[0])[
+                int(src_rest.split(")")[0])]
+            src_op = insts[caller, arg][1]
+        sources.append(src_op)
+    return sources
 
 
 def _loop_bounds(text):

@@ -490,7 +490,8 @@ class TestBoundedExchange:
     that overflows is served by further rounds of the same [H, cap]
     exchange, and the rows are the dense path's bit for bit whether the
     buckets overflow never, once or twice, with a replicated set and
-    with a quantised store."""
+    with a quantised store, at each shard's last rows and where a shard
+    asks for one row."""
 
     N, DIM, HOSTS = 320, 8, 8
 
@@ -511,15 +512,46 @@ class TestBoundedExchange:
             feat, info, comm, dtype_policy=policy, **kw)
         return make(), make(exchange_cap=cap, collect_metrics=True)
 
+    def _frontier(self, rng, kind, store):
+        """``mixed``: -1 fill and a hub among random ids. ``last_rows``:
+        ids at every host's last local row and node ``N - 1``, in a
+        store whose ``rows_per_host`` is no power of two, so the book's
+        ``owner * rows_per_host + row`` comes apart at the top of each
+        shard (with ``replicate``, the last replicated node resolves to
+        the fullest shard's very last row). ``lone``: shard 0's frontier
+        is all -1 but for one id, and it runs the rounds the other
+        shards need."""
+        hosts, per_host = self.HOSTS, 64
+        ids = rng.integers(0, self.N, hosts * per_host).astype(np.int32)
+        if kind == "mixed":
+            ids[::5] = -1
+            ids[1::9] = 5                   # a hub, replicated or not
+        elif kind == "last_rows":
+            dense, _ = self._stores(store, None)
+            rows = dense._rows_per_host
+            assert rows & (rows - 1)
+            g2h = np.asarray(dense.info.global2host)
+            g2l = np.asarray(dense.info.global2local)
+            last = [self.N - 1] + [
+                int(i) for h, size in enumerate(dense.info.local_sizes)
+                for i in np.flatnonzero((g2h == h) & (g2l == size - 1))]
+            assert len(last) > hosts // 2
+            ids[::3] = np.resize(last, ids[::3].size)
+            ids[::7] = -1
+        else:
+            ids[::5] = -1
+            ids[:per_host] = -1
+            ids[per_host // 2] = self.N - 1
+        return ids
+
+    @pytest.mark.parametrize("frontier", ["mixed", "last_rows", "lone"])
     @pytest.mark.parametrize("store", ["plain", "replicate", "int8"])
     @pytest.mark.parametrize("rounds", [1, 2, 3])
     def test_rounds_equal_the_dense_path_bit_for_bit(self, rng, store,
-                                                     rounds):
+                                                     rounds, frontier):
         from quiver_tpu import metrics as qm
         hosts, per_host = self.HOSTS, 64
-        ids = rng.integers(0, self.N, hosts * per_host).astype(np.int32)
-        ids[::5] = -1
-        ids[1::9] = 5                       # a hub, replicated or not
+        ids = self._frontier(rng, frontier, store)
         # the fullest per-owner bucket of distinct ids any shard fills
         _, probe = self._stores(store, per_host - 1)
         probe[jnp.asarray(ids)]
@@ -538,6 +570,45 @@ class TestBoundedExchange:
         # every shard says whether the lookup took a round beyond the
         # first: the pmax'd count, the same on all of them
         assert c[qm.EXCH_FALLBACK] == (hosts if rounds > 1 else 0)
+
+    def test_one_read_of_the_book_and_none_of_the_maps(self):
+        """The compact lookup resolves an id ONCE: one gather in the
+        program reads an [N] operand, the book that ``_route`` computes
+        out of ``g2h`` and ``g2l``, and the distinct ids index it (it
+        sits under ``_bucket``); no gather reads ``g2h`` or ``g2l``
+        themselves, and the slots' read under ``_route`` reads no [N]
+        operand (the parent read the maps three times)."""
+        from quiver_tpu import profiling
+        from _traffic import _sub_jaxprs, gather_reads
+        hosts, per_host, cap = self.HOSTS, 64, 4
+        _, compact = self._stores("plain", cap)
+        assert self.N not in (hosts, per_host, hosts * per_host, cap,
+                              self.DIM, compact._rows_per_host)
+        fn = qv.comm.build_dist_lookup_fn(
+            compact.comm.mesh, "host", compact._rows_per_host, per_host,
+            exchange_cap=cap)
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((hosts * per_host,), jnp.int32),
+            compact.info.global2host, compact.info.global2local,
+            compact._spmd_feat)
+        assert gather_reads(jaxpr, (self.N,)) == [(per_host, 0)]
+
+        def gathers(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "gather":
+                    yield j, eqn
+                for sub in _sub_jaxprs(eqn):
+                    yield from gathers(sub)
+
+        route = []
+        for j, eqn in gathers(jaxpr.jaxpr):
+            stack = str(eqn.source_info.name_stack)
+            if eqn.invars[0].aval.shape == (self.N,):
+                assert eqn.invars[0] not in j.invars   # computed: the book
+                assert profiling.QT_EXCHANGE_BUCKET in stack, stack
+            if profiling.QT_EXCHANGE_ROUTE in stack:
+                route.append(eqn.invars[0].aval.shape)
+        assert route == [(per_host,)]
 
     def test_no_block_of_the_frontiers_size_in_the_compact_program(self, rng):
         """No [H, B] or [H, B, width] value anywhere in the traced
