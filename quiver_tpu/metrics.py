@@ -107,8 +107,10 @@ COLD_OVERFLOW = 25    # tiered lookups whose cold count passed cold_budget
 #                       (filed with COLD_ROWS: the full host gather ran)
 EDGE_VALID = 26       # valid sampled edge slots, all hops of the walk
 EDGE_CAP = 27         # static edge-slot capacity of those hops
+EDGE_REL_MAX = 28     # valid edge slots of the walk's largest relation
+#                       (a typed graph's; 0 on an untyped one)
 
-NUM_COUNTERS = 28
+NUM_COUNTERS = 29
 
 #: slots merged with ``max`` across steps/shards; all others add
 MAX_SLOTS = (EXCH_BUCKET_MAX, EXCH_CAP, IO_DEPTH_PEAK)
@@ -135,6 +137,7 @@ SLOT_NAMES = {
     LOCALITY_MISS_ROWS: "locality_miss_rows",
     COLD_OVERFLOW: "cold_overflow",
     EDGE_VALID: "edge_valid", EDGE_CAP: "edge_cap",
+    EDGE_REL_MAX: "edge_rel_max",
 }
 
 _MAX_MASK_NP = np.zeros((NUM_COUNTERS,), bool)

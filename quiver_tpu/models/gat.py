@@ -19,6 +19,27 @@ softmax and the weighted sum run over the slot axis, the self edge as a
 slot of its own; otherwise they are segment reductions over the target
 ids. One path a layer, chosen at trace time from what the ``Adj`` states;
 the slot form leaves ``qt_attention_slots`` in the program's names.
+
+Over a typed graph (the ``Adj`` states each slot's ``relation``) the
+layer is a relational one, OGB-LSC's R-GAT (``GATConv(..., heads,
+add_self_loops=False)`` a relation, summed): every relation ``r`` has
+its own ``W_r``, ``a_src_r``, ``a_dst_r`` and bias, no edge is added or
+dropped, and the softmax runs over the slots of each (target, relation)
+group:
+
+    P_e = x_j W_r                          r = r(e), e = j -> i
+    e_ij = LeakyReLU(x_j . (W_r a_src_r) + x_i . (W_r a_dst_r))  per head
+    alpha_ij = softmax over j in N_r(i)
+    out_i = sum_r concat_h sum_j alpha_ij P_e + sum of b_r over the
+            relations with an edge in the block
+
+Each slot's source row is projected by its own relation's weight alone:
+a grouped product over the slots sorted by relation, in tiles, the
+sort, the places and the row moves named ``qt_relation_route``. Both
+logit terms are products with ``W_r a_r`` (``[in, heads]``): a slot's
+source term one more product of the same tiles, a target's under every
+relation. The existing layer is the case of one relation with its own self edge:
+the same group softmax serves both.
 """
 
 from __future__ import annotations
@@ -43,6 +64,29 @@ def segment_softmax(logits: jax.Array, segment_ids: jax.Array,
     expd = jnp.where(valid, jnp.exp(shifted), 0.0)
     denom = jax.ops.segment_sum(expd, segment_ids, num_segments=num_segments)
     return expd / jnp.maximum(denom[segment_ids], 1e-16)
+
+
+def _group_softmax(logits, ok, slope: float, rel=None, relations: int = 1):
+    """``alpha``, the shape of ``logits`` (slots first): each target's
+    softmax of ``LeakyReLU(logits)`` over its ``ok`` slots, and with
+    ``rel`` (a relation a slot) over the slots of each relation apart.
+    No relation is one group, which is what one relation is."""
+    logits = jnp.where(ok, nn.leaky_relu(logits, slope), NEG_INF)
+    if rel is None:
+        top = logits.max(axis=0)
+        top = jnp.where(top > NEG_INF, top, 0.0)
+        e = jnp.where(ok, jnp.exp(logits - top), 0.0)
+        return e / jnp.maximum(e.sum(axis=0), 1e-16)
+    groups = [rel == r for r in range(relations)]
+    top = jnp.zeros_like(logits)
+    for g in groups:
+        m = jnp.where(g, logits, NEG_INF).max(axis=0)
+        top = jnp.where(g, jnp.where(m > NEG_INF, m, 0.0), top)
+    e = jnp.where(ok, jnp.exp(logits - top), 0.0)
+    total = jnp.ones_like(logits)
+    for g in groups:
+        total = jnp.where(g, jnp.where(g, e, 0.0).sum(axis=0), total)
+    return e / jnp.maximum(total, 1e-16)
 
 
 def _attend_slots(h, att_src, att_dst, src, target_mask, fanout: int,
@@ -72,11 +116,7 @@ def _attend_slots(h, att_src, att_dst, src, target_mask, fanout: int,
         at_dst = (me * att_dst[i]).sum(-1)               # [T]
         logits = jnp.concatenate([(nbr * att_src[i]).sum(-1),
                                   (me * att_src[i]).sum(-1)[None]]) + at_dst
-        logits = jnp.where(ok, nn.leaky_relu(logits, slope), NEG_INF)
-        top = logits.max(axis=0)
-        top = jnp.where(top > NEG_INF, top, 0.0)
-        e = jnp.where(ok, jnp.exp(logits - top), 0.0)
-        alpha = e / jnp.maximum(e.sum(axis=0), 1e-16)    # [k + 1, T]
+        alpha = _group_softmax(logits, ok, slope)        # [k + 1, T]
         result.append((nbr * alpha[:fanout, :, None]).sum(axis=0)
                       + me * alpha[fanout][:, None])
     return jnp.concatenate(result, axis=-1)              # [T, heads x out]
@@ -109,13 +149,176 @@ def _attend_segments(h, att_src, att_dst, edge_index, target_mask,
     return jnp.concatenate(result, axis=-1)
 
 
+@jax.custom_vjp
+def _take_rows(x, take, inverse):
+    """``x[take]`` where ``take`` is one-to-one into ``x``'s rows and
+    ``inverse[p]`` is the row of the result that row ``p`` of ``x`` went
+    to (``len(take)`` where none did): the cotangent comes back through
+    ``inverse``, a gather, and is not scattered."""
+    return x[take]
+
+
+def _take_rows_fwd(x, take, inverse):
+    return x[take], inverse
+
+
+def _take_rows_bwd(inverse, g):
+    # ``len(take)`` reads 0, and ``g`` is not copied to hold a zero row
+    return jnp.take(g, inverse, axis=0, mode="fill", fill_value=0), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _tile_rows(n: int) -> int:
+    """Rows a tile of the grouped product: a power of two near ``n / 32``,
+    from 128 to 2048."""
+    return 1 << max(7, min(11, (max(n, 1) // 32).bit_length() - 1))
+
+
+def relation_slots(adj):
+    """``(src, rel, ok)`` of a typed ``adj``'s edge slots, each ``[E]``:
+    slot-major (``[fanout, T]`` flattened) where the ``Adj`` states its
+    ``fanout``, in its own order otherwise. ``ok``: a valid edge of a
+    relation into a target slot that holds a node."""
+    mask = adj.target_mask()
+    src, dst = adj.edge_index
+    rel = adj.relation
+    if adj.fanout is not None:
+        t, k = adj.size[1], adj.fanout
+        src = src.reshape(t, k).T.reshape(-1)
+        rel = rel.reshape(t, k).T.reshape(-1)
+        ok = (src >= 0) & (rel >= 0) & jnp.tile(mask, k)
+    else:
+        ok = (src >= 0) & (dst >= 0) & (rel >= 0) & mask[jnp.clip(dst, 0)]
+    return src, rel, ok
+
+
+def project_by_relation(x, ws, src, rel, ok):
+    """``[E, out]`` for each ``w`` of ``ws``: each edge slot's source row
+    ``x[src]`` by its own relation's weight ``w[rel]`` (``w`` is ``[R,
+    in, out]``, the ``out`` a ``w``'s own); a slot that is not ``ok``
+    gets some finite row, which nothing downstream weighs.
+
+    A grouped product in tiles of ``_tile_rows`` rows, each tile one
+    relation's: ONE sort lays the slots out by relation (the others
+    last), each relation's run followed by as many empty places as make
+    it whole tiles. It sorts the slots together with ``tile`` spare
+    entries a relation, of which the first ``tiles(r) * tile - size(r)``
+    carry that relation and sort behind its slots, the rest sort last;
+    so no place is gathered. The sources' rows are read in that layout,
+    every tile is multiplied by its relation's weight (one batched
+    product a ``w``), and the products are read back in slot order: a
+    slot's place is its relation's first tile plus its rank among the
+    relation's slots (a cumsum a relation). The sort, the places and the
+    row moves are ``qt_relation_route``, the products ``qt_project``."""
+    relations = ws[0].shape[0]
+    n = src.shape[0]
+    tile = _tile_rows(n)
+    tiles = -(-n // tile) + relations
+    spare = relations * tile
+    with profiling.scope(profiling.QT_RELATION_ROUTE):
+        key = jnp.where(ok, rel, relations)
+        mine = [key == r for r in range(relations + 1)]
+        sizes = jnp.stack([jnp.sum(m, dtype=jnp.int32) for m in mine])
+        runs = -(-sizes[:relations] // tile)
+        first = jnp.concatenate([jnp.cumsum(runs) - runs,
+                                 jnp.sum(runs, keepdims=True)])
+        # the spare entries: relation r's first runs(r) * tile - size(r)
+        # pad its run, the others go last
+        lane = jnp.arange(tile, dtype=jnp.int32)
+        pad = runs * tile - sizes[:relations]
+        spare_key = jnp.where(lane[None] < pad[:, None],
+                              jnp.arange(relations, dtype=jnp.int32)[:, None],
+                              relations + 1).reshape(-1)
+        _, whose, sources = jax.lax.sort(
+            (jnp.concatenate([key, spare_key]),
+             jnp.arange(n + spare, dtype=jnp.int32),
+             jnp.concatenate([jnp.where(ok, src, 0),
+                              jnp.zeros((spare,), src.dtype)])),
+            num_keys=2, is_stable=False)
+        whose = jnp.minimum(whose, n)                  # a spare place: none
+        back = jnp.zeros((n,), jnp.int32)
+        for r, m in enumerate(mine):
+            back = jnp.where(m, first[r] * tile + jnp.cumsum(
+                m, dtype=jnp.int32) - 1, back)
+        tail = tiles * tile - n - spare
+        rows = x[jnp.pad(sources, (0, tail))]
+        group = jnp.sum(jnp.arange(tiles, dtype=jnp.int32)[:, None]
+                        >= (first[:relations] + runs)[None], axis=1,
+                        dtype=jnp.int32)
+        group = jnp.minimum(group, relations - 1)
+        weights = [w[group] for w in ws]
+    with profiling.scope(profiling.QT_PROJECT):
+        rows = rows.reshape(tiles, tile, -1)
+        products = [jnp.einsum("tif,tfo->tio", rows, w
+                               ).reshape(tiles * tile, -1) for w in weights]
+    with profiling.scope(profiling.QT_RELATION_ROUTE):
+        whose = jnp.pad(whose, (0, tail), constant_values=n)
+        return [_take_rows(m, back, whose) for m in products]
+
+
+def _attend_relations(p, src_term, dst_terms, rel, softmax, total):
+    """One relational layer's attention, all heads at once. ``p``
+    [slots..., heads x out] each slot's source row by its relation's
+    weight and ``src_term`` [slots..., heads] its logit term;
+    ``dst_terms`` [slots..., R, heads] (broadcast) the slot's target's
+    logit term under each relation, of which the slot's relation picks
+    one; ``softmax(logits) -> alpha`` within the (target, relation)
+    groups, the heads on the minor axis; ``total`` sums the weighted rows
+    into targets."""
+    relations, heads = dst_terms.shape[-2:]
+    nbr = p.reshape(p.shape[:-1] + (heads, -1))
+    at_dst = dst_terms[..., 0, :]
+    for r in range(1, relations):
+        at_dst = jnp.where((rel == r)[..., None], dst_terms[..., r, :],
+                           at_dst)
+    y = total(nbr * softmax(src_term + at_dst)[..., None])
+    return y.reshape(y.shape[:-2] + (-1,))
+
+
+def _attend_typed(p, src_term, dst_terms, adj, slope: float):
+    relations = dst_terms.shape[1]
+    t = adj.size[1]
+    if adj.fanout is not None:
+        with profiling.scope(profiling.QT_ATTENTION_SLOTS):
+            _, rel, ok = relation_slots(adj)
+            shape = (adj.fanout, t)
+            rel, ok = rel.reshape(shape), ok.reshape(shape)
+            return _attend_relations(
+                p.reshape(shape + p.shape[-1:]),
+                src_term.reshape(shape + src_term.shape[-1:]),
+                dst_terms[None], rel,
+                lambda l: _group_softmax(l, ok[..., None], slope,
+                                         rel[..., None], relations),
+                lambda m: m.sum(axis=0))
+    _, rel, ok = relation_slots(adj)
+    d = jnp.where(ok, adj.edge_index[1], 0)
+    safe = jnp.where(ok, rel, 0)
+    return _attend_relations(
+        p, src_term, dst_terms[d], rel,
+        lambda l: segment_softmax(nn.leaky_relu(l, slope),
+                                  d * relations + safe, t * relations,
+                                  ok[:, None]),
+        lambda m: jax.ops.segment_sum(m, d, num_segments=t))
+
+
 def gat_attention(h, att_src, att_dst, adj, slope: float = 0.2):
     """``[T, heads x out]``: every valid target's attention-weighted sum
     of the projected rows ``h`` [S, heads x out] of its sampled neighbours
     and of itself (targets are the first ``adj.size[1]`` sources). Rows of
-    target slots that hold no node come back zero."""
+    target slots that hold no node come back zero.
+
+    Over a typed ``adj`` (it states ``relation``): ``h`` [E, heads x out]
+    is each edge slot's row by its relation's weight, in
+    ``relation_slots``' order (``project_by_relation``), ``att_src`` each
+    slot's logit term under its relation, ``[E, heads]`` in the same
+    order, and ``att_dst`` each target's under each relation, ``[T, R,
+    heads]``; no self edge, a softmax a (target, relation) group."""
     mask = adj.target_mask()
     with profiling.scope(profiling.QT_ATTENTION):
+        if adj.relation is not None:
+            return _attend_typed(h, att_src, att_dst, adj, slope)
         if adj.fanout is not None:
             with profiling.scope(profiling.QT_ATTENTION_SLOTS):
                 return _attend_slots(h, att_src, att_dst, adj.edge_index[0],
@@ -126,16 +329,21 @@ def gat_attention(h, att_src, att_dst, adj, slope: float = 0.2):
 
 class GATConv(nn.Module):
     """``conv(x, adj)``: ``x`` [S, in] are the layer's sources, its
-    targets their first ``adj.size[1]`` rows."""
+    targets their first ``adj.size[1]`` rows. ``relations`` > 1 asks for
+    the relational layer, which needs an ``Adj`` that states each slot's
+    relation; an untyped ``Adj`` takes the one-relation layer."""
 
     out_dim: int
     heads: int = 1
     concat: bool = True
     negative_slope: float = 0.2
+    relations: int = 1
 
     @nn.compact
     def __call__(self, x, adj):
         heads, out = self.heads, self.out_dim
+        if adj.relation is not None or self.relations != 1:
+            return self._typed(x, adj)
         with profiling.scope(profiling.QT_PROJECT):
             h = nn.Dense(heads * out, use_bias=False,
                          kernel_init=nn.initializers.glorot_uniform(),
@@ -150,6 +358,42 @@ class GATConv(nn.Module):
         if not self.concat:
             y = y.reshape(-1, heads, out).mean(axis=1)
         return y + bias
+
+    def _typed(self, x, adj):
+        """The relational layer (the module docstring's equations)."""
+        heads, out, r = self.heads, self.out_dim, self.relations
+        if adj.relations != r:
+            raise ValueError(
+                f"GATConv(relations={r}) over blocks of a graph with "
+                + ("no relations: an untyped graph (a typed one is "
+                   "ops.TypedIndices)" if adj.relations is None
+                   else f"{adj.relations} relations"))
+        glorot = nn.initializers.glorot_uniform(batch_axis=(0,))
+        w = self.param("kernel", glorot, (r, x.shape[-1], heads * out))
+        att_src = self.param("att_src", glorot, (r, heads, out))
+        att_dst = self.param("att_dst", glorot, (r, heads, out))
+        bias = self.param("bias", nn.initializers.zeros,
+                          (r, heads * out if self.concat else out))
+        src, rel, ok = relation_slots(adj)
+        with profiling.scope(profiling.QT_PROJECT):
+            # the logit terms x . (W_r a_r): [in, heads] a relation, no
+            # [.., heads x out] row is read for them
+            per_head = w.reshape(r, x.shape[-1], heads, out)
+            v = jnp.einsum("rfho,rho->rfh", per_head, att_src)
+            u = jnp.einsum("rfho,rho->frh", per_head, att_dst)
+        # a slot's row and its source's term, each by the slot's relation
+        p, at_src = project_by_relation(x, (w, v), src, rel, ok)
+        with profiling.scope(profiling.QT_PROJECT):
+            # the targets' terms under every relation
+            dst = (x[:adj.size[1]] @ u.reshape(x.shape[-1], r * heads)
+                   ).reshape(-1, r, heads)
+        y = gat_attention(p, at_src, dst, adj, self.negative_slope)
+        if not self.concat:
+            y = y.reshape(-1, heads, out).mean(axis=1)
+        # a relation's bias enters where the block holds an edge of it
+        for i in range(r):
+            y = y + jnp.where(jnp.any(ok & (rel == i)), bias[i], 0.0)
+        return y
 
 
 class GAT(nn.Module):

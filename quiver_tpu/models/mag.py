@@ -13,6 +13,14 @@ running averages feed evaluation only and are not kept.
 
 Scopes beneath ``qt_forward``: ``qt_project`` (every product with a
 weight matrix), ``qt_attention`` (``models.gat``), ``qt_norm``.
+
+``relations`` > 1 is OGB-LSC's R-GAT (``examples/lsc/mag240m/rgnn.py``,
+``RGNN(model='rgat')``) over a typed graph whose blocks state each
+edge's relation: each layer's conv is ``models.gat.GATConv``'s
+relational form (one ``GATConv`` a relation, summed, no self edge), with
+the same skip, batch norm over the valid targets of every type together,
+ELU, dropout and head (``qt_relation_route`` beneath ``qt_forward``
+too).
 """
 
 from __future__ import annotations
@@ -32,6 +40,7 @@ class MAG240MGNN(nn.Module):
     num_layers: int
     heads: int = 4
     dropout: float = 0.5
+    relations: int = 1              # > 1: R-GAT over a typed graph ('gat')
 
     @nn.compact
     def __call__(self, x, adjs, *, train: bool = False):
@@ -42,7 +51,7 @@ class MAG240MGNN(nn.Module):
             if self.model == "gat":
                 conv = GATConv(self.hidden_dim // self.heads,
                                heads=self.heads, concat=True,
-                               name=f"conv{i}")
+                               relations=self.relations, name=f"conv{i}")
                 h = conv(x, adj)
                 with profiling.scope(profiling.QT_PROJECT):
                     h = h + nn.Dense(self.hidden_dim,

@@ -19,6 +19,7 @@ from .sample import (
     LayerSample,
 )
 from .sample_multihop import sample_multihop, sample_multihop_dedup
+from .relation import NodeTypes, TypedIndices
 from .dedup import unique_within_budget, dedup_take
 from . import quant
 from .quant import QuantizedTensor, HotPlan, plan_hot_capacity
@@ -49,6 +50,8 @@ __all__ = [
     "sample_prob",
     "sample_multihop",
     "sample_multihop_dedup",
+    "NodeTypes",
+    "TypedIndices",
     "unique_within_budget",
     "dedup_take",
     "quant",

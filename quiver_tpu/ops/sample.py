@@ -72,6 +72,10 @@ class LayerSample(NamedTuple):
     seed_count: [] number of valid seeds; they hold the local ids
               ``[0, seed_count)`` (see ``compact_layer``), so a target
               slot ``t`` of this hop is a real node iff ``t < seed_count``
+    rel:      [num_seeds*k] the relation of each sampled edge (-1 fill)
+              over a typed graph (``ops.relation``); None otherwise
+    types:    that typed graph's ``ops.relation.NodeTypes`` (static, no
+              leaves), stated with ``rel``; None otherwise
     """
 
     n_id: jax.Array
@@ -81,6 +85,8 @@ class LayerSample(NamedTuple):
     edge_count: jax.Array
     e_id: jax.Array | None = None
     seed_count: jax.Array | None = None
+    rel: jax.Array | None = None
+    types: object | None = None
 
 
 def _fisher_yates_rows(key: jax.Array, deg: jax.Array, k: int) -> jax.Array:

@@ -28,6 +28,7 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
                     weight_rows: jax.Array | None = None,
                     hub_frac: float | None = None,
                     collector=None,
+                    node_types=None,
                     ) -> Tuple[jax.Array, List[LayerSample]]:
     """Expand ``seeds`` through ``sizes`` hops. Returns the final frontier
     ``n_id`` (static cap, -1 fill) and the per-hop LayerSamples in
@@ -92,6 +93,13 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
     frontier's fill — valid slots vs the static cap, the number the
     dedup budgets and exchange caps are sized against — with one jnp
     reduction on the returned ``n_id`` (no host sync, output unchanged).
+
+    ``node_types`` (an ``ops.relation.NodeTypes``: a typed graph's id
+    ranges and relations) gives every hop the relation of each sampled
+    edge, in ``LayerSample.rel`` (and ``node_types`` itself in
+    ``LayerSample.types``): read off the seed's and the pick's
+    global ids where the draw ends (under ``qt_draw_neighbors``), with
+    compares and selects and no gather. The draw itself is unchanged.
     """
     cur = seeds.astype(jnp.int32)
     track_eid = eid is not None
@@ -185,7 +193,10 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
           else:
               out = sample_layer(indptr, indices, cur, k, sub,
                                  with_slots=track_eid)
-        nbrs = out[0]
+          nbrs = out[0]
+          if node_types is not None:
+              with profiling.scope(profiling.QT_DRAW_NEIGHBORS):
+                  rel = node_types.relation(cur[:, None], nbrs).reshape(-1)
         if track_eid:
             slots = out[2]
         with profiling.scope(profiling.QT_COMPACT):
@@ -201,6 +212,8 @@ def sample_multihop(indptr: jax.Array, indices: jax.Array, seeds: jax.Array,
               else:
                   ids = jnp.asarray(eid)[jnp.clip(flat, 0)]
               layer = layer._replace(e_id=jnp.where(flat >= 0, ids, -1))
+        if node_types is not None:
+            layer = layer._replace(rel=rel, types=node_types)
         layers.append(layer)
         cur = layer.n_id
     count_walk(collector, cur, layers)
@@ -211,14 +224,23 @@ def count_walk(collector, n_id, layers) -> None:
     """What a walk counts into a ``metrics.Collector`` (None: nothing):
     the final frontier's valid slots against its static cap, and the
     hops' valid edge slots against theirs: what share of a model's
-    per-row and per-edge work is padding."""
+    per-row and per-edge work is padding. Over a typed graph (the layers
+    state their ``types``), also the valid edge slots of the relation
+    that holds the most of them over all hops: how uneven the groups of
+    a product by relation are."""
     if collector is None:
         return
-    from ..metrics import EDGE_CAP, EDGE_VALID, FRONTIER_CAP, FRONTIER_VALID
+    from ..metrics import (EDGE_CAP, EDGE_REL_MAX, EDGE_VALID, FRONTIER_CAP,
+                           FRONTIER_VALID)
     collector.add(FRONTIER_VALID, jnp.sum(n_id >= 0))
     collector.add(FRONTIER_CAP, int(n_id.shape[0]))
     collector.add(EDGE_VALID, sum(l.edge_count for l in layers))
     collector.add(EDGE_CAP, sum(int(l.col.shape[0]) for l in layers))
+    types = layers[0].types if layers else None
+    if types is not None:
+        collector.add(EDGE_REL_MAX, jnp.max(jnp.stack([
+            sum(jnp.sum(l.rel == r, dtype=jnp.int32) for l in layers)
+            for r in range(types.num_relations)])))
 
 
 def sample_multihop_dedup(indptr: jax.Array, indices: jax.Array,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from .. import profiling
 from ..comm import default_exchange_cap
+from ..ops.relation import split_typed
 from ..ops.sample_multihop import count_walk, sample_multihop
 from ..profiling import hot_path
 from ..pyg.sage_sampler import Adj, layer_shapes
@@ -43,8 +44,9 @@ def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
     or nothing, which is what each ``Adj.fanout`` set here states. A
     caller that cannot promise it builds its ``Adj``s without a fanout.
     Each ``Adj`` also states how many of its target slots hold a node
-    (``valid_targets``: the hop's count of valid seeds), for every step
-    builder alike."""
+    (``valid_targets``: the hop's count of valid seeds) and, over a typed
+    graph, each edge slot's relation (``relation``) and the graph's count
+    of relations (``relations``), for every step builder alike."""
     shapes = layer_shapes(batch_size, sizes)
     adjs = []
     for layer, shape in zip(layers, shapes):
@@ -52,7 +54,10 @@ def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
                         e_id=layer.e_id,
                         size=(shape.n_id_cap, shape.num_seeds),
                         mask=layer.col >= 0, fanout=shape.fanout,
-                        valid_targets=layer.seed_count))
+                        valid_targets=layer.seed_count,
+                        relation=layer.rel,
+                        relations=(None if layer.types is None
+                                   else layer.types.num_relations)))
     return adjs[::-1]
 
 
@@ -359,21 +364,31 @@ def walk_frontier(walk: Walk, feat, forder, indptr, indices, seeds, key,
 
     ``collector`` takes the frontier's fill and the gather's counts,
     unless ``rows_collector`` takes the latter (the sharded serve step
-    counts its replicated sampling apart from each shard's exchange)."""
+    counts its replicated sampling apart from each shard's exchange).
+
+    ``indices`` may be an ``ops.relation.TypedIndices``: the graph is
+    then typed, and every hop's ``LayerSample.rel`` holds each edge
+    slot's relation."""
     if rows_collector is None:
         rows_collector = collector
     walk.check_rows(indices_rows)
+    indices, types = split_typed(indices)
     if walk.row_cap is None:
         n_id, layers = sample_multihop(
             indptr, indices, seeds, walk.sizes, key, method=walk.method,
             indices_rows=indices_rows,
             indices_stride=(walk.indices_stride
                             if indices_rows is not None else None),
-            seeds_dense=True, hub_frac=walk.hub_frac, collector=collector)
+            seeds_dense=True, hub_frac=walk.hub_frac, collector=collector,
+            node_types=types)
         x = (walk.gather or masked_feature_gather)(
             feat, n_id, forder, collector=rows_collector)
         return n_id, x, layers
 
+    if types is not None:
+        raise ValueError(
+            f"{walk.who}: fused_hot_hop draws no relations; a typed graph "
+            "takes the sample_multihop walk")
     # the fused Pallas walk: frontier ids stay on chip at EVERY hop
     from ..ops.pallas.fused import (fused_multihop, fused_sample_multihop,
                                     pad_indices)

@@ -39,15 +39,19 @@ scope = jax.named_scope
 # batch norm over the valid rows. ``qt_attention_slots`` sits inside
 # ``qt_attention`` exactly when the softmax ran over the slot axis of an
 # ``Adj`` that states its ``fanout`` (as ``qt_aggregate_dense`` does for
-# the mean).
+# the mean). ``qt_relation_route`` sits beneath ``qt_forward`` in a layer
+# over a typed graph (models/gat.py): what brings each edge slot to its
+# relation's weight and back (the sort of the slots by relation, the
+# group sizes, the sources' rows read in that order, the product's rows
+# put back in slot order); the product itself stays ``qt_project``.
 (QT_DRAW, QT_COMPACT, QT_GATHER, QT_FORWARD, QT_LOSS, QT_OPTIMIZER,
  QT_AGGREGATE, QT_AGGREGATE_DENSE, QT_EXCHANGE, QT_LOOKUP_HOT,
  QT_LOOKUP_COLD, QT_PROJECT, QT_ATTENTION, QT_ATTENTION_SLOTS,
- QT_NORM) = DEVICE_SCOPES = (
+ QT_NORM, QT_RELATION_ROUTE) = DEVICE_SCOPES = (
     "qt_draw", "qt_compact", "qt_gather", "qt_forward", "qt_loss",
     "qt_optimizer", "qt_aggregate", "qt_aggregate_dense", "qt_exchange",
     "qt_lookup_hot", "qt_lookup_cold", "qt_project", "qt_attention",
-    "qt_attention_slots", "qt_norm")
+    "qt_attention_slots", "qt_norm", "qt_relation_route")
 
 # the row-sharded store's lookup (comm.dist_lookup_local) stands where a
 # one-chip step has ``qt_gather``: ALL of it sits under ``qt_exchange``,

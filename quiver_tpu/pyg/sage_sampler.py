@@ -68,21 +68,30 @@ class Adj:
                 batch's rows, or gives a target an edge of its own, reads
                 this. ``None`` promises nothing: every target slot is
                 then taken for a node.
+    relation:   [cap_edges] int32, the relation of each edge slot (-1
+                fill) where the graph is typed (``ops.relation``):
+                ``parallel.train.layers_to_adjs`` states it for every
+                step builder. ``None`` on an untyped graph.
+    relations:  static like ``size``: how many relations the typed graph
+                has (``NodeTypes.num_relations``), stated with
+                ``relation``; ``None`` on an untyped graph.
 
     Supports PyG-style destructuring: ``edge_index, e_id, size = adj``.
     """
 
     __slots__ = ("edge_index", "e_id", "size", "mask", "fanout",
-                 "valid_targets")
+                 "valid_targets", "relation", "relations")
 
     def __init__(self, edge_index, e_id, size, mask=None, fanout=None,
-                 valid_targets=None):
+                 valid_targets=None, relation=None, relations=None):
         self.edge_index = edge_index
         self.e_id = e_id
         self.size = tuple(size)
         self.mask = mask if mask is not None else edge_index[0] >= 0
         self.fanout = fanout
         self.valid_targets = valid_targets
+        self.relation = relation
+        self.relations = relations
 
     def target_mask(self):
         """``[size[1]]`` bool: which target slots hold a node."""
@@ -97,13 +106,14 @@ class Adj:
         return self
 
     def tree_flatten(self):
-        return ((self.edge_index, self.e_id, self.mask, self.valid_targets),
-                (self.size, self.fanout))
+        return ((self.edge_index, self.e_id, self.mask, self.valid_targets,
+                 self.relation), (self.size, self.fanout, self.relations))
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        size, fanout = aux
-        return cls(leaves[0], leaves[1], size, leaves[2], fanout, leaves[3])
+        size, fanout, relations = aux
+        return cls(leaves[0], leaves[1], size, leaves[2], fanout, leaves[3],
+                   leaves[4], relations)
 
 
 class _LayerShape(NamedTuple):

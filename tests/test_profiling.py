@@ -27,6 +27,9 @@ The scope contracts:
    parts beneath ``qt_draw`` (``profiling.DRAW_STAGES``): the two
    ``indptr`` gathers under ``qt_draw_rows``, the ``scan``'s loop under
    ``qt_draw_picks``, the ``indices`` gather under ``qt_draw_neighbors``.
+   The relational model over a typed graph (``MAG240MGNN(relations=5)``)
+   has ``qt_relation_route`` beside ``qt_project`` and ``qt_attention``,
+   forward and backward, and the one-relation model has none.
    The tiered lookup names its bookkeeping (``profiling.LOOKUP_STAGES``)
    beside the tiers' reads, never inside them: the compaction's ``sort``
    under ``qt_lookup_compact``, the cold block's ``scatter`` under
@@ -48,6 +51,7 @@ from jax.sharding import Mesh
 
 from quiver_tpu import profiling
 from quiver_tpu.models import MAG240MGNN, GraphSAGE
+from quiver_tpu.ops import NodeTypes, TypedIndices
 from quiver_tpu.ops.sample_multihop import sample_multihop
 from quiver_tpu.parallel.train import (build_e2e_train_step, build_train_step,
                                        init_state, layers_to_adjs,
@@ -59,11 +63,15 @@ N, DIM, SIZES, BATCH = 400, 16, [3, 2], 8
 LOOKUP_SCOPES = {profiling.QT_LOOKUP_HOT, profiling.QT_LOOKUP_COLD}
 # the attention model's own, beneath ``qt_forward``
 MODEL_SCOPES = {profiling.QT_PROJECT, profiling.QT_ATTENTION,
-                profiling.QT_ATTENTION_SLOTS, profiling.QT_NORM}
+                profiling.QT_ATTENTION_SLOTS, profiling.QT_NORM,
+                profiling.QT_RELATION_ROUTE}
 TRAIN_SCOPES = set(profiling.DEVICE_SCOPES) - {profiling.QT_EXCHANGE} \
     - LOOKUP_SCOPES - MODEL_SCOPES
-GAT_SCOPES = (TRAIN_SCOPES - {profiling.QT_AGGREGATE,
-                              profiling.QT_AGGREGATE_DENSE}) | MODEL_SCOPES
+# the relational model over a typed graph routes its slots by relation;
+# the one-relation model has no route
+RGAT_SCOPES = (TRAIN_SCOPES - {profiling.QT_AGGREGATE,
+                               profiling.QT_AGGREGATE_DENSE}) | MODEL_SCOPES
+GAT_SCOPES = RGAT_SCOPES - {profiling.QT_RELATION_ROUTE}
 # a step over a spliced tiered store has the two tiers' reads beneath its
 # ``qt_gather``
 TIERED_SCOPES = TRAIN_SCOPES | LOOKUP_SCOPES
@@ -106,9 +114,20 @@ def world():
                      num_layers=len(SIZES), heads=2)
     gat_state = init_state(gat, tx, masked_feature_gather(feat, n_id),
                            layers_to_adjs(layers, BATCH, SIZES), key)
+    # the same graph typed: papers, authors, institutions as id ranges
+    types = NodeTypes(starts=(N // 2, N - 20),
+                      relations=((0, 0), (1, 0), (0, 1), (1, 2), (2, 1)))
+    n_id, typed_layers = sample_multihop(indptr, indices, seeds, SIZES, key,
+                                         seeds_dense=True, node_types=types)
+    rgat = MAG240MGNN(model="gat", hidden_dim=8, out_dim=4,
+                      num_layers=len(SIZES), heads=2, relations=5)
+    rgat_state = init_state(rgat, tx, masked_feature_gather(feat, n_id),
+                            layers_to_adjs(typed_layers, BATCH, SIZES), key)
     return {"model": model, "tx": tx, "state": state, "feat": feat,
             "indptr": indptr, "indices": indices, "key": key, "gat": gat,
-            "gat_state": gat_state, "n_id": n_id, "layers": layers}
+            "gat_state": gat_state, "n_id": n_id, "layers": layers,
+            "rgat": rgat, "rgat_state": rgat_state,
+            "typed": TypedIndices(indices, types)}
 
 
 def _lower(builder: str, w):
@@ -124,6 +143,12 @@ def _lower(builder: str, w):
         fn = build_train_step(w["gat"], w["tx"], SIZES, BATCH, donate=False)
         return fn.lower(w["gat_state"], w["feat"].astype(jnp.float16),
                         *graph[1:], jnp.arange(BATCH, dtype=jnp.int32),
+                        jnp.zeros((BATCH,), jnp.int32), w["key"])
+    if builder == "rgat":
+        fn = build_train_step(w["rgat"], w["tx"], SIZES, BATCH, donate=False)
+        return fn.lower(w["rgat_state"], w["feat"].astype(jnp.float16), None,
+                        w["indptr"], w["typed"],
+                        jnp.arange(BATCH, dtype=jnp.int32),
                         jnp.zeros((BATCH,), jnp.int32), w["key"])
     if builder == "tiered":
         import quiver_tpu as qv
@@ -169,7 +194,8 @@ def _op_names(lowered):
 
 @pytest.mark.parametrize("builder,scopes", [
     ("train", TRAIN_SCOPES), ("e2e", TRAIN_SCOPES), ("serve", SERVE_SCOPES),
-    ("dist", DIST_SCOPES), ("tiered", TIERED_SCOPES), ("gat", GAT_SCOPES)])
+    ("dist", DIST_SCOPES), ("tiered", TIERED_SCOPES), ("gat", GAT_SCOPES),
+    ("rgat", RGAT_SCOPES)])
 def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
     names = _op_names(_lower(builder, world))
     for scope in scopes:
@@ -178,14 +204,22 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
         # the attention model's scopes lie beneath the forward pass of
         # the step that runs it and nowhere else
         if any(s in n for s in MODEL_SCOPES):
-            assert builder == "gat" and "qt_forward" in n, n
+            assert builder in ("gat", "rgat") and "qt_forward" in n, n
         # the softmax ran over the slot axis, all of it
         if profiling.QT_ATTENTION in n:
             assert "qt_attention/qt_attention_slots/" in n, n
-    if builder == "gat":
+        # a slot's way to its relation's weight and back is the route's,
+        # beside the products and the attention, never inside them
+        if profiling.QT_RELATION_ROUTE in n:
+            assert builder == "rgat", n
+            assert profiling.QT_PROJECT not in n, n
+            assert profiling.QT_ATTENTION not in n, n
+    if builder in ("gat", "rgat"):
         assert not any(profiling.QT_AGGREGATE in n for n in names)
         for scope in (profiling.QT_PROJECT, profiling.QT_ATTENTION,
-                      profiling.QT_NORM):
+                      profiling.QT_NORM) + (
+                          (profiling.QT_RELATION_ROUTE,)
+                          if builder == "rgat" else ()):
             assert any(scope in n and "transpose(" in n for n in names), scope
     # draw and compact are the two halves of a hop, never on their own
     for n in names:
@@ -215,7 +249,7 @@ def test_scopes_reach_the_compiled_op_names(world, builder, scopes):
 
 
 @pytest.mark.parametrize("builder", ["train", "e2e", "serve", "dist", "tiered",
-                                     "gat"])
+                                     "gat", "rgat"])
 def test_the_draws_parts_lie_beneath_the_draw(world, builder):
     """``DRAW_STAGES`` reach every builder's names, each only as
     ``qt_sample_hop<i>/qt_draw/qt_draw_<stage>/``."""
@@ -274,16 +308,18 @@ def test_the_lookups_bookkeeping_lies_beside_the_tiers(world):
         assert ops and all(line in held[stage] for line in ops), opcode
 
 
-@pytest.mark.parametrize("builder", ["train", "e2e", "serve", "dist", "gat"])
+@pytest.mark.parametrize("builder", ["train", "e2e", "serve", "dist", "gat",
+                                     "rgat"])
 def test_no_other_builder_names_the_lookups_bookkeeping(world, builder):
     names = _op_names(_lower(builder, world))
     assert not any(s in n for n in names for s in profiling.LOOKUP_STAGES)
 
 
-@pytest.mark.parametrize("builder", ["train", "serve"])
+@pytest.mark.parametrize("builder", ["train", "serve", "rgat"])
 def test_the_draw_gathers_nothing_but_the_data(world, builder):
     """A hop's draw holds three ``gather``s: the two reads of ``indptr``
-    and the one of ``indices``. Its Fisher–Yates write log is read by
+    and the one of ``indices``; over a typed graph too, whose slots'
+    relations are read off the two ids by compares. Its Fisher–Yates write log is read by
     selects over the log's columns (a gather costs by the index on the
     chip: reading the log that way was 16 ms of an 89 ms step)."""
     text = _lower(builder, world).compile().as_text()
@@ -350,7 +386,7 @@ def test_the_exchange_scopes_cover_the_lookup(world):
 
 
 @pytest.mark.parametrize("builder", ["train", "serve", "dist", "tiered",
-                                     "gat"])
+                                     "gat", "rgat"])
 def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     named = _lower(builder, world).as_text()
     monkeypatch.setattr(profiling, "scope",

@@ -385,6 +385,84 @@ def test_every_builders_blocks_state_their_valid_targets(w, which):
         assert _bits(loss) == _bits(sum(shards) / np.float32(HOSTS))
 
 
+class TellRelations:
+    """In a model's place: its "logits" spell out, outermost hop first,
+    12 bits a hop, 1 + the edge slots with a relation of each block that
+    states its relations, 0 for a block that states none."""
+
+    @staticmethod
+    def apply(params, x, adjs, train=False, rngs=None):
+        told = sum(0.0 if adj.relation is None else
+                   (1 + (adj.relation >= 0).sum()).astype(jnp.float32)
+                   * 4096.0 ** j for j, adj in enumerate(adjs))
+        return jnp.zeros((x.shape[0], CLASSES)) + told + 0.0 * params["w"]
+
+
+# two types, every pair of them a relation: every sampled edge has one
+TYPES = qv.ops.NodeTypes(starts=(N // 2,),
+                         relations=((0, 0), (0, 1), (1, 0), (1, 1)))
+
+
+@pytest.mark.parametrize("typed", [True, False], ids=["typed", "untyped"])
+@pytest.mark.parametrize("which", BUILDERS)
+def test_every_builders_blocks_state_their_relations(w, which, typed):
+    """Whatever builds the step, over a typed graph (``indices`` as
+    ``ops.TypedIndices``) the model's ``Adj``s state each edge slot's
+    relation, one for every sampled edge; over an untyped graph they state
+    none."""
+    from quiver_tpu.parallel.train import TrainState
+    key = jax.random.key(29)
+    serve = which in ("serve", "sharded-serve")
+    tx = optax.sgd(0.1)
+    params = {"w": jnp.float32(1.0)}
+    state = TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+    told = type("ToldWorld", (), dict(
+        vars(w), model=TellRelations, tx=tx, state=state,
+        indices=qv.ops.TypedIndices(w.indices, TYPES) if typed
+        else w.indices))
+    kw = {} if serve else {"loss_fn": _probe_loss}
+    if which in ("train", "e2e", "dist", "split"):
+        kw["donate"] = False
+    step = _build(told, which, **kw)
+
+    def want(seeds, key):
+        _, layers = sample_multihop(w.indptr, w.indices, seeds, SIZES, key,
+                                    seeds_dense=True)
+        return [1 + int(l.edge_count) if typed else 0
+                for l in layers][::-1]           # outermost hop first
+
+    spell = lambda counts: np.float32(sum(c * 4096.0 ** j
+                                          for j, c in enumerate(counts)))
+    one = told.seeds[:BATCH]
+    if which == "split":
+        _, adjs = _call(told, which, step, key)
+        assert [a.relation is None for a in adjs] == [not typed] * len(SIZES)
+        if typed:
+            assert [1 + int((a.relation >= 0).sum()) for a in adjs] == \
+                want(one, key)
+    elif serve:
+        out = _call(told, which, step, jnp.copy(key))
+        sub = jax.random.split(key)[1]
+        assert _bits(out[1][0, 0]) == _bits(spell(want(one, sub)))
+    elif which in ("train", "gspmd"):
+        _, loss = _call(told, which, step, key)
+        assert _bits(loss) == _bits(spell(want(one, key)))
+    else:
+        _, loss = _call(told, which, step, key)
+        shards = [spell(want(told.seeds[h * BATCH:(h + 1) * BATCH],
+                             jax.random.fold_in(key, h)))
+                  for h in range(HOSTS)]
+        assert _bits(loss) == _bits(sum(shards) / np.float32(HOSTS))
+
+
+def test_the_fused_walk_refuses_a_typed_graph(w):
+    step = _build(w, "train", donate=False, **FUSED)
+    with pytest.raises(ValueError, match="typed graph"):
+        step(w.state, w.feat, None, w.indptr,
+             qv.ops.TypedIndices(w.indices, TYPES), w.seeds[:BATCH],
+             w.labels[w.seeds[:BATCH]], jax.random.key(0))
+
+
 def test_serve_and_train_reach_a_feature_store_through_one_splice(
         w, monkeypatch):
     """``parallel.frontier.feature_splice`` is the ONE function that turns
