@@ -109,8 +109,10 @@ EDGE_VALID = 26       # valid sampled edge slots, all hops of the walk
 EDGE_CAP = 27         # static edge-slot capacity of those hops
 EDGE_REL_MAX = 28     # valid edge slots of the walk's largest relation
 #                       (a typed graph's; 0 on an untyped one)
+PROJECT_ROWS = 29     # rows the model's live-rows products multiplied
+PROJECT_ROWS_CAP = 30  # rows those products' static shapes hold
 
-NUM_COUNTERS = 29
+NUM_COUNTERS = 31
 
 #: slots merged with ``max`` across steps/shards; all others add
 MAX_SLOTS = (EXCH_BUCKET_MAX, EXCH_CAP, IO_DEPTH_PEAK)
@@ -138,6 +140,7 @@ SLOT_NAMES = {
     COLD_OVERFLOW: "cold_overflow",
     EDGE_VALID: "edge_valid", EDGE_CAP: "edge_cap",
     EDGE_REL_MAX: "edge_rel_max",
+    PROJECT_ROWS: "project_rows", PROJECT_ROWS_CAP: "project_rows_cap",
 }
 
 _MAX_MASK_NP = np.zeros((NUM_COUNTERS,), bool)
@@ -170,6 +173,16 @@ class Collector:
     def peak(self, slot: int, value) -> None:
         """Merge ``value`` into a max slot."""
         self._entries.append((int(slot), value, True))
+
+    def add_sown(self, sown) -> None:
+        """Add what a model sowed into its ``counters`` collection
+        (``nn.Module.sow("counters", name, value)``, a slot's name of
+        ``SLOT_NAMES``) into the additive slots of those names."""
+        slots = {name: slot for slot, name in SLOT_NAMES.items()}
+        for path, value in jax.tree_util.tree_flatten_with_path(sown)[0]:
+            name = [k.key for k in path
+                    if isinstance(k, jax.tree_util.DictKey)][-1]
+            self.add(slots[name], value)
 
     def counters(self) -> jax.Array:
         """Materialize the ``[NUM_COUNTERS]`` int32 vector."""

@@ -40,6 +40,15 @@ logit terms are products with ``W_r a_r`` (``[in, heads]``): a slot's
 source term one more product of the same tiles, a target's under every
 relation. The existing layer is the case of one relation with its own self edge:
 the same group softmax serves both.
+
+Every projection of a layer's rows multiplies only the rows that
+something reads (``models.live_rows``): the one-relation layer's rows up
+to the largest source any slot names and the valid targets, the
+relational layer's tiles up to the end of the last relation's run. The
+rest of the output stays zero and no product runs for it; each such
+product sows the rows it multiplied and the rows its shape holds
+(``counters``: ``project_rows``, ``project_rows_cap``), which a step
+that collects metrics adds to its device counters.
 """
 
 from __future__ import annotations
@@ -49,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import profiling
+from .live_rows import live_rows_product
 
 NEG_INF = -1e30
 
@@ -171,9 +181,26 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 def _tile_rows(n: int) -> int:
-    """Rows a tile of the grouped product: a power of two near ``n / 32``,
-    from 128 to 2048."""
+    """Rows a tile of the grouped product, and a block of the one-relation
+    projection: a power of two near ``n / 32``, from 128 to 2048."""
     return 1 << max(7, min(11, (max(n, 1) // 32).bit_length() - 1))
+
+
+def live_sources(adj):
+    """How many leading rows of a layer's sources something reads: every
+    slot's source id lies below it, and so does every valid target (all
+    of ``size[1]`` where the ``Adj`` states no count). An empty slot's -1
+    moves nothing."""
+    targets = adj.size[1] if adj.valid_targets is None else adj.valid_targets
+    return jnp.maximum(jnp.max(adj.edge_index[0], initial=-1) + 1, targets)
+
+
+def _sow_rows(module, rows, cap: int):
+    """The rows a live-rows product multiplied and the rows its shape
+    holds, sown where the caller asks for ``counters`` (not at init)."""
+    if not module.is_initializing():
+        module.sow("counters", "project_rows", rows)
+        module.sow("counters", "project_rows_cap", jnp.int32(cap))
 
 
 def relation_slots(adj):
@@ -195,10 +222,11 @@ def relation_slots(adj):
 
 
 def project_by_relation(x, ws, src, rel, ok):
-    """``[E, out]`` for each ``w`` of ``ws``: each edge slot's source row
-    ``x[src]`` by its own relation's weight ``w[rel]`` (``w`` is ``[R,
-    in, out]``, the ``out`` a ``w``'s own); a slot that is not ``ok``
-    gets some finite row, which nothing downstream weighs.
+    """``([E, out] for each w of ws, rows, cap)``: each edge slot's
+    source row ``x[src]`` by its own relation's weight ``w[rel]`` (``w``
+    is ``[R, in, out]``, the ``out`` a ``w``'s own); a slot that is not
+    ``ok`` gets a zero row, which nothing downstream weighs. ``rows`` of
+    the layout's ``cap`` were multiplied.
 
     A grouped product in tiles of ``_tile_rows`` rows, each tile one
     relation's: ONE sort lays the slots out by relation (the others
@@ -207,11 +235,14 @@ def project_by_relation(x, ws, src, rel, ok):
     entries a relation, of which the first ``tiles(r) * tile - size(r)``
     carry that relation and sort behind its slots, the rest sort last;
     so no place is gathered. The sources' rows are read in that layout,
-    every tile is multiplied by its relation's weight (one batched
-    product a ``w``), and the products are read back in slot order: a
-    slot's place is its relation's first tile plus its rank among the
-    relation's slots (a cumsum a relation). The sort, the places and the
-    row moves are ``qt_relation_route``, the products ``qt_project``."""
+    the tiles up to the end of the last relation's run are multiplied,
+    each by its relation's weight picked inside the loop's turn
+    (``live_rows_product``; the tiles past it hold only empty places and
+    spare entries, and are not), and the products are read back in slot
+    order: a slot's place is its relation's first tile plus its rank
+    among the relation's slots (a cumsum a relation). The sort, the
+    places and the row moves are ``qt_relation_route``, the products'
+    loop, the weight picks in it included, ``qt_project``."""
     relations = ws[0].shape[0]
     n = src.shape[0]
     tile = _tile_rows(n)
@@ -248,14 +279,13 @@ def project_by_relation(x, ws, src, rel, ok):
                         >= (first[:relations] + runs)[None], axis=1,
                         dtype=jnp.int32)
         group = jnp.minimum(group, relations - 1)
-        weights = [w[group] for w in ws]
     with profiling.scope(profiling.QT_PROJECT):
-        rows = rows.reshape(tiles, tile, -1)
-        products = [jnp.einsum("tif,tfo->tio", rows, w
-                               ).reshape(tiles * tile, -1) for w in weights]
+        products, used = live_rows_product(
+            rows, ws, first[relations] * tile, tile, group)
     with profiling.scope(profiling.QT_RELATION_ROUTE):
         whose = jnp.pad(whose, (0, tail), constant_values=n)
-        return [_take_rows(m, back, whose) for m in products]
+        return ([_take_rows(m, back, whose) for m in products], used,
+                tiles * tile)
 
 
 def _attend_relations(p, src_term, dst_terms, rel, softmax, total):
@@ -327,6 +357,18 @@ def gat_attention(h, att_src, att_dst, adj, slope: float = 0.2):
                                 slope)
 
 
+class _Kernel(nn.Module):
+    """The projection's weight where ``nn.Dense`` keeps it
+    (``{"kernel": [in, out]}``), for a product ``nn.Dense`` does not run."""
+
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.glorot_uniform(),
+                          self.shape)
+
+
 class GATConv(nn.Module):
     """``conv(x, adj)``: ``x`` [S, in] are the layer's sources, its
     targets their first ``adj.size[1]`` rows. ``relations`` > 1 asks for
@@ -344,10 +386,12 @@ class GATConv(nn.Module):
         heads, out = self.heads, self.out_dim
         if adj.relation is not None or self.relations != 1:
             return self._typed(x, adj)
+        kernel = _Kernel((x.shape[-1], heads * out), name="lin")()
         with profiling.scope(profiling.QT_PROJECT):
-            h = nn.Dense(heads * out, use_bias=False,
-                         kernel_init=nn.initializers.glorot_uniform(),
-                         name="lin")(x)
+            # the rows past the last source and target are read by nothing
+            (h,), rows = live_rows_product(x, (kernel,), live_sources(adj),
+                                           _tile_rows(x.shape[0]))
+        _sow_rows(self, rows, x.shape[0])
         att_src = self.param("att_src", nn.initializers.glorot_uniform(),
                              (heads, out))
         att_dst = self.param("att_dst", nn.initializers.glorot_uniform(),
@@ -382,7 +426,8 @@ class GATConv(nn.Module):
             v = jnp.einsum("rfho,rho->rfh", per_head, att_src)
             u = jnp.einsum("rfho,rho->frh", per_head, att_dst)
         # a slot's row and its source's term, each by the slot's relation
-        p, at_src = project_by_relation(x, (w, v), src, rel, ok)
+        (p, at_src), rows, cap = project_by_relation(x, (w, v), src, rel, ok)
+        _sow_rows(self, rows, cap)
         with profiling.scope(profiling.QT_PROJECT):
             # the targets' terms under every relation
             dst = (x[:adj.size[1]] @ u.reshape(x.shape[-1], r * heads)

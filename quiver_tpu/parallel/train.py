@@ -54,14 +54,21 @@ def _fused_loss(model, loss_fn, walk: Walk, batch_size, params, feat, forder,
     constant and the logits slice are THE shared definition — dist/DP
     loss parity depends on there being exactly one copy. ``collector``
     (a ``metrics.Collector``) opts into device-counter telemetry:
-    sampling and the gather record counts they already compute; the loss
-    itself is untouched (bit-identical with collection on or off)."""
+    sampling and the gather record counts they already compute, and the
+    model what it sows into its ``counters`` collection (the attention
+    layers' live-rows products); the loss itself is untouched
+    (bit-identical with collection on or off)."""
     _, x, layers = walk_frontier(walk, feat, forder, indptr, indices, seeds,
                                  key, indices_rows, collector)
     adjs = layers_to_adjs(layers, batch_size, walk.sizes)
+    rngs = {"dropout": jax.random.fold_in(key, 1000)}
     with profiling.scope(profiling.QT_FORWARD):
-        logits = model.apply(params, x, adjs, train=True,
-                             rngs={"dropout": jax.random.fold_in(key, 1000)})
+        if collector is None:
+            logits = model.apply(params, x, adjs, train=True, rngs=rngs)
+        else:
+            logits, sown = model.apply(params, x, adjs, train=True,
+                                       rngs=rngs, mutable=["counters"])
+            collector.add_sown(sown)
     with profiling.scope(profiling.QT_LOSS):
         return loss_fn(logits[:batch_size], labels)
 

@@ -445,3 +445,46 @@ def test_the_attention_step_fits_beside_a_float16_table(topo, shape_on_chip):
     assert memory.temp_size_in_bytes < 6 * block, memory
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
         < 15.75e9
+
+
+def test_the_attention_layer_projects_its_live_blocks_in_one_loop(
+        topo, shape_on_chip):
+    """The same first layer with its projection as ``GATConv`` runs it
+    (``models.live_rows``, 2,048-row blocks up to ``gat.live_sources``):
+    the product's blocks are multiplied in ONE ``while`` forward and one
+    backward, and the layer's temporaries, forward and backward, are no
+    larger than with the dense product over every row."""
+    import re
+    from quiver_tpu.models.gat import _tile_rows, gat_attention, live_sources
+    from quiver_tpu.models.live_rows import live_rows_product
+    from quiver_tpu.parallel.frontier import masked_feature_gather
+    from quiver_tpu.pyg.sage_sampler import Adj
+    nodes, width, hidden, heads = 3_804_740, 768, 1024, 4
+    targets, k = 26_624, 15
+    sources = targets * (k + 1)
+    assert _tile_rows(sources) == 2048
+
+    def loss_of(live: bool):
+        def loss(w, a_src, a_dst, feat, n_id, src, count):
+            x = masked_feature_gather(feat, n_id)
+            adj = Adj(jnp.stack([src, src]), None, (sources, targets),
+                      fanout=k, valid_targets=count)
+            h = (live_rows_product(x, (w,), live_sources(adj), 2048)[0][0]
+                 if live else x @ w)
+            return (gat_attention(h, a_src, a_dst, adj) ** 2).sum()
+        return loss
+
+    compiled = {live: jax.jit(jax.grad(loss_of(live), argnums=(0, 1, 2))).lower(
+        shape_on_chip((width, hidden), jnp.float32),
+        shape_on_chip((heads, hidden // heads), jnp.float32),
+        shape_on_chip((heads, hidden // heads), jnp.float32),
+        shape_on_chip((nodes, width), jnp.float16),
+        shape_on_chip((sources,), jnp.int32),
+        shape_on_chip((targets * k,), jnp.int32),
+        shape_on_chip((), jnp.int32)).compile() for live in (False, True)}
+    text = compiled[True].as_text()
+    assert len(re.findall(r" while\(", text)) == 2
+    assert "f32[2048,1024]" in text                 # a block's product
+    assert not re.search(r" while\(", compiled[False].as_text())
+    assert compiled[True].memory_analysis().temp_size_in_bytes \
+        <= compiled[False].memory_analysis().temp_size_in_bytes

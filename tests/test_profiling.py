@@ -355,6 +355,33 @@ def test_which_attention_path_ran_is_in_the_names(world):
                if profiling.QT_ATTENTION in n)
 
 
+@pytest.mark.parametrize("builder", ["gat", "rgat"])
+def test_the_projections_loops_lie_under_qt_project(world, builder):
+    """Each attention layer projects its live row blocks in one loop
+    forward and one backward (``models.live_rows``), every one of them
+    ``qt_project``'s, and the blocks' products sit in their bodies; over
+    a typed graph a turn's pick of its relation's weight (an index into
+    the relations' stack, a ``dynamic-slice``) lies there too:
+    ``qt_project``, never ``qt_relation_route``."""
+    named = _names_and_ops(_lower(builder, world).compile().as_text())
+    loops = [n for n, line in named
+             if " while(" in line and re.search(r"/conv\d", n)]
+    assert len(loops) == 2 * len(SIZES)
+    assert sum("transpose(" in n for n in loops) == len(SIZES)
+    assert all(re.search(r"qt_project\)?/while$", n) for n in loops), loops
+    dots = {n for n, line in named
+            if re.search(r" (dot|convolution)\(", line) and "/while/" in n}
+    assert dots and all("qt_project/while/body/" in n for n in dots), dots
+    # a relation's [in, out] weight out of the [R, in, out] stack
+    picks = [n for n, line in named if "/while/" in n and re.search(
+        r"= f32\[1,\d+,\d+\]\S* dynamic-slice\(", line)]
+    if builder == "rgat":
+        assert picks and all("qt_project/while/body/" in n for n in picks)
+        assert not any(profiling.QT_RELATION_ROUTE in n for n in picks)
+    else:
+        assert not picks
+
+
 def _names_and_ops(text):
     """``(op_name, instruction text)`` of a compiled program's lines."""
     return [(m.group(1), line) for line in text.splitlines()
