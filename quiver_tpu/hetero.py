@@ -12,7 +12,11 @@ module supports that *and* true multi-relation sampling for R-GCN:
 - ``HeteroGraphSageSampler``: per hop, every relation samples ``k`` of
   the current dst-type frontier's neighbors; per node type, the frontier
   union is compacted with the same first-occurrence static-shape
-  compaction as the homogeneous path.
+  compaction as the homogeneous path. The hop is the per-relation walk's
+  own (``ops.sample_multihop.typed_hop``, which ``build_train_step``
+  runs over an ``ops.RelationIndices`` graph); this sampler brings the
+  draw modes (rotation, window, weighted, edge ids) and the local-id
+  topology, not a draw of its own.
 
 All shapes static; same -1 masking contract as the homogeneous sampler.
 """
@@ -26,10 +30,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .ops.sample import (as_index_rows, as_index_rows_overlapping,
-                         compact_union, compose_slot_map, edge_row_ids,
-                         reshuffle_csr, sample_layer,
-                         sample_layer_exact_wide, sample_layer_rotation,
-                         sample_layer_window, suggest_hub_cap)
+                         compose_slot_map, edge_row_ids, reshuffle_csr,
+                         sample_layer, sample_layer_exact_wide,
+                         sample_layer_rotation, sample_layer_window,
+                         suggest_hub_cap)
+from .ops.sample_multihop import typed_hop
 from .ops.weighted import sample_layer_weighted
 from .pyg.sage_sampler import Adj
 from .utils import CSRTopo
@@ -103,7 +108,8 @@ class HeteroGraphSageSampler:
     or ``{node_type: int}``): multi-relation expansion otherwise grows
     frontier caps multiplicatively per hop. Sampled edges whose source
     falls past the cap are masked (-1) — the same static-capacity
-    truncation contract as every other capped shape here.
+    truncation contract as every other capped shape here — and counted
+    (``typed_hop``'s ``drops``).
 
     ``edge_weight`` (``{edge_type: CSR-slot-aligned weights}``) switches
     the listed relations to weighted (attention) draws — with
@@ -264,114 +270,82 @@ class HeteroGraphSageSampler:
         # closed-over device array is embedded in the HLO as a literal
         # constant, and MAG240M-scale relations would bloat the
         # executable by their size — same hazard bench.py documents
+        edge_types = self.topo.edge_types
+        pairs = tuple((node_types.index(dst), node_types.index(src))
+                      for src, _, dst in edge_types)
+        cap_of = [None if not caps else caps.get(t) for t in node_types]
+
         def run(seeds, key, rows, rels, weights, eids):
-            frontier = {t: None for t in node_types}
-            frontier[seed_type] = seeds.astype(jnp.int32)
+            def draw(r, cur, k, sub):
+                # one relation's draw, in this sampler's mode
+                et = edge_types[r]
+                indptr, indices = rels[et]
+                w = weights.get(et)
+                if w is not None:
+                    out = sample_layer_weighted(indptr, indices, w, cur, k,
+                                                sub, with_slots=with_eid)
+                elif method == "rotation":
+                    out = sample_layer_rotation(indptr, rows[et], cur, k,
+                                                sub, stride=stride,
+                                                with_slots=with_eid)
+                elif method == "window":
+                    out = sample_layer_window(indptr, rows[et], cur, k, sub,
+                                              stride=stride,
+                                              with_slots=with_eid)
+                elif rows is not None:
+                    # scattered-load budget from the relation's own
+                    # cached degree-bucket split (CSRTopo metadata,
+                    # shared across batch sizes and epochs); static
+                    # because the frontier width is a compile-time shape
+                    out = sample_layer_exact_wide(
+                        indptr, indices, rows[et], cur, k, sub,
+                        stride=stride, with_slots=with_eid,
+                        hub_cap=suggest_hub_cap(int(cur.shape[0]),
+                                                hub_fracs.get(et)))
+                else:
+                    out = sample_layer(indptr, indices, cur, k, sub,
+                                       with_slots=with_eid)
+                slots = out[2] if with_eid else None
+                if slots is not None and et in eids:
+                    # CSR slot -> original COO edge id (CSRTopo.eid)
+                    e = eids[et]
+                    slots = jnp.where(
+                        slots >= 0,
+                        e[jnp.clip(slots, 0, e.shape[0] - 1)]
+                        .astype(slots.dtype), -1)
+                return out[0], slots
+
+            frontier = [None] * len(node_types)
+            count = [None] * len(node_types)
+            at = node_types.index(seed_type)
+            frontier[at] = seeds.astype(jnp.int32)
+            count[at] = jnp.sum(seeds >= 0, dtype=jnp.int32)
             hops = []
-            step = 0
             for hop, fanouts in enumerate(sizes):
-                per_rel_samples: Dict[EdgeType, tuple] = {}
-                # 1. sample every relation whose dst type has a frontier
-                for et, k in fanouts.items():
-                    src_t, _, dst_t = et
-                    cur = frontier[dst_t]
-                    if cur is None or k <= 0:
-                        continue
-                    sub = jax.random.fold_in(key, step)
-                    step += 1
-                    indptr, indices = rels[et]
-
-                    def unpack(out):
-                        # (nbrs, counts[, slots]) -> (nbrs, slots|None)
-                        return (out[0], out[2] if with_eid else None)
-
-                    w = weights.get(et)
-                    if w is not None:
-                        nbrs, slots = unpack(sample_layer_weighted(
-                            indptr, indices, w, cur, k, sub,
-                            with_slots=with_eid))
-                    elif method == "rotation":
-                        nbrs, slots = unpack(sample_layer_rotation(
-                            indptr, rows[et], cur, k, sub, stride=stride,
-                            with_slots=with_eid))
-                    elif method == "window":
-                        nbrs, slots = unpack(sample_layer_window(
-                            indptr, rows[et], cur, k, sub, stride=stride,
-                            with_slots=with_eid))
-                    elif rows is not None:
-                        # scattered-load budget from the relation's own
-                        # cached degree-bucket split (CSRTopo metadata,
-                        # shared across batch sizes and epochs); static
-                        # because the frontier width is a compile-time
-                        # shape
-                        nbrs, slots = unpack(sample_layer_exact_wide(
-                            indptr, indices, rows[et], cur, k, sub,
-                            stride=stride, with_slots=with_eid,
-                            hub_cap=suggest_hub_cap(
-                                int(cur.shape[0]), hub_fracs.get(et))))
-                    else:
-                        nbrs, slots = unpack(sample_layer(
-                            indptr, indices, cur, k, sub,
-                            with_slots=with_eid))
-                    if slots is not None and et in eids:
-                        # CSR slot -> original COO edge id (CSRTopo.eid)
-                        e = eids[et]
-                        slots = jnp.where(
-                            slots >= 0,
-                            e[jnp.clip(slots, 0, e.shape[0] - 1)]
-                            .astype(slots.dtype), -1)
-                    per_rel_samples[et] = (cur, nbrs, slots)
-                # 2. per src type: compact (old frontier ++ all sampled)
-                new_frontier = dict(frontier)
-                new_counts = {}
+                # the walk's one hop (``ops.sample_multihop.typed_hop``):
+                # every relation whose dst type has a frontier draws,
+                # each src type's frontier is the union of itself and its
+                # picks, a cap masks (and counts) what it cannot take
+                layer = typed_hop(frontier, count, pairs, draw,
+                                  [fanouts.get(et, 0) for et in edge_types],
+                                  jax.random.fold_in(key, hop), cap_of)
                 adjs = {}
-                by_src: Dict[str, list] = {}
-                for et, (cur, nbrs, slots) in per_rel_samples.items():
-                    by_src.setdefault(et[0], []).append(
-                        (et, cur, nbrs, slots))
-                for src_t, group in by_src.items():
-                    prev = frontier[src_t]
-                    prev = prev if prev is not None else \
-                        jnp.full((0,), -1, jnp.int32)
-                    all_nbrs = jnp.concatenate(
-                        [nbrs.reshape(-1) for _, _, nbrs, _ in group])
-                    n_id, n_count, extra_local = compact_union(prev, all_nbrs)
-                    cap = caps.get(src_t) if caps else None
-                    if cap is not None and n_id.shape[0] > cap:
-                        # static-capacity truncation: keep the seeds-
-                        # first prefix, mask edges whose source fell
-                        # past the cap (same -1 contract as everywhere)
-                        n_id = n_id[:cap]
-                        n_count = jnp.minimum(n_count, cap)
-                        extra_local = jnp.where(
-                            extra_local < cap, extra_local, -1)
-                    # n_id holds prev ++ unique new, first-occurrence order
-                    new_frontier[src_t] = n_id
-                    new_counts[src_t] = n_count
-                    # 3. per relation: local COO against the merged frontier
-                    offset = 0
-                    for et, cur, nbrs, slots in group:
-                        s, kk = nbrs.shape
-                        flat = extra_local[offset:offset + s * kk]
-                        offset += s * kk
-                        row = jnp.where(
-                            flat >= 0,
-                            jnp.repeat(jnp.arange(s, dtype=jnp.int32), kk),
-                            -1)
-                        edge_index = jnp.stack([flat, row])
-                        e_id = None
-                        if slots is not None:
-                            # frontier-cap truncation masks the edge in
-                            # flat; its e_id masks with it
-                            e_id = jnp.where(flat >= 0,
-                                             slots.reshape(-1), -1)
-                        adjs[et] = Adj(
-                            edge_index=edge_index, e_id=e_id,
-                            size=(int(n_id.shape[0]), s),
-                            mask=flat >= 0)
-                hops.append((adjs, dict(new_frontier), new_counts))
-                frontier = new_frontier
-            return frontier, hops
+                for r, et in enumerate(edge_types):
+                    col = layer.col[r]
+                    if col is None:
+                        continue
+                    t, s = pairs[r]
+                    adjs[et] = Adj(
+                        edge_index=jnp.stack([col, layer.row[r]]),
+                        e_id=layer.slot[r],
+                        size=(int(layer.frontier[s].shape[0]),
+                              int(frontier[t].shape[0])),
+                        mask=col >= 0)
+                frontier, count = layer.frontier, layer.count
+                hops.append((adjs, dict(zip(node_types, frontier)),
+                             {t: c for t, c in zip(node_types, count)
+                              if c is not None}))
+            return dict(zip(node_types, frontier)), hops
 
         return jax.jit(run)
 

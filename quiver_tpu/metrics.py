@@ -111,8 +111,10 @@ EDGE_REL_MAX = 28     # valid edge slots of the walk's largest relation
 #                       (a typed graph's; 0 on an untyped one)
 PROJECT_ROWS = 29     # rows the model's live-rows products multiplied
 PROJECT_ROWS_CAP = 30  # rows those products' static shapes hold
+TYPE_CAP_DROPS = 31   # picks of a per-relation walk that a node type's
+#                       frontier cap could not take (masked, counted)
 
-NUM_COUNTERS = 31
+NUM_COUNTERS = 32
 
 #: slots merged with ``max`` across steps/shards; all others add
 MAX_SLOTS = (EXCH_BUCKET_MAX, EXCH_CAP, IO_DEPTH_PEAK)
@@ -141,6 +143,7 @@ SLOT_NAMES = {
     EDGE_VALID: "edge_valid", EDGE_CAP: "edge_cap",
     EDGE_REL_MAX: "edge_rel_max",
     PROJECT_ROWS: "project_rows", PROJECT_ROWS_CAP: "project_rows_cap",
+    TYPE_CAP_DROPS: "type_cap_drops",
 }
 
 _MAX_MASK_NP = np.zeros((NUM_COUNTERS,), bool)

@@ -36,14 +36,18 @@ from jax import lax
 from jax.custom_derivatives import SymbolicZero
 
 
-def live_rows_product(x, ws, live, block: int, group=None):
+def live_rows_product(x, ws, live, block: int, group=None,
+                      clean: bool = False):
     """``(ys, rows)``: for each weight ``w`` of the tuple ``ws``, ``y``
     [N, out] is ``x @ w`` on rows ``[0, rows)`` and zero past them (a turn
     reads its rows once for all the weights); ``rows = min(ceil(live /
     block) * block, N)`` the rows the loop multiplied (an int32 scalar,
     what the step's ``project_rows`` counter records). Rows of ``x`` at
     or past ``rows`` are never read, forward or backward. ``block`` is
-    clipped to ``N``; with ``group``, ``N`` must be a multiple of it."""
+    clipped to ``N``; with ``group``, ``N`` must be a multiple of it.
+    ``clean`` takes the rows of a turn at or past ``live`` for zero rows,
+    forward and backward, so that nothing they hold (a padding slot's
+    NaN) reaches an output or a gradient."""
     n = x.shape[0]
     block = max(1, min(int(block), n))
     if group is not None and n % block:
@@ -51,12 +55,23 @@ def live_rows_product(x, ws, live, block: int, group=None):
                          f"in blocks of {block}")
     live = jnp.clip(jnp.asarray(live, jnp.int32), 0, n)
     turns = (live + block - 1) // block
-    return (_product(x, tuple(ws), turns, group, block),
+    return (_product(x, tuple(ws), turns, group, live if clean else None,
+                     block),
             jnp.minimum(turns * block, n))
 
 
 def _start(b, block: int, n: int):
     return jnp.minimum(b * block, n - block)
+
+
+def _rows(x, start, block: int, limit):
+    """The turn's ``block`` rows from ``start``; with ``limit``, those at
+    or past it read as zero."""
+    xb = lax.dynamic_slice_in_dim(x, start, block)
+    if limit is None:
+        return xb
+    keep = start + jnp.arange(block, dtype=jnp.int32) < limit
+    return jnp.where(keep[:, None], xb, 0)
 
 
 def _pick(w, group, b):
@@ -77,8 +92,8 @@ def _unstack(stack, n: int, block: int):
     return rows
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _product(x, ws, turns, group, block):
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _product(x, ws, turns, group, limit, block):
     # the output is written a block at a time into a stack whose leading
     # index is the turn's: on the chip, a run-time row offset into the
     # [N, out] block kept that block beside a second one in the compiled
@@ -86,7 +101,7 @@ def _product(x, ws, turns, group, block):
     n = x.shape[0]
 
     def turn(b, ys):
-        xb = lax.dynamic_slice_in_dim(x, _start(b, block, n), block)
+        xb = _rows(x, _start(b, block, n), block, limit)
         return tuple(lax.dynamic_update_index_in_dim(
             y, jnp.dot(xb, _pick(w, group, b)).astype(y.dtype), b, 0)
             for w, y in zip(ws, ys))
@@ -97,17 +112,18 @@ def _product(x, ws, turns, group, block):
                  for y in lax.fori_loop(0, turns, turn, zeros))
 
 
-def _product_fwd(x, ws, turns, group, block):
+def _product_fwd(x, ws, turns, group, limit, block):
     # ``symbolic_zeros``: every primal comes with whether it is perturbed
     xv, wv = x.value, tuple(w.value for w in ws)
     turns = turns.value
     group = None if group is None else group.value
-    return (_product(xv, wv, turns, group, block),
-            (xv, wv, turns, group, x.perturbed))
+    limit = None if limit is None else limit.value
+    return (_product(xv, wv, turns, group, limit, block),
+            (xv, wv, turns, group, limit, x.perturbed))
 
 
 def _product_bwd(block, res, gs):
-    x, ws, turns, group, want_dx = res
+    x, ws, turns, group, limit, want_dx = res
     n = x.shape[0]
     # a weight whose output nothing differentiates adds no work
     moved = [i for i, g in enumerate(gs) if not isinstance(g, SymbolicZero)]
@@ -116,7 +132,7 @@ def _product_bwd(block, res, gs):
     def turn(b, carry):
         dws, dx = carry
         start = _start(b, block, n)
-        xb = lax.dynamic_slice_in_dim(x, start, block)
+        xb = _rows(x, start, block, limit)
         if n % block:
             # the clamped last turn reads again rows an earlier one summed
             fresh = start + jnp.arange(block) >= b * block
@@ -146,7 +162,7 @@ def _product_bwd(block, res, gs):
     grads = [None] * len(ws)
     for j, i in enumerate(moved):
         grads[i] = dws[j]
-    return dx, tuple(grads), None, None
+    return dx, tuple(grads), None, None, None
 
 
 _product.defvjp(_product_fwd, _product_bwd, symbolic_zeros=True)

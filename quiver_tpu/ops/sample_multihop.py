@@ -4,13 +4,14 @@ jitted training step)."""
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import profiling
-from .sample import (LayerSample, as_index_rows, as_index_rows_overlapping,
+from .sample import (LayerSample, _compact_core, as_index_rows,
+                     as_index_rows_overlapping,
                      compact_layer, edge_rows, permute_csr, sample_layer,
                      sample_layer_exact_wide, sample_layer_rotation,
                      sample_layer_window, suggest_hub_cap)
@@ -241,6 +242,176 @@ def count_walk(collector, n_id, layers) -> None:
         collector.add(EDGE_REL_MAX, jnp.max(jnp.stack([
             sum(jnp.sum(l.rel == r, dtype=jnp.int32) for l in layers)
             for r in range(types.num_relations)])))
+
+
+class TypedLayer(NamedTuple):
+    """One hop of the per-relation walk (``typed_hop``), fixed shapes.
+    Types and relations are indices of the walk's ``NodeTypes``.
+
+    frontier:   per node type, ``[cap]`` LOCAL ids after the hop: the
+                type's frontier before the hop keeps its slots at the
+                head, the new nodes follow in ascending id order, -1
+                fill; None where the type has no frontier yet
+    count:      per node type, ``[]`` valid entries of ``frontier``
+    col:        per relation, ``[targets x k]``: slot ``e`` holds a pick
+                of target ``e // k`` (``k`` the relation's fanout), its
+                place in the source type's ``frontier``; -1 fill. None
+                where the relation was not drawn (its target type had no
+                frontier, or its fanout is 0)
+    row:        per relation, the target's place (``e // k``), -1 fill
+    edge_count: per relation, ``[]`` valid slots
+    seed_count: per relation, ``[]`` valid targets (the target type's
+                ``count`` before the hop)
+    slot:       per relation, the CSR slot of each pick (-1 fill) where
+                the draw hands it out, else None
+    drops:      ``[]`` valid picks masked because their node fell past
+                its type's cap
+    types:      the walk's ``NodeTypes`` (static, no leaves), where the
+                walk has one
+    """
+
+    frontier: tuple
+    count: tuple
+    col: tuple
+    row: tuple
+    edge_count: tuple
+    seed_count: tuple
+    slot: tuple
+    drops: jax.Array
+    types: object = None
+
+
+def typed_hop(frontier, count, pairs, draw, fanouts, key, caps=None,
+              dense: bool = False) -> TypedLayer:
+    """One hop over a typed graph whose relations keep their own lists:
+    every relation ``r = (t, s)`` of ``pairs`` whose target type has a
+    frontier and whose ``fanouts[r]`` is positive draws ``draw(r,
+    frontier[t], k, fold_in(key, r)) -> (picks [len, k], slots or
+    None)`` (``picks`` in type ``s``'s local ids, -1 fill) under
+    ``qt_draw_relation``; then, under ``qt_type_union``, each type's
+    frontier becomes the first-occurrence union of itself (the prefix:
+    its valid entries, distinct, keep their slots) and the picks of it,
+    in relation order, cut to ``caps[s]`` where that is smaller. A pick
+    whose node falls past the cap is masked and counted in ``drops``.
+    ``dense`` promises every frontier is valid-first (a walk's always
+    are; hop 0's batch where the caller says so)."""
+    relations, types = len(pairs), len(frontier)
+    picks = [None] * relations
+    slots = [None] * relations
+    with profiling.scope(profiling.QT_DRAW):
+        for r, (t, _) in enumerate(pairs):
+            k = fanouts[r]
+            if frontier[t] is None or not k:
+                continue
+            with profiling.scope(profiling.QT_DRAW_RELATION):
+                picks[r], slots[r] = draw(r, frontier[t], int(k),
+                                          jax.random.fold_in(key, r))
+    new_f, new_c = list(frontier), list(count)
+    col = [None] * relations
+    drops = jnp.int32(0)
+    with profiling.scope(profiling.QT_COMPACT):
+        with profiling.scope(profiling.QT_TYPE_UNION):
+            for s in range(types):
+                mine = [r for r in range(relations)
+                        if picks[r] is not None and pairs[r][1] == s]
+                if not mine:
+                    continue
+                prev = frontier[s] if frontier[s] is not None \
+                    else jnp.full((0,), -1, jnp.int32)
+                p = prev.shape[0]
+                flat = jnp.concatenate([picks[r].reshape(-1) for r in mine])
+                n_id, n_count, local = _compact_core(
+                    jnp.concatenate([prev.astype(jnp.int32), flat]), p,
+                    seeds_dense=dense)
+                extra = jnp.where(flat >= 0, local[p:], -1)
+                cap = None if caps is None else caps[s]
+                if cap is not None and cap < n_id.shape[0]:
+                    n_id, n_count = n_id[:cap], jnp.minimum(n_count, cap)
+                    past = extra >= cap
+                    drops = drops + jnp.sum(past, dtype=jnp.int32)
+                    extra = jnp.where(past, -1, extra)
+                new_f[s], new_c[s] = n_id, n_count
+                at = 0
+                for r in mine:
+                    size = picks[r].size
+                    col[r] = extra[at:at + size]
+                    at += size
+        row = [None] * relations
+        edges = [None] * relations
+        seeds = [None] * relations
+        for r, (t, _) in enumerate(pairs):
+            if col[r] is None:
+                continue
+            targets, k = picks[r].shape
+            row[r] = jnp.where(col[r] >= 0, jnp.repeat(
+                jnp.arange(targets, dtype=jnp.int32), k), -1)
+            edges[r] = jnp.sum(col[r] >= 0, dtype=jnp.int32)
+            seeds[r] = count[t]
+            if slots[r] is not None:
+                slots[r] = jnp.where(col[r] >= 0, slots[r].reshape(-1), -1)
+    return TypedLayer(tuple(new_f), tuple(new_c), tuple(col), tuple(row),
+                      tuple(edges), tuple(seeds), tuple(slots), drops)
+
+
+def sample_typed_multihop(indptrs, graph, seeds: jax.Array,
+                          sizes: Sequence[int], key: jax.Array,
+                          collector=None, seeds_dense: bool = True):
+    """The per-relation walk over ``graph`` (``ops.relation
+    .RelationIndices``; ``indptrs`` its relations' row pointers): hop
+    ``i`` runs ``typed_hop`` from ``fold_in(key, i)`` with fanout
+    ``sizes[i]`` for every relation and the graph's caps for hop ``i``,
+    each relation drawing ``min(degree, k)`` distinct neighbours a target
+    with the exact draw (``sample_layer``: a Fisher-Yates write log, so a
+    hub row costs what any row does). ``seeds`` are ids of type 0 (-1
+    fill; valid-first where ``seeds_dense``).
+
+    Returns ``(n_id, layers)``: the final frontiers of every type as
+    global ids, in type order, each at its static cap (-1 fill), and the
+    ``TypedLayer``s in sampling order. ``collector`` records the type
+    frontiers' fill, the relation blocks' fill and ``type_cap_drops``."""
+    types = graph.types
+    frontier = [None] * types.num_types
+    count = [None] * types.num_types
+    frontier[0] = seeds.astype(jnp.int32)
+    count[0] = jnp.sum(seeds >= 0, dtype=jnp.int32)
+
+    def draw(r, targets, k, sub):
+        return sample_layer(indptrs[r], graph.indices[r], targets, k,
+                            sub)[0], None
+
+    layers = []
+    for i, k in enumerate(sizes):
+        with jax.named_scope(f"qt_sample_hop{i}"):
+            layer = typed_hop(
+                frontier, count, types.relations, draw,
+                [k] * types.num_relations, jax.random.fold_in(key, i),
+                [graph.cap(t, i) for t in range(types.num_types)],
+                dense=(i > 0) or seeds_dense)
+        layers.append(layer._replace(types=types))
+        frontier, count = layer.frontier, layer.count
+    n_id = jnp.concatenate([jnp.where(f >= 0, f + types.start(t), -1)
+                            for t, f in enumerate(frontier) if f is not None])
+    count_typed_walk(collector, layers)
+    return n_id, layers
+
+
+def count_typed_walk(collector, layers) -> None:
+    """``count_walk`` of the per-relation walk: the final type frontiers'
+    valid rows against their caps, the relation blocks' valid slots
+    against theirs over every hop, and the picks no cap could take."""
+    if collector is None:
+        return
+    from ..metrics import (EDGE_CAP, EDGE_VALID, FRONTIER_CAP,
+                           FRONTIER_VALID, TYPE_CAP_DROPS)
+    last = layers[-1]
+    collector.add(FRONTIER_VALID, sum(c for c in last.count if c is not None))
+    collector.add(FRONTIER_CAP, sum(int(f.shape[0]) for f in last.frontier
+                                    if f is not None))
+    collector.add(EDGE_VALID, sum(e for l in layers for e in l.edge_count
+                                  if e is not None))
+    collector.add(EDGE_CAP, sum(int(c.shape[0]) for l in layers for c in l.col
+                                if c is not None))
+    collector.add(TYPE_CAP_DROPS, sum(l.drops for l in layers))
 
 
 def sample_multihop_dedup(indptr: jax.Array, indices: jax.Array,

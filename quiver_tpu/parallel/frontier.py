@@ -28,10 +28,11 @@ import jax.numpy as jnp
 
 from .. import profiling
 from ..comm import default_exchange_cap
-from ..ops.relation import split_typed
-from ..ops.sample_multihop import count_walk, sample_multihop
+from ..ops.relation import RelationIndices, split_typed
+from ..ops.sample_multihop import (TypedLayer, count_walk, sample_multihop,
+                                   sample_typed_multihop)
 from ..profiling import hot_path
-from ..pyg.sage_sampler import Adj, layer_shapes
+from ..pyg.sage_sampler import Adj, RelationBlocks, layer_shapes
 
 
 def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
@@ -46,7 +47,14 @@ def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
     Each ``Adj`` also states how many of its target slots hold a node
     (``valid_targets``: the hop's count of valid seeds) and, over a typed
     graph, each edge slot's relation (``relation``) and the graph's count
-    of relations (``relations``), for every step builder alike."""
+    of relations (``relations``), for every step builder alike.
+
+    The ``TypedLayer``s of a per-relation walk become ``RelationBlocks``,
+    one a hop (outermost first), each with an ``Adj`` a relation drawn:
+    its slot ``e`` targets ``e // fanout`` as above, and it states its
+    relation as a static int, its ``fanout`` and its ``valid_targets``."""
+    if layers and isinstance(layers[0], TypedLayer):
+        return _relation_blocks(layers, sizes)
     shapes = layer_shapes(batch_size, sizes)
     adjs = []
     for layer, shape in zip(layers, shapes):
@@ -59,6 +67,30 @@ def layers_to_adjs(layers, batch_size: int, sizes: Sequence[int]):
                         relations=(None if layer.types is None
                                    else layer.types.num_relations)))
     return adjs[::-1]
+
+
+def _relation_blocks(layers, sizes):
+    """``layers_to_adjs`` of a per-relation walk's ``TypedLayer``s."""
+    types = layers[0].types
+    out, before = [], None
+    for layer, k in zip(layers, sizes):
+        sources = [0 if f is None else int(f.shape[0]) for f in layer.frontier]
+        if before is None:
+            # hop 0's targets are the batch, whose rows its blocks target
+            before = [0] * len(sources)
+            for r, col in enumerate(layer.col):
+                if col is not None:
+                    before[types.relations[r][0]] = int(col.shape[0]) // k
+        adjs = [Adj(edge_index=jnp.stack([col, layer.row[r]]), e_id=None,
+                    size=(sources[types.relations[r][1]],
+                          before[types.relations[r][0]]),
+                    mask=col >= 0, fanout=int(k),
+                    valid_targets=layer.seed_count[r], relation=r,
+                    relations=types.num_relations)
+                for r, col in enumerate(layer.col) if col is not None]
+        out.append(RelationBlocks(adjs, sources, before, types))
+        before = sources
+    return out[::-1]
 
 
 @hot_path
@@ -368,10 +400,36 @@ def walk_frontier(walk: Walk, feat, forder, indptr, indices, seeds, key,
 
     ``indices`` may be an ``ops.relation.TypedIndices``: the graph is
     then typed, and every hop's ``LayerSample.rel`` holds each edge
-    slot's relation."""
+    slot's relation. It may be an ``ops.relation.RelationIndices``
+    (``indptr`` the tuple of its relations' row pointers): every relation
+    then draws its own ``min(degree, fanout)`` a target
+    (``sample_typed_multihop``), each node type keeps a frontier of its
+    own, ``layers`` are ``TypedLayer``s and ``x`` is the tuple of the
+    types' row blocks (None where a type has no frontier), each read
+    from the one table by its global ids."""
     if rows_collector is None:
         rows_collector = collector
     walk.check_rows(indices_rows)
+    if isinstance(indices, RelationIndices):
+        if walk.method != "exact" or indices_rows is not None \
+                or walk.row_cap is not None:
+            raise ValueError(
+                f"{walk.who}: a graph whose relations keep their own lists "
+                "takes the exact draw, without indices_rows or the fused "
+                "walk")
+        n_id, layers = sample_typed_multihop(
+            tuple(indptr), indices, seeds, walk.sizes, key,
+            collector=collector)
+        gather = walk.gather or masked_feature_gather
+        x, at = [], 0
+        for f in layers[-1].frontier:
+            if f is None:
+                x.append(None)
+                continue
+            x.append(gather(feat, n_id[at:at + f.shape[0]], forder,
+                            collector=rows_collector))
+            at += f.shape[0]
+        return n_id, tuple(x), layers
     indices, types = split_typed(indices)
     if walk.row_cap is None:
         n_id, layers = sample_multihop(

@@ -86,6 +86,17 @@ scope = jax.named_scope
 (QT_DRAW_ROWS, QT_DRAW_PICKS, QT_DRAW_NEIGHBORS) = DRAW_STAGES = tuple(
     QT_DRAW + "_" + stage for stage in ("rows", "picks", "neighbors"))
 
+# the per-relation walk (ops/sample_multihop.py ``typed_hop``, where
+# every relation of a typed graph keeps its own neighbour lists and
+# draws its own fanout): ``qt_draw_relation`` around one relation's draw,
+# beneath the hop's ``qt_draw`` (the draw's own stages above beneath
+# it); ``qt_type_union`` around the first-occurrence union of each node
+# type's frontier with that hop's picks of it, the cap and the count of
+# what a cap could not take, beneath the hop's ``qt_compact``. The
+# relations' blocks in local ids stay under ``qt_compact`` alone.
+(QT_DRAW_RELATION, QT_TYPE_UNION) = TYPED_STAGES = (
+    QT_DRAW + "_relation", "qt_type_union")
+
 # the bookkeeping of a tiered store's lookup, beneath its ``qt_gather``
 # as siblings of ``qt_lookup_hot`` / ``qt_lookup_cold`` and never inside
 # them (feature.py ``lookup_tiered_rows``): ``_translate`` the read of

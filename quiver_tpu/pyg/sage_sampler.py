@@ -71,7 +71,10 @@ class Adj:
     relation:   [cap_edges] int32, the relation of each edge slot (-1
                 fill) where the graph is typed (``ops.relation``):
                 ``parallel.train.layers_to_adjs`` states it for every
-                step builder. ``None`` on an untyped graph.
+                step builder. ``None`` on an untyped graph. A static
+                ``int`` where every slot holds the one relation: a block
+                of the per-relation walk (``RelationBlocks``); it is then
+                aux data, not a leaf.
     relations:  static like ``size``: how many relations the typed graph
                 has (``NodeTypes.num_relations``), stated with
                 ``relation``; ``None`` on an untyped graph.
@@ -106,14 +109,44 @@ class Adj:
         return self
 
     def tree_flatten(self):
+        one = self.relation if isinstance(self.relation, int) else None
         return ((self.edge_index, self.e_id, self.mask, self.valid_targets,
-                 self.relation), (self.size, self.fanout, self.relations))
+                 None if one is not None else self.relation),
+                (self.size, self.fanout, self.relations, one))
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        size, fanout, relations = aux
+        size, fanout, relations, one = aux
         return cls(leaves[0], leaves[1], size, leaves[2], fanout, leaves[3],
-                   leaves[4], relations)
+                   leaves[4] if one is None else one, relations)
+
+
+@jax.tree_util.register_pytree_node_class
+class RelationBlocks:
+    """One hop of a per-relation walk as the model reads it
+    (``parallel.frontier.layers_to_adjs`` over ``TypedLayer``s): ``adjs``,
+    one ``Adj`` a relation drawn at the hop (its ``relation`` the static
+    index into ``types.relations``, ``fanout`` and ``valid_targets``
+    stated, its sources local ids into the source type's frontier after
+    the hop, its targets into the target type's before it). Static:
+    ``sources[t]`` the rows of type ``t``'s frontier after the hop (the
+    layer's inputs), ``targets[t]`` before it (the layer's outputs), 0
+    where the type has none; ``types`` the ``NodeTypes``."""
+
+    __slots__ = ("adjs", "sources", "targets", "types")
+
+    def __init__(self, adjs, sources, targets, types):
+        self.adjs = tuple(adjs)
+        self.sources = tuple(int(c) for c in sources)
+        self.targets = tuple(int(c) for c in targets)
+        self.types = types
+
+    def tree_flatten(self):
+        return (self.adjs,), (self.sources, self.targets, self.types)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(leaves[0], *aux)
 
 
 class _LayerShape(NamedTuple):

@@ -488,3 +488,62 @@ def test_the_attention_layer_projects_its_live_blocks_in_one_loop(
     assert not re.search(r" while\(", compiled[False].as_text())
     assert compiled[True].memory_analysis().temp_size_in_bytes \
         <= compiled[False].memory_analysis().temp_size_in_bytes
+
+
+def test_the_igbh_step_fits_beside_its_table(topo, shape_on_chip):
+    """The MLPerf R-GAT cell's whole step at its shapes (``chipbench/
+    configs/igbh-rgat-1of256.json``: a 2.14 M-row float16 table of 1024,
+    seven relations' lists, batch 512, fanout [15, 10, 5] for every
+    relation, the configuration's frontier caps; ``build_train_step``
+    with ``collect_metrics=True``, as the cell builds it) compiles for a
+    described v5e, and its temporaries beside the resident arguments fit
+    the allocator's 16.9 GB with room: arguments 4.68 GB, temporaries
+    8.60 GB."""
+    import json
+    import os
+
+    import optax
+
+    from chipbench import spec
+    from quiver_tpu.models import RGAT
+    from quiver_tpu.parallel.train import TrainState, build_train_step
+
+    cfg = json.load(open(os.path.join(spec.HERE, "configs",
+                                      "igbh-rgat-1of256.json")))
+    entry = spec.plugin("entries", "igbh_train_step")
+    ref = spec.plugin("references", "igbh_rgat")
+    _, _, counts, rels = entry.layout(cfg)
+    batch = 512
+    model = RGAT(hidden_dim=cfg["hidden_dim"], out_dim=cfg["num_classes"],
+                 num_layers=cfg["num_layers"], heads=cfg["heads"],
+                 dropout=cfg["dropout"])
+    tx = optax.adam(1e-3)
+    drawn = ref.relations_by_layer([(t, s) for t, s, _ in rels], 0,
+                                   cfg["num_layers"])
+
+    def make_state():
+        params = entry.program_tree(ref.init_layers(
+            jax.random.key(0), cfg["feature_dim"], cfg["hidden_dim"],
+            cfg["num_classes"], cfg["heads"], drawn))
+        return TrainState(params, tx.init(params), jnp.zeros((), jnp.int32))
+
+    state = jax.tree.map(lambda a: shape_on_chip(a.shape, a.dtype),
+                         jax.eval_shape(make_state))
+    indptr = tuple(shape_on_chip((counts[t] + 1,), jnp.int32)
+                   for t, _, _ in rels)
+    graph = entry.relation_indices(cfg, tuple(
+        shape_on_chip((n,), jnp.int32) for _, _, n in rels))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    step = build_train_step(model, tx, list(cfg["fanout"]), batch,
+                            method="exact", collect_metrics=True)
+    with jax.default_matmul_precision("highest"):
+        compiled = step.jitted_fns[-1].lower(
+            state, shape_on_chip((cfg["nodes"], cfg["feature_dim"]),
+                                 jnp.float16), None, indptr, graph,
+            shape_on_chip((batch,), jnp.int32),
+            shape_on_chip((batch,), jnp.int32),
+            shape_on_chip(key.shape, key.dtype)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 2 * cfg["nodes"] * 1024  # the table
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        < 15.75e9, memory

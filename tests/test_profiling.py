@@ -33,7 +33,11 @@ The scope contracts:
    The tiered lookup names its bookkeeping (``profiling.LOOKUP_STAGES``)
    beside the tiers' reads, never inside them: the compaction's ``sort``
    under ``qt_lookup_compact``, the cold block's ``scatter`` under
-   ``qt_lookup_merge``.
+   ``qt_lookup_merge``. The per-relation walk (a graph whose relations
+   keep their own lists, ``RGAT``) names each relation's draw
+   ``qt_draw_relation`` inside ``qt_draw`` and each type's union
+   ``qt_type_union`` inside ``qt_compact``; its model's products are
+   ``qt_project``'s.
 2. the scopes are names and nothing else: with ``profiling.scope``
    swapped for a null context the lowered program is the same text.
 """
@@ -50,9 +54,10 @@ import pytest
 from jax.sharding import Mesh
 
 from quiver_tpu import profiling
-from quiver_tpu.models import MAG240MGNN, GraphSAGE
-from quiver_tpu.ops import NodeTypes, TypedIndices
+from quiver_tpu.models import RGAT, MAG240MGNN, GraphSAGE
+from quiver_tpu.ops import NodeTypes, RelationIndices, TypedIndices
 from quiver_tpu.ops.sample_multihop import sample_multihop
+from quiver_tpu.parallel.frontier import ALL_KNOBS, Walk, walk_frontier
 from quiver_tpu.parallel.train import (build_e2e_train_step, build_train_step,
                                        init_state, layers_to_adjs,
                                        masked_feature_gather)
@@ -123,11 +128,30 @@ def world():
                       num_layers=len(SIZES), heads=2, relations=5)
     rgat_state = init_state(rgat, tx, masked_feature_gather(feat, n_id),
                             layers_to_adjs(typed_layers, BATCH, SIZES), key)
+    # and as a graph whose relations keep their own lists (each relation
+    # a CSR over its target type's local ids), for the MLPerf R-GAT
+    counts = (N // 2, N // 2 - 20, 20)
+    per_relation = NodeTypes(starts=types.starts, relations=types.relations)
+    ptrs, lists = [], []
+    for t, src in per_relation.relations:
+        d = rng.integers(0, 4, counts[t])
+        ptrs.append(jnp.asarray(np.concatenate([[0], np.cumsum(d)]),
+                                jnp.int32))
+        lists.append(jnp.asarray(rng.integers(0, counts[src], int(d.sum())),
+                                 jnp.int32))
+    relational = RelationIndices(lists, per_relation)
+    igbh = RGAT(hidden_dim=8, out_dim=4, num_layers=len(SIZES), heads=2)
+    _, x, hops = walk_frontier(Walk.of("test", ALL_KNOBS, SIZES, {}), feat,
+                               None, tuple(ptrs), relational, seeds, key)
+    igbh_state = init_state(igbh, tx, x, layers_to_adjs(hops, BATCH, SIZES),
+                            key)
     return {"model": model, "tx": tx, "state": state, "feat": feat,
             "indptr": indptr, "indices": indices, "key": key, "gat": gat,
             "gat_state": gat_state, "n_id": n_id, "layers": layers,
             "rgat": rgat, "rgat_state": rgat_state,
-            "typed": TypedIndices(indices, types)}
+            "typed": TypedIndices(indices, types), "igbh": igbh,
+            "igbh_state": igbh_state, "relation_ptrs": tuple(ptrs),
+            "relational": relational}
 
 
 def _lower(builder: str, w):
@@ -148,6 +172,12 @@ def _lower(builder: str, w):
         fn = build_train_step(w["rgat"], w["tx"], SIZES, BATCH, donate=False)
         return fn.lower(w["rgat_state"], w["feat"].astype(jnp.float16), None,
                         w["indptr"], w["typed"],
+                        jnp.arange(BATCH, dtype=jnp.int32),
+                        jnp.zeros((BATCH,), jnp.int32), w["key"])
+    if builder == "igbh":
+        fn = build_train_step(w["igbh"], w["tx"], SIZES, BATCH, donate=False)
+        return fn.lower(w["igbh_state"], w["feat"].astype(jnp.float16), None,
+                        w["relation_ptrs"], w["relational"],
                         jnp.arange(BATCH, dtype=jnp.int32),
                         jnp.zeros((BATCH,), jnp.int32), w["key"])
     if builder == "tiered":
@@ -287,6 +317,44 @@ def test_a_hops_gathers_by_the_draws_part(world, builder):
     assert set(loops) <= {profiling.QT_DRAW_PICKS}
 
 
+def test_the_per_relation_walk_and_blocks_are_named(world):
+    """Over a graph whose relations keep their own lists: every op of a
+    relation's draw (its three stages among them) lies under
+    ``qt_sample_hop<i>/qt_draw/qt_draw_relation``, every op of a type's
+    union under ``qt_sample_hop<i>/qt_compact/qt_type_union``, every
+    product of the model (its blocks' loops and the head) under
+    ``qt_project``, and the walk holds no other builder's names."""
+    named = _names_and_ops(_lower("igbh", world).compile().as_text())
+    names = [n for n, _ in named]
+    for scope, parent in ((profiling.QT_DRAW_RELATION, profiling.QT_DRAW),
+                          (profiling.QT_TYPE_UNION, profiling.QT_COMPACT)):
+        held = [n for n in names if scope in n]
+        assert held, scope
+        assert all(re.search(r"qt_sample_hop\d\)?/" + parent + r"\)?/"
+                             + scope + r"\)?/", n) for n in held), scope
+        hops = {re.search(r"qt_sample_hop\d", n).group(0) for n in held}
+        assert hops == {f"qt_sample_hop{i}" for i in range(len(SIZES))}
+    for stage in profiling.DRAW_STAGES:
+        held = [n for n in names if stage in n]
+        assert held and all(
+            "qt_draw/qt_draw_relation/" + stage in n for n in held), stage
+    # the union's sort is the union's
+    sorts = [n for n, line in named if re.search(r" sort\(", line)
+             and "qt_sample_hop" in n]
+    assert sorts and all(profiling.QT_TYPE_UNION in n for n in sorts)
+    dots = [n for n, line in named
+            if re.search(r" (dot|convolution)\(", line)
+            and "qt_forward" in n]
+    assert dots and all(profiling.QT_PROJECT in n for n in dots), dots
+    loops = [n for n, line in named
+             if " while(" in line and re.search(r"/conv\d_\d", n)]
+    assert loops and all(re.search(r"qt_project\)?/while$", n)
+                         for n in loops)
+    assert any(profiling.QT_ATTENTION_SLOTS in n for n in names)
+    assert not any(profiling.QT_RELATION_ROUTE in n or "qt_norm" in n
+                   or profiling.QT_AGGREGATE in n for n in names)
+
+
 def test_the_lookups_bookkeeping_lies_beside_the_tiers(world):
     """``LOOKUP_STAGES`` lie beneath the tiered lookup's ``qt_gather`` as
     siblings of ``qt_lookup_hot`` / ``qt_lookup_cold``, never inside
@@ -413,7 +481,7 @@ def test_the_exchange_scopes_cover_the_lookup(world):
 
 
 @pytest.mark.parametrize("builder", ["train", "serve", "dist", "tiered",
-                                     "gat", "rgat"])
+                                     "gat", "rgat", "igbh"])
 def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     named = _lower(builder, world).as_text()
     monkeypatch.setattr(profiling, "scope",
@@ -428,5 +496,5 @@ def test_scopes_change_nothing_but_names(world, builder, monkeypatch):
     located = lowered.as_text(debug_info=True)
     assert not any(s in located for s in profiling.DEVICE_SCOPES
                    + profiling.EXCHANGE_STAGES + profiling.DRAW_STAGES
-                   + profiling.LOOKUP_STAGES)
+                   + profiling.LOOKUP_STAGES + profiling.TYPED_STAGES)
     assert "qt_sample_hop0" in located
